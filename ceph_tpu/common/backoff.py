@@ -42,12 +42,11 @@ def census_reset() -> None:
     GIVE_UPS.clear()
 
 
-class BackoffGiveUp(TimeoutError, asyncio.TimeoutError):
-    """A Backoff exhausted its deadline/attempt budget.  Subclasses
-    BOTH TimeoutError flavors (builtin and asyncio's — distinct
-    classes until 3.11) so callers that treated the old fixed
-    ``wait_for`` timeout as "peer is gone" handle a give-up
-    identically."""
+class BackoffGiveUp(TimeoutError):
+    """A Backoff exhausted its deadline/attempt budget.  A
+    TimeoutError (``asyncio.TimeoutError`` is the same class) so
+    callers that treated the old fixed ``wait_for`` timeout as "peer
+    is gone" handle a give-up identically."""
 
     def __init__(self, cause: str, attempts: int, elapsed: float):
         super().__init__(

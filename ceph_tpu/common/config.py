@@ -367,9 +367,11 @@ DEFAULT_OPTIONS: List[Option] = [
     Option("mon_cluster_log_file", "str", "",
            "cluster log sink path on the mon ('' = memory only)"),
     Option("osd_ec_batch_device", "str", "auto",
-           "EC encode device routing: auto/on (real accelerator only; a "
-           "cpu jax backend bypasses to the native SIMD kernel), "
-           "force (any jax backend, for tests), off"),
+           "EC encode device routing, decided once at OSD start: on "
+           "(a real accelerator is REQUIRED, the start fails without "
+           "one), auto (the accelerator when the process has one, the "
+           "native SIMD kernel otherwise), force (any jax backend, "
+           "for tests), off"),
     Option("osd_ec_batch_window_ms", "float", 2.0,
            "batch-collector fill window before a device launch"),
     Option("osd_ec_batch_min_bytes", "size", "64k",

@@ -1,14 +1,21 @@
 """ctypes bindings for the native runtime kernels (src/native.cc).
 
-Builds libceph_tpu_native.so on first import if missing or stale (mtime
-check against the source); all callers must tolerate `available() == False`
-(e.g. no compiler in the environment) and fall back to pure-python paths.
+Builds the library on first import, on the machine that runs it: the
+file is named after a hash of the source AND this CPU's feature flags
+(`-march=native` bakes them in), so a library built from other source
+or on another CPU — a working tree copied between machines carries
+such files — is never loaded, only rebuilt.  All callers must tolerate
+`available() == False` (e.g. no compiler in the environment) and fall
+back to pure-python paths.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import os
 import pathlib
+import platform
 import subprocess
 import threading
 from typing import Optional
@@ -17,20 +24,41 @@ import numpy as np
 
 _HERE = pathlib.Path(__file__).resolve().parent
 _SRC = _HERE / "src" / "native.cc"
-_SO = _HERE / "libceph_tpu_native.so"
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
+def _cpu_flags() -> str:
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith("flags"):
+                    return line
+    except OSError:
+        pass
+    return platform.machine()
+
+
+def _so_path() -> pathlib.Path:
+    h = hashlib.sha256(_SRC.read_bytes())
+    h.update(_cpu_flags().encode())
+    return _HERE / f"libceph_tpu_native.{h.hexdigest()[:16]}.so"
+
+
+def _build(so: pathlib.Path) -> bool:
+    # build beside the target and rename: daemons that start together
+    # all build, and none may load a half-written file
+    tmp = so.with_suffix(f".{os.getpid()}.tmp")
     try:
         subprocess.run(
             ["g++", "-O3", "-march=native", "-fopenmp", "-shared", "-fPIC",
-             "-o", str(_SO), str(_SRC)],
+             "-o", str(tmp), str(_SRC)],
             check=True, capture_output=True, timeout=120)
+        os.replace(tmp, so)
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
+        tmp.unlink(missing_ok=True)
         return False
 
 
@@ -40,15 +68,13 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        stale = (_SRC.exists()
-                 and (not _SO.exists()
-                      or _SO.stat().st_mtime < _SRC.stat().st_mtime))
-        if stale and not _build() and not _SO.exists():
-            return None  # no prebuilt .so and cannot compile
-        if not _SO.exists():
+        if not _SRC.exists():
             return None
+        so = _so_path()
+        if not so.exists() and not _build(so):
+            return None  # cannot compile here
         try:
-            lib = ctypes.CDLL(str(_SO))
+            lib = ctypes.CDLL(str(so))
         except OSError:
             return None
         return _bind(lib)
@@ -90,9 +116,9 @@ def _bind(lib: ctypes.CDLL) -> Optional[ctypes.CDLL]:
         lib.ceph_xxh64.argtypes = [u8p, ctypes.c_uint64,
                                    ctypes.c_uint64]
     except AttributeError:
-        # stale prebuilt .so missing newer symbols (no compiler to
-        # rebuild): degrade to unavailable, never raise out of _load —
-        # callers rely on available() -> False for the pure-python paths
+        # a symbol the bindings expect is missing: degrade to
+        # unavailable, never raise out of _load — callers rely on
+        # available() -> False for the pure-python paths
         return None
     _lib = lib
     return _lib

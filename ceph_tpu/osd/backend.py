@@ -852,17 +852,16 @@ class ECBackend(PGBackend):
         shard axis = the fan-out hop)."""
         gen = getattr(self.codec, "generator", None)
         ex = getattr(self.osd, "mesh_exec", None)
-        if ex is not None and gen is not None:
-            try:
-                return await ex.encode_object(self.codec, data)
-            except Exception as e:
-                self.log_.warning(f"mesh encode failed ({e}); "
-                                  f"falling back to batch queue")
         # per-loop collector: under threaded shards the daemon-wide
         # queue's wake event belongs to another loop (osd/shards.py)
         q = self.osd.ec_batch_queue() \
             if hasattr(self.osd, "ec_batch_queue") \
             else getattr(self.osd, "ec_queue", None)
+        if ex is not None and gen is not None:
+            try:
+                return await ex.encode_object(self.codec, data)
+            except Exception as e:
+                q.note_fallback("mesh encode", e)
         if gen is None or q is None:
             return self.codec.encode(set(range(self.n)), data)
         chunks = self.codec.split_data(data)
@@ -905,6 +904,9 @@ class ECBackend(PGBackend):
         mat_for = getattr(self.codec, "decode_matrix_for", None)
         t0 = time.monotonic()
         ex = getattr(self.osd, "mesh_exec", None)
+        q = self.osd.ec_batch_queue() \
+            if hasattr(self.osd, "ec_batch_queue") \
+            else getattr(self.osd, "ec_queue", None)
         if ex is not None and gen is not None:
             try:
                 rec = await ex.recover_chunks(self.codec, missing,
@@ -913,11 +915,7 @@ class ECBackend(PGBackend):
                 self._note_decode(t0)
                 return out
             except Exception as e:
-                self.log_.warning(f"mesh decode failed ({e}); "
-                                  f"falling back to batch queue")
-        q = self.osd.ec_batch_queue() \
-            if hasattr(self.osd, "ec_batch_queue") \
-            else getattr(self.osd, "ec_queue", None)
+                q.note_fallback("mesh decode", e)
         if gen is None or mat_for is None or q is None:
             out.update(self.codec.decode_chunks(missing, streams))
             self._note_decode(t0)

@@ -275,6 +275,10 @@ class OSD(Dispatcher):
         # it buys.  shards=1 keeps today's threaded handoff.
         if self.shards.enabled:
             self.store.ack_on_apply = True
+        # the EC queue's backend is decided ONCE, before this OSD takes
+        # ops; osd_ec_batch_device=on without an accelerator raises
+        # here and fails the start
+        await self.ec_queue.start()
         self.store.mount()
         if self.messenger.addr.is_blank():
             await self.messenger.bind()

@@ -19,9 +19,13 @@ Design:
     elsewhere — ec/kernel.py) runs in a single-thread executor so the
     event loop never blocks on the device.
   * Small lone requests take the native host kernel (GFNI/AVX-512)
-    instead: a sub-window dispatch to a remote device costs more latency
-    than encoding 64 KiB on the CPU.  Everything is counted in perf
+    instead: a window plus a device dispatch costs more latency than
+    encoding 64 KiB on the CPU.  Everything is counted in perf
     counters so `perf dump` proves where bytes went.
+  * Whether the queue launches on the device at all is decided ONCE,
+    by `resolve_backend()`, before the OSD takes ops: "on" without an
+    accelerator fails the start, and every later device->host reroute
+    is an error counted in `device_fallbacks`.
 """
 
 from __future__ import annotations
@@ -81,7 +85,7 @@ class ECBatchQueue:
             max_workers=1, thread_name_prefix="ec-device")
         self.perf = ctx.perf.create("ec_batch_queue")
         for key in ("device_launches", "device_requests", "device_bytes",
-                    "host_requests", "host_bytes"):
+                    "host_requests", "host_bytes", "device_fallbacks"):
             self.perf.add_u64(key)
         self.perf.add_avg("batch_fill")    # requests per device launch
         # concurrent encodes parked in the collector at each arrival:
@@ -91,70 +95,72 @@ class ECBatchQueue:
         # each PG held one op in flight)
         self.perf.add_avg("pending_depth")
         self._device_ok: Optional[bool] = None
-        self._probe_started = False
 
     # ------------------------------------------------------------- policy
-    def device_available(self) -> bool:
-        """Route to the device only when it can actually win.
+    def resolve_backend(self) -> bool:
+        """Decide, once, whether this queue launches on the device.
 
         Modes: "off" = host always; "force" = any jax backend, even the
         CPU one (tests exercise the device code path without a TPU);
-        "on"/"auto" = a real accelerator only.  On a CPU jax backend the
-        device path pays dispatch + fill-window latency to run the same
-        bytes slower than the native GFNI/AVX-512 kernel (round-4 bench:
-        3.4x e2e regression) — bypass straight to the host."""
-        if self.mode == "off":
-            return False
-        if self._device_ok is not None:
-            return self._device_ok
-        if self.mode == "force":
-            self._device_ok = self._probe()
-            return self._device_ok
-        # on/auto: even `import jax` can BLOCK for seconds (plugin
-        # registration / remote runtime init / a wedged device tunnel),
-        # and the FIRST apply() runs on the OSD event loop — every
-        # in-flight op would stall behind it (r5 bench: p99 8x worse
-        # with zero device bytes).  Probe in a daemon thread and serve
-        # the host path until the accelerator proves itself.
-        if not self._probe_started:
-            self._probe_started = True
-            import threading
-            threading.Thread(target=self._bg_probe, daemon=True,
-                             name="ec-device-probe").start()
-        return False
+        "on" = a real accelerator is REQUIRED — without one this
+        raises, which fails the OSD start; "auto" = the device when the
+        process's jax backend is an accelerator, the host otherwise (on
+        a CPU jax backend the device path pays dispatch + fill-window
+        latency to run the same bytes slower than the native
+        GFNI/AVX-512 kernel).
 
-    def _bg_probe(self) -> None:
-        ok = self._probe(require_accelerator=True)
-        self._device_ok = ok
-        if ok:
-            self.logger.info("accelerator probe ok: EC batch device on")
+        Blocking (the first call may import jax and initialise the
+        runtime): start() runs it off the loop before the OSD takes
+        ops, so apply() only ever reads the cached answer."""
+        if self._device_ok is None:
+            from ceph_tpu.common import envutil
+            accel = self.mode in ("on", "auto") \
+                and envutil.accelerator_present()
+            if self.mode == "on" and not accel:
+                raise RuntimeError(
+                    "osd_ec_batch_device=on requires an accelerator, "
+                    "and this process's jax backend is the CPU")
+            if accel:
+                cache = envutil.enable_compile_cache()
+                self.logger.info(
+                    f"EC batch device on; compile cache at {cache}")
+            self._device_ok = accel or self.mode == "force"
+        return self._device_ok
 
-    def _probe(self, require_accelerator: bool = False) -> bool:
-        import os
-        if (require_accelerator
-                and os.environ.get("JAX_PLATFORMS", "").strip()
-                .lower().startswith("cpu")):
-            return False         # no accelerator configured: skip the
-            #                      (expensive) jax import entirely
-        try:
-            import jax
-            if require_accelerator and jax.default_backend() == "cpu":
-                return False
-            return True
-        except Exception:
-            return False
+    async def start(self) -> None:
+        """Resolve the backend before the first request.  Asking jax
+        for its backend can take seconds (import, device runtime
+        init), so that happens on this queue's device thread, off the
+        loop.  A process pinned to the CPU — every test, vstart daemon
+        and lane worker — answers without jax and without a thread
+        hop (the sim loop must not wait on one)."""
+        from ceph_tpu.common import envutil
+        if self.mode in ("on", "auto") and not envutil.pinned_to_cpu():
+            await asyncio.get_running_loop().run_in_executor(
+                self._pool, self.resolve_backend)
+        else:
+            self.resolve_backend()
+
+    def note_fallback(self, what: str, err: BaseException) -> None:
+        """A device launch failed and its bytes are being re-run on a
+        slower path.  The reroute keeps the op alive; the counter and
+        the error line keep it from passing for device work."""
+        self.perf.inc("device_fallbacks")
+        self.logger.error(f"{what} failed ({err!r}); rerouting off "
+                          f"the device")
 
     # ---------------------------------------------------------------- api
     async def apply(self, mat: np.ndarray,
                     chunks: np.ndarray) -> np.ndarray:
         """out[r, L] = mat @ chunks over GF(2^8), batched across callers.
 
-        Single awaitable entry for PG backends; falls back to the native
-        host kernel when the device isn't worth it (small lone request,
-        no jax, mode=off)."""
+        Single awaitable entry for PG backends; takes the native host
+        kernel when the device isn't worth it (small lone request) or
+        isn't this queue's backend (mode=off, auto without an
+        accelerator)."""
         chunks = np.ascontiguousarray(chunks, np.uint8)
         nbytes = chunks.shape[0] * chunks.shape[1]
-        if (not self.device_available()
+        if (not self.resolve_backend()
                 or (nbytes < self.min_device_bytes
                     and not self._pending)):
             return self._host_apply(mat, chunks, nbytes)
@@ -238,8 +244,7 @@ class ECBatchQueue:
                         if not r.fut.done():
                             r.fut.set_result(out)
                 except Exception as e:     # device failure: host fallback
-                    self.logger.warning(f"device batch failed ({e}); "
-                                        f"host fallback")
+                    self.note_fallback("device batch", e)
                     for r in reqs:
                         if not r.fut.done():
                             try:

@@ -1284,6 +1284,9 @@ class LaneRuntime:
         store.ack_on_apply = True
         self.osd = _make_lane_osd(ctx, self, store, monmap)
         osd = self.osd
+        # this process is pinned to the CPU (lane_main), so the answer
+        # is immediate — and osd_ec_batch_device=on fails the lane
+        osd.ec_queue.resolve_backend()
         store.mount()
         osd.shards.start()        # disabled plane: inline route()
         osd.running = True
@@ -1398,6 +1401,11 @@ class LaneRuntime:
 def lane_main(spec: dict, to_wake_r, from_wake_w) -> None:
     """Worker entry point (spawned).  Builds a fresh event loop and
     runs the lane runtime until STOP or parent death."""
+    # the worker inherits the parent's environment, and the parent may
+    # hold the chip: a chip belongs to one process, so this one must
+    # never initialise a non-CPU jax backend (set before anything here
+    # imports jax)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     logging.basicConfig(level=logging.WARNING)
     runtime = LaneRuntime(spec, to_wake_r, from_wake_w)
     try:

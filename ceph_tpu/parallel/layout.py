@@ -27,22 +27,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def shard_map_check_kwargs(shard_map_fn) -> dict:
-    """Version-portable shard_map replication-check kwarg: the flag
-    was renamed check_rep -> check_vma across jax releases (the seed's
-    mesh tests failed on whichever name the installed jax lacked)."""
-    import inspect
-    try:
-        params = inspect.signature(shard_map_fn).parameters
-    except (TypeError, ValueError):
-        return {}
-    for name in ("check_vma", "check_rep"):
-        if name in params:
-            return {name: False}
-    return {}
 
 
 def make_mesh(n_devices: Optional[int] = None,
@@ -71,11 +57,6 @@ def ec_cluster_step(mesh: Mesh, bitmat: jnp.ndarray):
     over 'host' (the scrub roll-up).  Returns (parity, scrub) with parity
     laid out like the data.
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
     from ceph_tpu.ec.kernel import _apply_bitmatrix
 
     def step(data):
@@ -94,7 +75,7 @@ def ec_cluster_step(mesh: Mesh, bitmat: jnp.ndarray):
         step, mesh=mesh,
         in_specs=(P("host", None, "shard"),),
         out_specs=(P("host", None, "shard"), P()),
-        **shard_map_check_kwargs(shard_map))
+        check_vma=False)
     return jax.jit(sharded)
 
 
@@ -120,11 +101,6 @@ def ec_recover_step(mesh: Mesh, dec_bitmat: jnp.ndarray,
     """
     assert n_surv % mesh.shape["shard"] == 0, \
         (n_surv, dict(mesh.shape))
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
     from ceph_tpu.ec.kernel import _apply_bitmatrix
 
     def step(surv):
@@ -140,7 +116,7 @@ def ec_recover_step(mesh: Mesh, dec_bitmat: jnp.ndarray,
         step, mesh=mesh,
         in_specs=(P("host", "shard", None),),
         out_specs=(P("host", None, None), P()),
-        **shard_map_check_kwargs(shard_map))
+        check_vma=False)
     return jax.jit(sharded)
 
 

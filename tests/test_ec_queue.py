@@ -1,9 +1,10 @@
 """Cross-PG device batch collector (osd/ec_queue.py).
 
-Unit: coalescing, correctness vs the host kernel, host-fallback policy,
-perf accounting.  E2E: a live in-process cluster with
-osd_ec_batch_device=on proves client writes on an EC pool flow through
-the device queue (device_bytes > 0 on the primary, results readable).
+Unit: coalescing, correctness vs the host kernel, backend resolution and
+host-fallback policy, perf accounting.  E2E: a live in-process cluster
+with osd_ec_batch_device=force proves client writes on an EC pool flow
+through the device queue (device_bytes > 0 on the primary, results
+readable).
 The jit path runs on the CPU backend here; the identical code hits the
 fused pallas kernel on TPU.
 """
@@ -94,13 +95,41 @@ def test_oversize_batch_splits_into_bucket_windows():
     asyncio.run(run())
 
 
-def test_mode_on_bypasses_device_on_cpu_backend():
-    """mode=on requires a real accelerator: on the CPU jax backend the
-    device path only adds dispatch+window latency over the native SIMD
-    kernel (round-4 bench: 3.4x e2e regression), so requests must route
-    straight to the host."""
+def test_mode_on_without_accelerator_fails_the_start():
+    """mode=on REQUIRES a real accelerator: the backend is resolved
+    once, before the queue takes requests, and on the CPU jax backend
+    that resolution raises — `on` never quietly means "host"."""
+    q = make_queue(mode="on")
+    with pytest.raises(RuntimeError, match="requires an accelerator"):
+        q.resolve_backend()
+
+
+def test_osd_start_fails_with_mode_on_and_no_accelerator():
+    sys.path.insert(0, __file__.rsplit("/", 1)[0])
+    from test_osd import Cluster, FAST_CFG
+    saved = dict(FAST_CFG)
+    FAST_CFG["osd_ec_batch_device"] = "on"
+    try:
+        async def run():
+            cl = Cluster()
+            try:
+                with pytest.raises(RuntimeError,
+                                   match="requires an accelerator"):
+                    await cl.start(1)
+            finally:
+                await cl.stop()
+        asyncio.run(run())
+    finally:
+        FAST_CFG.clear()
+        FAST_CFG.update(saved)
+
+
+def test_mode_auto_takes_the_host_on_cpu_backend():
+    """auto decides at start by the backend it observes: on the CPU
+    jax backend every request goes to the native host kernel."""
     async def run():
-        q = make_queue(mode="on", min_device_bytes=256)
+        q = make_queue(mode="auto", min_device_bytes=256)
+        assert q.resolve_backend() is False
         mat = gen_mat()
         c = np.arange(4 * (1 << 17), dtype=np.uint8).reshape(4, -1) \
             .astype(np.uint8)
@@ -158,13 +187,16 @@ def test_device_failure_falls_back_to_host(monkeypatch):
             .astype(np.uint8)
         out = await q.apply(mat, c)
         assert np.array_equal(out, gf256.host_apply(mat, c))
-        assert q.perf.dump()["host_requests"] == 1
+        d = q.perf.dump()
+        assert d["host_requests"] == 1
+        # the reroute is counted, never silent
+        assert d["device_fallbacks"] == 1 and d["device_bytes"] == 0
         await q.stop()
     asyncio.run(run())
 
 
 def test_ec_pool_writes_ride_the_device_queue():
-    """E2E: cluster with osd_ec_batch_device=on — concurrent EC writes
+    """E2E: cluster with osd_ec_batch_device=force — concurrent EC writes
     coalesce on the primary's device queue and read back intact."""
     sys.path.insert(0, __file__.rsplit("/", 1)[0])
     from test_osd import Cluster, FAST_CFG
