@@ -1,0 +1,8 @@
+"""Per-layer metric `ec.encode_ms.op_rate`: tracer stage ec_encode
+(writes), mean ms per op."""
+
+from benchmark import readers
+
+
+def read(obs):
+    return readers.stage_ms_per_op(obs, ["ec_encode"])

@@ -1,0 +1,8 @@
+"""Per-layer metric `launch.window_compiles.goodput`: jax compile events
+inside the window, count."""
+
+from benchmark import readers
+
+
+def read(obs):
+    return readers.window_compiles(obs)

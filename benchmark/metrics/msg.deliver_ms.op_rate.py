@@ -1,0 +1,8 @@
+"""Per-layer metric `msg.deliver_ms.op_rate`: tracer stages deliver +
+ack_delivery, mean ms per op."""
+
+from benchmark import readers
+
+
+def read(obs):
+    return readers.stage_ms_per_op(obs, ["deliver", "ack_delivery"])

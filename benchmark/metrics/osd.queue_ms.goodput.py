@@ -1,0 +1,8 @@
+"""Per-layer metric `osd.queue_ms.goodput`: tracer queue-wait stages +
+admit_wait + dep_wait, mean ms per op."""
+
+from benchmark import readers
+
+
+def read(obs):
+    return readers.stage_ms_per_op(obs, readers.QUEUE_STAGES)
