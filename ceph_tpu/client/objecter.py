@@ -227,32 +227,36 @@ class Objecter(Dispatcher):
         pend, self._cork = self._cork, []
         if not pend:
             return
-        m = self.osdmap
-        if m is not None and len(pend) > 1:
-            # device-candidate:crush-placement@landed batch-compute
-            # every corked op's placement in ONE ops/crush_kernel.py
-            # call (OSDMap.prime_pgs → batch_do_rule, CHUNK_SIZES-
-            # bucketed) instead of per-op _calc_target scalar descents
-            # — the corked pass is already the N-ops shape the batched
-            # kernel wants; _build_msg below then runs on pure
-            # _acting_cache hits
-            pgs = []
+        # op tracing: placement + message build of the cork as one loop
+        # section; the sends below are not in it (a local send can run
+        # the OSD's intake, which has sections of its own)
+        with self.ctx.tracer.section("loop_client"):
+            m = self.osdmap
+            if m is not None and len(pend) > 1:
+                # device-candidate:crush-placement@landed batch-compute
+                # every corked op's placement in ONE ops/crush_kernel.py
+                # call (OSDMap.prime_pgs → batch_do_rule, CHUNK_SIZES-
+                # bucketed) instead of per-op _calc_target scalar descents
+                # — the corked pass is already the N-ops shape the batched
+                # kernel wants; _build_msg below then runs on pure
+                # _acting_cache hits
+                pgs = []
+                for op in pend:
+                    loc = self._effective_loc(op.loc, op.ops)
+                    if loc.pool in m.pools:
+                        pgs.append(m.object_locator_to_pg(op.oid, loc))
+                m.prime_pgs(pgs)
+            by_addr: Dict[Tuple[str, int], list] = {}
             for op in pend:
-                loc = self._effective_loc(op.loc, op.ops)
-                if loc.pool in m.pools:
-                    pgs.append(m.object_locator_to_pg(op.oid, loc))
-            m.prime_pgs(pgs)
-        by_addr: Dict[Tuple[str, int], list] = {}
-        for op in pend:
-            built = self._build_msg(op)
-            if built is None:
-                # no reachable primary: leave the op for the next map's
-                # resend scan (uncork so it can re-enter)
-                op.corked = False
-                continue
-            msg, addr = built
-            by_addr.setdefault(addr.without_nonce(),
-                               (addr, []))[1].append((msg, op))
+                built = self._build_msg(op)
+                if built is None:
+                    # no reachable primary: leave the op for the next map's
+                    # resend scan (uncork so it can re-enter)
+                    op.corked = False
+                    continue
+                msg, addr = built
+                by_addr.setdefault(addr.without_nonce(),
+                                   (addr, []))[1].append((msg, op))
         for addr, group in by_addr.values():
             if len(group) == 1:
                 msg, op = group[0]

@@ -31,18 +31,37 @@ Design:
     OVERLAP chain stages (a replica applies inside the primary's
     ``replica_rtt``) and are excluded from the chain sum.
 
+  * SECTIONS (``Tracer.section(name)``) name synchronous work by the
+    thread that ran it: a ``with`` block that never awaits, timed into
+    the same histogram group under ``name`` and entered as a
+    ``jax.profiler.TraceAnnotation`` of the same name, so the block
+    lies in the profiler's trace on the profiler's clock.  ``loop_*``
+    sections run on the event-loop thread and never nest (their sum
+    is loop time with a name); ``seam_*`` sections run on the EC
+    queue's device thread.  INTERVALS (``Tracer.interval``) are the
+    awaited counterpart: histogram only, from a ``Tracer.stamp()``.
+
+  * While tracing is on, one sampler per event loop records the loop
+    thread's wall and CPU time (``loop_wall`` / ``loop_cpu``) every
+    100 ms: their ratio is the share of the one Python loop that is
+    burning CPU, the rest is the loop waiting.
+
   * Fully off-path when disabled (``op_tracing=false``, the default):
     no span allocation, no clock reads — every call site guards on
     ``tracer.enabled`` / ``span is not None``, and the tracer caches
     the config flag with an observer so the check is one attribute
-    load per op.
+    load per op.  ``section()`` then returns one shared no-op object.
 """
 
 from __future__ import annotations
 
+import asyncio
+import contextlib
 import random
+import sys
 import threading
 import time
+import weakref
 from typing import Dict, List, Optional, Tuple
 
 from ceph_tpu.common.perf_counters import PerfHistogram
@@ -93,6 +112,37 @@ QUEUE_WAIT_CAUSES = (
     "queue_wait_pump",  # PG worker busy with ops ahead in its queue
 )
 
+#: One device request's trip through the seam (osd/ec_queue.py), below
+#: the chain's ``ec_encode`` / the aux ``decode_rebuild``.  Intervals
+#: on the loop's side, sections on the ec-device executor thread:
+#: seam_pending + [seam_fold .. seam_split] + seam_resume ~ seam_apply.
+SEAM_STAGES = (
+    "seam_apply",       # interval: the whole apply() await
+    "seam_pending",     # interval: apply() entry -> executor takes the group
+    "seam_fold",        # section: zero + concatenate into the folded batch
+    "seam_h2d",         # section: jax.device_put of the folded batch
+    "seam_launch",      # section: slice / pad / device_call / concatenate
+    "seam_d2h",         # section: np.asarray of the device result
+    "seam_split",       # section: per-request result copies
+    "seam_resume",      # interval: executor done -> awaiter runs again
+)
+
+#: Synchronous work on the event-loop thread, by section, plus the
+#: loop sampler's two per-tick stages.  Sections never nest, so their
+#: sum over loop_cpu is the share of the loop's CPU that has a name.
+LOOP_STAGES = (
+    "loop_client",        # objecter: placement + message build of a cork
+    "loop_dispatch",      # OSD: delivered client op / sub-op ack -> its PG
+    "loop_prepare",       # EC write: cls, cow, per-shard txns before encode
+    "loop_ec_host",       # EC: split, tobytes/crc/txn build, decode glue
+    "loop_store_apply",   # store apply at the primary and the sub-op handler
+    "loop_store_commit",  # store: one inline (ack-on-apply) commit group
+    "loop_submit",        # payload seal + fan-out in the submit regions
+    "loop_reply",         # PG: reply build + send, tracker/budget release
+    "loop_wall",          # sampler: monotonic delta per tick
+    "loop_cpu",           # sampler: time.thread_time() delta per tick
+)
+
 #: Auxiliary (non-chain) stages, for dump annotation.  recovery_pull
 #: (one recovered object: gather -> decode -> push ack) and
 #: decode_rebuild (the decode slice alone, batched through the EC
@@ -106,7 +156,7 @@ QUEUE_WAIT_CAUSES = (
 #: the evidence the copy moved rather than vanished.
 AUX_STAGES = ("op_total", "repl_apply", "repl_commit",
               "recovery_pull", "decode_rebuild",
-              "extent_write", "extent_read")
+              "extent_write", "extent_read") + SEAM_STAGES + LOOP_STAGES
 
 STAGE_GROUP = "op_stages"
 
@@ -203,6 +253,98 @@ class Span:
         }
 
 
+#: what ``Tracer.section`` returns while tracing is off: one shared
+#: reusable no-op, no clock read, no allocation
+_NO_SECTION = contextlib.nullcontext()
+
+#: jax.profiler.TraceAnnotation once found.  Taken only from a jax that
+#: is ALREADY imported: the process that owns the chip has it; a lane
+#: worker or a CPU-pinned daemon must not import jax for this.
+_annotation_cls = None
+
+
+def _annotation(name: str):
+    global _annotation_cls
+    if _annotation_cls is None:
+        prof = sys.modules.get("jax.profiler")
+        if prof is None:
+            return None
+        _annotation_cls = prof.TraceAnnotation
+    return _annotation_cls(name)
+
+
+class _Section:
+    """One timed synchronous block: histogram + profiler annotation
+    under one name."""
+
+    __slots__ = ("hist", "name", "ann", "t0")
+
+    def __init__(self, hist, name: str):
+        self.hist = hist
+        self.name = name
+
+    def __enter__(self):
+        self.ann = _annotation(self.name)
+        if self.ann is not None:
+            self.ann.__enter__()
+        self.t0 = time.monotonic()
+
+    def __exit__(self, *exc):
+        dt = time.monotonic() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        self.hist.hinc(self.name, dt)
+        _last_stage[threading.get_ident()] = self.name
+        return False
+
+
+#: seconds between two ticks of a loop's sampler
+LOOP_SAMPLE_PERIOD = 0.1
+
+#: the event loops that have a live sampler (at most one per loop)
+_sampled_loops: "weakref.WeakSet" = weakref.WeakSet()
+
+
+class _LoopSampler:
+    """Records the loop thread's wall and CPU time per tick into the
+    histograms of the tracer that started it.  A timer chain, not a
+    task: nothing to cancel when the loop closes.  Ends when its tracer
+    is switched off or collected; the next enabled tracer on that loop
+    starts a new one."""
+
+    __slots__ = ("loop", "tracer", "wall", "cpu")
+
+    def __init__(self, loop, tracer: "Tracer"):
+        self.loop = loop
+        self.tracer = weakref.ref(tracer)
+        self.wall = time.monotonic()
+        self.cpu = time.thread_time()
+        loop.call_later(LOOP_SAMPLE_PERIOD, self._tick)
+
+    def _tick(self) -> None:
+        tr = self.tracer()
+        if tr is None or not tr.enabled or self.loop.is_closed():
+            _sampled_loops.discard(self.loop)
+            return
+        wall, cpu = time.monotonic(), time.thread_time()
+        tr.hist.hinc("loop_wall", wall - self.wall)
+        tr.hist.hinc("loop_cpu", cpu - self.cpu)
+        self.wall, self.cpu = wall, cpu
+        self.loop.call_later(LOOP_SAMPLE_PERIOD, self._tick)
+
+
+def _ensure_sampler(tracer: "Tracer") -> None:
+    """Start this thread's loop's sampler if it has none.  No-op off
+    the loop (executor threads) and under the deterministic sim loop,
+    whose clock is virtual and whose schedule a timer would perturb."""
+    loop = asyncio._get_running_loop()
+    if loop is None or loop in _sampled_loops \
+            or getattr(loop, "deterministic", False):
+        return
+    _sampled_loops.add(loop)
+    _LoopSampler(loop, tracer)
+
+
 class Tracer:
     """Per-context tracing frontend: enablement cache + stage group.
 
@@ -237,8 +379,32 @@ class Tracer:
         every downstream touch on that None)."""
         if not self.enabled:
             return None
+        _ensure_sampler(self)
         return Span(random.getrandbits(63) | 1,
                     random.getrandbits(63) | 1, name)
+
+    def section(self, name: str):
+        """``with tracer.section("loop_x"):`` around synchronous work
+        (never an await inside).  On: the block's time lands in this
+        tracer's histograms under ``name`` and, where jax is loaded,
+        in the profiler's trace under the same name on the thread that
+        ran it.  Off: the shared no-op."""
+        if not self.enabled:
+            return _NO_SECTION
+        _ensure_sampler(self)
+        return _Section(self.hist, name)
+
+    def stamp(self) -> float:
+        """Start of an awaited interval (0.0 when tracing is off: no
+        clock read); close it with ``interval``."""
+        return time.monotonic() if self.enabled else 0.0
+
+    def interval(self, name: str, t0: float) -> None:
+        """Record the aux interval ``t0`` (a ``stamp``) -> now under
+        ``name``.  A zero stamp (tracing was off when it was taken)
+        records nothing."""
+        if t0 and self.enabled:
+            self.hist.hinc(name, time.monotonic() - t0)
 
     def adopt(self, trace_id: int, span_id: int,
               t0: Optional[float] = None) -> Span:

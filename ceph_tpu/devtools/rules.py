@@ -83,6 +83,8 @@ Each rule mechanically enforces one PR-landed write-path invariant
                            PROTO08 shape applied to observability):
                            every literal stage name passed to
                            ``span.cut(...)`` / ``span.attribute(...)``
+                           / ``tracer.section(...)`` /
+                           ``tracer.interval(...)``
                            must be declared in CHAIN_STAGES /
                            AUX_STAGES (common/tracer.py), and — when
                            the linted set spans the op-path modules —
@@ -1097,13 +1099,15 @@ _STAGE_COVERAGE_ANCHORS = (
     "msg/messenger.py",
 )
 
-#: span-recording call names whose first literal argument is a stage
-_STAGE_CALL_ATTRS = ("cut", "attribute")
+#: stage-recording call names whose first literal argument is a stage:
+#: the span's cuts and the tracer's sections and intervals
+_STAGE_CALL_ATTRS = ("cut", "attribute", "section", "interval")
 
 
 def collect_stage_sites(files: List["FileInfo"]) -> Dict[str, list]:
     """stage name -> [(FileInfo, line)] over every ``.cut("x", ...)`` /
-    ``.attribute("x", ...)`` call with a literal first argument.  The
+    ``.attribute("x", ...)`` / ``.section("x")`` / ``.interval("x", t0)``
+    call with a literal first argument.  The
     lint --json document exposes the per-stage site counts so CI can
     diff coverage like it diffs the seam/device inventories."""
     sites: Dict[str, list] = {}
@@ -1135,10 +1139,11 @@ def check_stage18(files: List["FileInfo"]) -> Iterator[Violation]:
                 continue
             yield Violation(
                 "STAGE18", fi.rel, line,
-                f"span cut names undeclared stage {name!r} — declare "
-                f"it in CHAIN_STAGES/AUX_STAGES (common/tracer.py) or "
-                f"fix the typo; an undeclared cut silently falls out "
-                f"of the attributed chain sum")
+                f"span cut / tracer section names undeclared stage "
+                f"{name!r} — declare it in CHAIN_STAGES/AUX_STAGES "
+                f"(common/tracer.py) or fix the typo; an undeclared "
+                f"stage silently falls out of the attributed chain "
+                f"sum and of every reader")
     rels = {fi.rel for fi in files}
     if not all(a in rels for a in _STAGE_COVERAGE_ANCHORS):
         return                    # partial lint: skip the coverage half

@@ -384,13 +384,11 @@ class PGBackend:
             if rec is not None:
                 rec.inc("active_pulls")
             try:
-                t0 = time.monotonic()
+                t0 = tr.stamp()
                 await self.recover_object(peer, oid, progress=progress)
-                if tr.enabled:
-                    # aux stage: overlaps the client chain (recovery
-                    # runs concurrently with ops), never summed into it
-                    tr.hist.hinc("recovery_pull",
-                                 time.monotonic() - t0)
+                # aux stage: overlaps the client chain (recovery runs
+                # concurrently with ops), never summed into it
+                tr.interval("recovery_pull", t0)
             finally:
                 if rec is not None:
                     rec.inc("active_pulls", -1)
@@ -651,40 +649,48 @@ class ReplicatedBackend(PGBackend):
         # invariant lint (devtools rule AF01) fails on any suspension
         # point between the sentinels.
         # awaitfree:begin replicated-submit
-        version = pg.next_version()
-        entry = LogEntry(LOG_DELETE if deletes else LOG_MODIFY, m.oid,
-                         version, pg.info.last_update, m.reqid)
-        if not deletes:
-            txn.setattr(pg.cid, soid, VERSION_XATTR, version.to_bytes())
-        pg.append_log(txn, entry)
-        # seal the txn + entry into lazy payloads: freezes the txn (no
-        # further sender mutation) and shares ONE encoder cache across
-        # the whole fan-out — bytes materialize only if a peer hop
-        # actually crosses a TCP socket (msg/payload.py)
-        txn_payload = LazyPayload.seal(txn)
-        log_payload = LazyPayload.seal(entry)
-        # local apply now (memory is immediately readable); durability
-        # rides the commit thread CONCURRENTLY with the replica round
-        # trip — pglog last_complete advances from the commit callback
-        commit_fut = self._queue_txn(
-            txn, on_commit=lambda: pg.complete_to(version))
+        tr = self.osd.ctx.tracer
+        with tr.section("loop_store_apply"):
+            version = pg.next_version()
+            entry = LogEntry(LOG_DELETE if deletes else LOG_MODIFY,
+                             m.oid, version, pg.info.last_update,
+                             m.reqid)
+            if not deletes:
+                txn.setattr(pg.cid, soid, VERSION_XATTR,
+                            version.to_bytes())
+            pg.append_log(txn, entry)
+            # seal the txn + entry into lazy payloads: freezes the txn
+            # (no further sender mutation) and shares ONE encoder cache
+            # across the whole fan-out — bytes materialize only if a
+            # peer hop actually crosses a TCP socket (msg/payload.py)
+            txn_payload = LazyPayload.seal(txn)
+            log_payload = LazyPayload.seal(entry)
+            # local apply now (memory is immediately readable);
+            # durability rides the commit thread CONCURRENTLY with the
+            # replica round trip — pglog last_complete advances from
+            # the commit callback
+            commit_fut = self._queue_txn(
+                txn, on_commit=lambda: pg.complete_to(version))
         if span is not None:
             span.cut("store_apply", th)
         # fan out to acting AND up: an up-but-not-acting member (pg_temp
         # backfill target) must see every write or its copy stales
-        peers = {o for o in set(pg.acting) | set(pg.up)
-                 if o != self.osd.whoami and o >= 0
-                 and o != CRUSH_ITEM_NONE}
-        tid = self.osd.next_tid()
-        fut = self._ack_init(tid, peers)
-        for p in peers:
-            rep = MOSDRepOp(pg.pgid, tid, txn_payload, log_payload,
-                            version, self.osd.osdmap.epoch)
-            if span is not None:
-                # propagate the trace so replica-side stage records
-                # land under the client's trace (wire: payload fields)
-                rep.trace_id, rep.span_id = span.trace_id, span.span_id
-            self.osd.send_osd(p, rep)
+        with tr.section("loop_submit"):
+            peers = {o for o in set(pg.acting) | set(pg.up)
+                     if o != self.osd.whoami and o >= 0
+                     and o != CRUSH_ITEM_NONE}
+            tid = self.osd.next_tid()
+            fut = self._ack_init(tid, peers)
+            for p in peers:
+                rep = MOSDRepOp(pg.pgid, tid, txn_payload, log_payload,
+                                version, self.osd.osdmap.epoch)
+                if span is not None:
+                    # propagate the trace so replica-side stage
+                    # records land under the client's trace (wire:
+                    # payload fields)
+                    rep.trace_id, rep.span_id = \
+                        span.trace_id, span.span_id
+                self.osd.send_osd(p, rep)
         if span is not None:
             span.cut("submit", th)
         # awaitfree:end replicated-submit
@@ -761,47 +767,48 @@ class ReplicatedBackend(PGBackend):
             # here or they leak until the lane-death sweep
             extents.release_message(m)
             return
-        rt = self._repl_trace(m)
-        # copy discipline: txn() is OUR mutable copy (save_meta
-        # appends below must never reach the sender or a sibling
-        # replica); the log entry is immutable and shared as-is
-        txn = m.txn()
-        entry = m.log_entry()
-        advance = None
-        if pg.log.head < entry.version:
-            pg.log.append(entry)
-            pg.note_reqid(entry)
-            pg.info.last_update = entry.version
-            if not pg.missing:
-                # a copy still owed recovery pushes must keep its
-                # honest last_complete cursor, or the gap hides
-                advance = entry.version
-        pg.save_meta_log(txn, entry)
-        src = int(m.src_name.id)
-        reply = MOSDRepOpReply(pg.pgid, m.tid, 0, True,
-                               self.osd.whoami)
-        if rt is not None:
-            rt.applied()
-
-        def _committed():
-            # last_complete and the repop ack advance TOGETHER from
-            # the commit callback — the ack can never outrun the
-            # durability of the pglog entry it vouches for, and the
-            # PG worker is already applying the next sub-op while
-            # this one's group commits (commit pipelining).  The op's
-            # extent slots retire with the same durability point, and
-            # the ack rides the per-connection cork: the commit thread
-            # runs a drained group's callbacks in ONE loop callback,
-            # so every ack of the burst coalesces into one frame
-            extents.release_message(m)
-            if advance is not None:
-                pg.complete_to(advance)
+        with self.osd.ctx.tracer.section("loop_store_apply"):
+            rt = self._repl_trace(m)
+            # copy discipline: txn() is OUR mutable copy (save_meta
+            # appends below must never reach the sender or a sibling
+            # replica); the log entry is immutable and shared as-is
+            txn = m.txn()
+            entry = m.log_entry()
+            advance = None
+            if pg.log.head < entry.version:
+                pg.log.append(entry)
+                pg.note_reqid(entry)
+                pg.info.last_update = entry.version
+                if not pg.missing:
+                    # a copy still owed recovery pushes must keep its
+                    # honest last_complete cursor, or the gap hides
+                    advance = entry.version
+            pg.save_meta_log(txn, entry)
+            src = int(m.src_name.id)
+            reply = MOSDRepOpReply(pg.pgid, m.tid, 0, True,
+                                   self.osd.whoami)
             if rt is not None:
-                rt.committed()
-            self.osd.queue_rep_ack(src, reply)
+                rt.applied()
 
-        self.osd.store.queue_transactions([txn],
-                                          on_commit=_committed)
+            def _committed():
+                # last_complete and the repop ack advance TOGETHER from
+                # the commit callback — the ack can never outrun the
+                # durability of the pglog entry it vouches for, and the
+                # PG worker is already applying the next sub-op while
+                # this one's group commits (commit pipelining).  The op's
+                # extent slots retire with the same durability point, and
+                # the ack rides the per-connection cork: the commit thread
+                # runs a drained group's callbacks in ONE loop callback,
+                # so every ack of the burst coalesces into one frame
+                extents.release_message(m)
+                if advance is not None:
+                    pg.complete_to(advance)
+                if rt is not None:
+                    rt.committed()
+                self.osd.queue_rep_ack(src, reply)
+
+            self.osd.store.queue_transactions([txn],
+                                              on_commit=_committed)
 
 
 # ================================================================= erasure
@@ -864,7 +871,8 @@ class ECBackend(PGBackend):
                 q.note_fallback("mesh encode", e)
         if gen is None or q is None:
             return self.codec.encode(set(range(self.n)), data)
-        chunks = self.codec.split_data(data)
+        with self.osd.ctx.tracer.section("loop_ec_host"):
+            chunks = self.codec.split_data(data)
         # device-candidate:ec-encode@landed the live kernel call site: awaits
         # the cross-PG collector (LANE_BUCKETS-bucketed, executor
         # dispatch) — the loop never blocks on the device
@@ -902,7 +910,8 @@ class ECBackend(PGBackend):
             raise ValueError(f"mixed chunk lengths {sorted(lens)}")
         gen = getattr(self.codec, "generator", None)
         mat_for = getattr(self.codec, "decode_matrix_for", None)
-        t0 = time.monotonic()
+        tr = self.osd.ctx.tracer
+        t0 = tr.stamp()
         ex = getattr(self.osd, "mesh_exec", None)
         q = self.osd.ec_batch_queue() \
             if hasattr(self.osd, "ec_batch_queue") \
@@ -912,29 +921,25 @@ class ECBackend(PGBackend):
                 rec = await ex.recover_chunks(self.codec, missing,
                                               streams)
                 out.update(rec)
-                self._note_decode(t0)
+                tr.interval("decode_rebuild", t0)
                 return out
             except Exception as e:
                 q.note_fallback("mesh decode", e)
         if gen is None or mat_for is None or q is None:
             out.update(self.codec.decode_chunks(missing, streams))
-            self._note_decode(t0)
+            tr.interval("decode_rebuild", t0)
             return out
-        mat = mat_for(present, missing)
-        src = np.stack([np.asarray(streams[i], np.uint8)
-                        for i in present])
+        with tr.section("loop_ec_host"):
+            mat = mat_for(present, missing)
+            src = np.stack([np.asarray(streams[i], np.uint8)
+                            for i in present])
         # device-candidate:ec-decode@landed the live degraded-read/rebuild
         # decode call site: awaits the cross-PG collector
         # (LANE_BUCKETS-bucketed, executor dispatch) like encodes do
         dec = await q.apply(mat, src)
         out.update({w: dec[j] for j, w in enumerate(missing)})
-        self._note_decode(t0)
+        tr.interval("decode_rebuild", t0)
         return out
-
-    def _note_decode(self, t0: float) -> None:
-        tr = self.osd.ctx.tracer
-        if tr.enabled:
-            tr.hist.hinc("decode_rebuild", time.monotonic() - t0)
 
     @property
     def my_shard(self) -> int:
@@ -969,69 +974,75 @@ class ECBackend(PGBackend):
         def _ec_size():
             return int(self.osd.store.getattr(pg.cid, soid, SIZE_XATTR))
 
-        rv, batch_ops = cls_mod.expand_write_calls(
-            self.osd.store, pg.cid, soid, m.ops,
-            read_fn=_no_data_read, size_fn=_ec_size)
-        if rv < 0:
-            return rv
-        writes = [op for op in batch_ops
-                  if op.is_write() and op.op != OP_WATCH]
-        unsupported = {OP_WRITE, OP_APPEND, OP_ZERO, OP_OMAP_SET,
-                       OP_OMAP_RM_KEYS, OP_OMAP_SET_HEADER}
-        if any(op.op in unsupported for op in writes):
-            return -errno.EOPNOTSUPP
-        deletes = any(op.op == OP_DELETE for op in writes)
-        # one txn PER SHARD, addressed at that shard's own collection
-        # (each shard osd stores under <pool>.<seed>s<shard>_head);
-        # full-object data is encoded in one TPU shot
-        from ceph_tpu.store.types import CollectionId
-        cids = {i: CollectionId.pg(pg.pool_id, pg.pgid.seed, i)
-                for i in range(self.n)}
-        shard_txns: Dict[int, Transaction] = {
-            i: Transaction() for i in range(self.n)}
-        # clone-on-write: every shard clones ITS OWN chunk object in its
-        # txn — no chunk bytes travel for the snapshot itself
-        from ceph_tpu.osd import snaps as snaps_mod
-        snaps_mod.prepare_cow(
-            pg, m.oid, m.snap_seq, m.snaps,
-            [(shard_txns[i], cids[i], soid) for i in range(self.n)])
-        # the write may have advanced the snapset: the survey cache
-        # must not serve the pre-COW row to a later read-at-snap
-        self._ss_cache.pop(m.oid, None)
-        for op in [o for o in writes if o.op == OP_ROLLBACK]:
-            try:
-                src = snaps_mod.rollback_targets(pg, m.oid, soid,
-                                                 op.offset)
-            except KeyError:
-                return -errno.ENOENT
-            if src is not None:
-                for i, t in shard_txns.items():
-                    t.remove(cids[i], soid)
-                    t.clone(cids[i], src, soid)
-        writes = [op for op in writes if op.op != OP_ROLLBACK]
-        # op tracing: guards/cls/cow so far = `prepare`; the writes loop
-        # below holds the encode awaits = `ec_encode`
-        span = m._span
-        th = self.osd.ctx.tracer.hist if span is not None else None
-        if span is not None:
-            span.cut("prepare", th)
-        from ceph_tpu.common.crc import crc32c
-        from ceph_tpu.osd.scrub import CRC_XATTR
-        empty_crc = str(crc32c(b"")).encode()
+        # op tracing: the synchronous build-up to the encode (cls, the
+        # per-shard txns, cow) as a loop section beside the chain's cut
+        tr = self.osd.ctx.tracer
+        with tr.section("loop_prepare"):
+            rv, batch_ops = cls_mod.expand_write_calls(
+                self.osd.store, pg.cid, soid, m.ops,
+                read_fn=_no_data_read, size_fn=_ec_size)
+            if rv < 0:
+                return rv
+            writes = [op for op in batch_ops
+                      if op.is_write() and op.op != OP_WATCH]
+            unsupported = {OP_WRITE, OP_APPEND, OP_ZERO, OP_OMAP_SET,
+                           OP_OMAP_RM_KEYS, OP_OMAP_SET_HEADER}
+            if any(op.op in unsupported for op in writes):
+                return -errno.EOPNOTSUPP
+            deletes = any(op.op == OP_DELETE for op in writes)
+            # one txn PER SHARD, addressed at that shard's own collection
+            # (each shard osd stores under <pool>.<seed>s<shard>_head);
+            # full-object data is encoded in one TPU shot
+            from ceph_tpu.store.types import CollectionId
+            cids = {i: CollectionId.pg(pg.pool_id, pg.pgid.seed, i)
+                    for i in range(self.n)}
+            shard_txns: Dict[int, Transaction] = {
+                i: Transaction() for i in range(self.n)}
+            # clone-on-write: every shard clones ITS OWN chunk object in its
+            # txn — no chunk bytes travel for the snapshot itself
+            from ceph_tpu.osd import snaps as snaps_mod
+            snaps_mod.prepare_cow(
+                pg, m.oid, m.snap_seq, m.snaps,
+                [(shard_txns[i], cids[i], soid) for i in range(self.n)])
+            # the write may have advanced the snapset: the survey cache
+            # must not serve the pre-COW row to a later read-at-snap
+            self._ss_cache.pop(m.oid, None)
+            for op in [o for o in writes if o.op == OP_ROLLBACK]:
+                try:
+                    src = snaps_mod.rollback_targets(pg, m.oid, soid,
+                                                     op.offset)
+                except KeyError:
+                    return -errno.ENOENT
+                if src is not None:
+                    for i, t in shard_txns.items():
+                        t.remove(cids[i], soid)
+                        t.clone(cids[i], src, soid)
+            writes = [op for op in writes if op.op != OP_ROLLBACK]
+            # op tracing: guards/cls/cow so far = `prepare`; the writes loop
+            # below holds the encode awaits = `ec_encode`
+            span = m._span
+            th = tr.hist if span is not None else None
+            if span is not None:
+                span.cut("prepare", th)
+            from ceph_tpu.common.crc import crc32c
+            from ceph_tpu.osd.scrub import CRC_XATTR
+            empty_crc = str(crc32c(b"")).encode()
         for op in writes:
             if op.op == OP_WRITEFULL:
                 chunks = await self._encode_object(op.data)
-                for i in range(self.n):
-                    t = shard_txns[i]
-                    chunk_bytes = chunks[i].tobytes()
-                    t.truncate(cids[i], soid, 0)
-                    t.write(cids[i], soid, 0, chunk_bytes)
-                    t.setattr(cids[i], soid, SIZE_XATTR,
-                              str(len(op.data)).encode())
-                    # per-shard digest (hinfo role, ECBackend.cc:1695):
-                    # scrub verifies stored bytes against this
-                    t.setattr(cids[i], soid, CRC_XATTR,
-                              str(crc32c(chunk_bytes)).encode())
+                with tr.section("loop_ec_host"):
+                    for i in range(self.n):
+                        t = shard_txns[i]
+                        chunk_bytes = chunks[i].tobytes()
+                        t.truncate(cids[i], soid, 0)
+                        t.write(cids[i], soid, 0, chunk_bytes)
+                        t.setattr(cids[i], soid, SIZE_XATTR,
+                                  str(len(op.data)).encode())
+                        # per-shard digest (hinfo role,
+                        # ECBackend.cc:1695): scrub verifies stored
+                        # bytes against this
+                        t.setattr(cids[i], soid, CRC_XATTR,
+                                  str(crc32c(chunk_bytes)).encode())
             elif op.op == OP_CREATE:
                 for i, t in shard_txns.items():
                     t.touch(cids[i], soid)
@@ -1065,21 +1076,23 @@ class ECBackend(PGBackend):
         # BEFORE the encode awaits — would hand two concurrent ops the
         # same version.  Machine-checked by devtools rule AF01.
         # awaitfree:begin ec-submit
-        version = pg.next_version()
-        entry = LogEntry(LOG_DELETE if deletes else LOG_MODIFY, m.oid,
-                         version, pg.info.last_update, m.reqid)
-        if not deletes:
-            for i, t in shard_txns.items():
-                t.setattr(cids[i], soid, VERSION_XATTR,
-                          version.to_bytes())
-        # local shard applies in memory now; its durability overlaps
-        # the sub-op fan-out (commit pipelining), and pglog
-        # last_complete advances from the commit callback
-        my = self.my_shard
-        local_txn = shard_txns.get(my, Transaction())
-        pg.append_log(local_txn, entry)
-        commit_fut = self._queue_txn(
-            local_txn, on_commit=lambda: pg.complete_to(version))
+        with tr.section("loop_store_apply"):
+            version = pg.next_version()
+            entry = LogEntry(LOG_DELETE if deletes else LOG_MODIFY,
+                             m.oid, version, pg.info.last_update,
+                             m.reqid)
+            if not deletes:
+                for i, t in shard_txns.items():
+                    t.setattr(cids[i], soid, VERSION_XATTR,
+                              version.to_bytes())
+            # local shard applies in memory now; its durability
+            # overlaps the sub-op fan-out (commit pipelining), and
+            # pglog last_complete advances from the commit callback
+            my = self.my_shard
+            local_txn = shard_txns.get(my, Transaction())
+            pg.append_log(local_txn, entry)
+            commit_fut = self._queue_txn(
+                local_txn, on_commit=lambda: pg.complete_to(version))
         if span is not None:
             span.cut("store_apply", th)
         # fan out to the other shards; each position also goes to its
@@ -1088,42 +1101,47 @@ class ECBackend(PGBackend):
         # log-entry payload is shared across every sub-op and each
         # position's txn payload across its acting+up targets, so over
         # TCP each body encodes at most once; local hops encode nothing
-        log_payload = LazyPayload.seal(entry)
-        txn_payloads: Dict[int, LazyPayload] = {}
-        tid = self.osd.next_tid()
-        peers = set()
-        sends = []
-        for i, osd_id in enumerate(pg.acting):
-            targets = {osd_id}
-            if i < len(pg.up):
-                targets.add(pg.up[i])
-            for t_osd in targets:
-                # NOTE: no position filter here — even at the primary's
-                # own position, the up-side backfill target must get the
-                # write; only self is excluded
-                if t_osd == self.osd.whoami or t_osd < 0 \
-                        or t_osd == CRUSH_ITEM_NONE:
+        # (mesh mode's in-process deliver can run the target's apply
+        # inline: its loop_store_apply then lies inside this section)
+        with tr.section("loop_submit"):
+            log_payload = LazyPayload.seal(entry)
+            txn_payloads: Dict[int, LazyPayload] = {}
+            tid = self.osd.next_tid()
+            peers = set()
+            sends = []
+            for i, osd_id in enumerate(pg.acting):
+                targets = {osd_id}
+                if i < len(pg.up):
+                    targets.add(pg.up[i])
+                for t_osd in targets:
+                    # NOTE: no position filter here — even at the
+                    # primary's own position, the up-side backfill
+                    # target must get the write; only self is excluded
+                    if t_osd == self.osd.whoami or t_osd < 0 \
+                            or t_osd == CRUSH_ITEM_NONE:
+                        continue
+                    peers.add(t_osd)
+                    tp = txn_payloads.get(i)
+                    if tp is None:
+                        tp = txn_payloads[i] = LazyPayload.seal(
+                            shard_txns[i])
+                    sub = MOSDECSubOpWrite(
+                        pg.pgid.with_shard(i), tid, tp, log_payload,
+                        version, self.osd.osdmap.epoch)
+                    if span is not None:
+                        sub.trace_id = span.trace_id
+                        sub.span_id = span.span_id
+                    sends.append((t_osd, sub))
+            fut = self._ack_init(tid, peers)
+            ex = getattr(self.osd, "mesh_exec", None)
+            for osd_id, msg in sends:
+                # mesh mode: co-located shard OSDs take the sub-op
+                # (chunk bytes included) in process; acks still ride
+                # the messenger
+                if ex is not None and ex.deliver(osd_id, msg,
+                                                 self.osd.whoami):
                     continue
-                peers.add(t_osd)
-                tp = txn_payloads.get(i)
-                if tp is None:
-                    tp = txn_payloads[i] = LazyPayload.seal(shard_txns[i])
-                sub = MOSDECSubOpWrite(
-                    pg.pgid.with_shard(i), tid, tp, log_payload,
-                    version, self.osd.osdmap.epoch)
-                if span is not None:
-                    sub.trace_id = span.trace_id
-                    sub.span_id = span.span_id
-                sends.append((t_osd, sub))
-        fut = self._ack_init(tid, peers)
-        ex = getattr(self.osd, "mesh_exec", None)
-        for osd_id, msg in sends:
-            # mesh mode: co-located shard OSDs take the sub-op (chunk
-            # bytes included) in process; acks still ride the messenger
-            if ex is not None and ex.deliver(osd_id, msg,
-                                             self.osd.whoami):
-                continue
-            self.osd.send_osd(osd_id, msg)
+                self.osd.send_osd(osd_id, msg)
         if span is not None:
             span.cut("submit", th)
         # awaitfree:end ec-submit
@@ -1532,8 +1550,9 @@ class ECBackend(PGBackend):
             # collector, so concurrent recovery-window reads fold
             # their decodes into single launches like writes do
             decoded = await self._decode_shards(range(self.k), streams)
-            data = b"".join(np.asarray(decoded[i]).tobytes()
-                            for i in range(self.k))
+            with self.osd.ctx.tracer.section("loop_ec_host"):
+                data = b"".join(np.asarray(decoded[i]).tobytes()
+                                for i in range(self.k))
         except (ErasureCodeError, ValueError):
             # ValueError: mixed-generation chunk lengths — undecodable
             return None
@@ -1767,40 +1786,42 @@ class ECBackend(PGBackend):
             # extent slots like any other terminal outcome
             extents.release_message(m)
             return
-        rt = self._repl_trace(m)
-        # copy discipline: mutable txn copy, shared immutable entry
-        # (see ReplicatedBackend.handle_sub_message)
-        txn = m.txn()
-        entry = m.log_entry()
-        advance = None
-        if pg.log.head < entry.version:
-            pg.log.append(entry)
-            pg.note_reqid(entry)
-            pg.info.last_update = entry.version
-            if not pg.missing:
-                # a copy still owed recovery pushes must keep its
-                # honest last_complete cursor, or the gap hides
-                advance = entry.version
-        pg.save_meta_log(txn, entry)
-        src = int(m.src_name.id)
-        reply = MOSDECSubOpWriteReply(pg.pgid, m.tid, 0,
-                                      self.my_shard, self.osd.whoami)
-        if rt is not None:
-            rt.applied()
-
-        def _committed():
-            # EC sub-op ack + last_complete ride the commit callback
-            # in submission order (see MOSDRepOp above); extents
-            # retire here and the ack coalesces per drained burst
-            extents.release_message(m)
-            if advance is not None:
-                pg.complete_to(advance)
+        with self.osd.ctx.tracer.section("loop_store_apply"):
+            rt = self._repl_trace(m)
+            # copy discipline: mutable txn copy, shared immutable entry
+            # (see ReplicatedBackend.handle_sub_message)
+            txn = m.txn()
+            entry = m.log_entry()
+            advance = None
+            if pg.log.head < entry.version:
+                pg.log.append(entry)
+                pg.note_reqid(entry)
+                pg.info.last_update = entry.version
+                if not pg.missing:
+                    # a copy still owed recovery pushes must keep its
+                    # honest last_complete cursor, or the gap hides
+                    advance = entry.version
+            pg.save_meta_log(txn, entry)
+            src = int(m.src_name.id)
+            reply = MOSDECSubOpWriteReply(pg.pgid, m.tid, 0,
+                                          self.my_shard, self.osd.whoami)
             if rt is not None:
-                rt.committed()
-            self.osd.queue_rep_ack(src, reply)
+                rt.applied()
 
-        self.osd.store.queue_transactions([txn],
-                                          on_commit=_committed)
+            def _committed():
+                # EC sub-op ack + last_complete ride the commit callback
+                # in submission order (see MOSDRepOp above); extents
+                # retire here and the ack coalesces per drained burst
+                extents.release_message(m)
+                if advance is not None:
+                    pg.complete_to(advance)
+                if rt is not None:
+                    rt.committed()
+                self.osd.queue_rep_ack(src, reply)
+
+            self.osd.store.queue_transactions([txn],
+                                              on_commit=_committed)
+
     def _handle_ec_sub_read(self, m) -> None:
         from ceph_tpu.osd.pglog import LB_MAX
         pg = self.pg

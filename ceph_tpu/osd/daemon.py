@@ -275,6 +275,9 @@ class OSD(Dispatcher):
         # it buys.  shards=1 keeps today's threaded handoff.
         if self.shards.enabled:
             self.store.ack_on_apply = True
+            # the inline commit groups then run on this daemon's loop:
+            # its tracer names them (loop_store_commit)
+            self.store.tracer = self.ctx.tracer
         # the EC queue's backend is decided ONCE, before this OSD takes
         # ops; osd_ec_batch_device=on without an accelerator raises
         # here and fails the start
@@ -921,7 +924,11 @@ class OSD(Dispatcher):
         shard (routed by ms_dispatch / the messenger's shard
         classifier), so everything it touches stays shard-local."""
         if isinstance(m, MOSDOp):
-            self._client_op(m)
+            # op tracing: the hand-off of a delivered client op into
+            # its PG's queue, as a loop section (the sub-op branch
+            # below is the store's: loop_store_apply, in the backend)
+            with self.ctx.tracer.section("loop_dispatch"):
+                self._client_op(m)
             return
         if isinstance(m, (MOSDRepOp, MOSDECSubOpWrite, MOSDECSubOpRead)):
             pg = self._pg_for(m.pgid)
@@ -946,10 +953,11 @@ class OSD(Dispatcher):
             # acks resolve futures the PG worker awaits: handle off
             # the op queue the worker is blocked on (the shard pump is
             # a separate task, so delivery stays prompt)
-            pg = self._pg_for_reply(
-                m.pgid, lambda i: m.tid in i.backend._inflight)
-            if pg is not None:
-                pg.backend.handle_reply(m)
+            with self.ctx.tracer.section("loop_dispatch"):
+                pg = self._pg_for_reply(
+                    m.pgid, lambda i: m.tid in i.backend._inflight)
+                if pg is not None:
+                    pg.backend.handle_reply(m)
             return
         if isinstance(m, MPGQuery):
             pg = self._pg_for(m.pgid) or self._load_stray_pg(m.pgid)

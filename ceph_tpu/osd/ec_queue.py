@@ -49,13 +49,17 @@ def _bucket(n: int) -> int:
 
 
 class _Req:
-    __slots__ = ("key", "mat", "chunks", "fut")
+    __slots__ = ("key", "mat", "chunks", "fut", "t_apply", "t_done")
 
-    def __init__(self, key, mat, chunks, fut):
+    def __init__(self, key, mat, chunks, fut, t_apply):
         self.key = key
         self.mat = mat
         self.chunks = chunks        # [k, L] uint8
         self.fut = fut
+        # op tracing (tracer stamps; 0.0 while tracing is off):
+        # apply() entry, and the executor's last instant on the group
+        self.t_apply = t_apply
+        self.t_done = 0.0
 
 
 class ECBatchQueue:
@@ -158,27 +162,37 @@ class ECBatchQueue:
         kernel when the device isn't worth it (small lone request) or
         isn't this queue's backend (mode=off, auto without an
         accelerator)."""
+        tr = self.ctx.tracer
+        t_apply = tr.stamp()
         chunks = np.ascontiguousarray(chunks, np.uint8)
         nbytes = chunks.shape[0] * chunks.shape[1]
         if (not self.resolve_backend()
                 or (nbytes < self.min_device_bytes
                     and not self._pending)):
-            return self._host_apply(mat, chunks, nbytes)
+            out = self._host_apply(mat, chunks, nbytes)
+            tr.interval("seam_apply", t_apply)
+            return out
         loop = asyncio.get_running_loop()
         if self._wake is None:
             self._wake = asyncio.Event()
         await self._pending_throttle.get(nbytes)
         fut = loop.create_future()
-        self._pending.append(
-            _Req((mat.shape, mat.tobytes()),
-                 np.ascontiguousarray(mat, np.uint8), chunks, fut))
+        req = _Req((mat.shape, mat.tobytes()),
+                   np.ascontiguousarray(mat, np.uint8), chunks, fut,
+                   t_apply)
+        self._pending.append(req)
         self._pending_bytes += nbytes
         self.perf.tinc("pending_depth", len(self._pending))
         self._wake.set()
         if self._task is None or self._task.done():
             self._task = loop.create_task(self._collector())
         try:
-            return await fut
+            out = await fut
+            # how long the finished result waited for the loop, and
+            # the whole await the seam's other stages tile
+            tr.interval("seam_resume", req.t_done)
+            tr.interval("seam_apply", t_apply)
+            return out
         finally:
             self._pending_throttle.put(nbytes)
 
@@ -271,37 +285,47 @@ class ECBatchQueue:
         import jax
         import jax.numpy as jnp
         from ceph_tpu.ec.kernel import matrix_apply
+        tr = self.ctx.tracer
+        if tr.enabled:
+            # the executor takes the group: the collector's window and
+            # the wait behind the groups launched before it end here
+            for r in reqs:
+                tr.interval("seam_pending", r.t_apply)
         mat = reqs[0].mat
         lens = [r.chunks.shape[1] for r in reqs]
         total = sum(lens)
         k = reqs[0].chunks.shape[0]
-        folded = np.zeros((k, total), np.uint8)
-        off = 0
-        for r in reqs:
-            folded[:, off:off + r.chunks.shape[1]] = r.chunks
-            off += r.chunks.shape[1]
+        with tr.section("seam_fold"):
+            folded = np.zeros((k, total), np.uint8)
+            off = 0
+            for r in reqs:
+                folded[:, off:off + r.chunks.shape[1]] = r.chunks
+                off += r.chunks.shape[1]
         ap = matrix_apply(mat)
         cap = LANE_BUCKETS[-1]
         # device-candidate:ec-dispatch@landed the live executor-side launch:
         # LANE_BUCKETS-bucketed windows over the folded group, staged
         # once, fetched once (the shape every candidate above adopts)
         # XFER17 staging transfer: one h2d for the whole folded group
-        dev = jax.device_put(folded)
-        parts = []
-        for w0 in range(0, total, cap):
-            seg = dev[:, w0:w0 + cap]
-            pad = _bucket(seg.shape[1]) - seg.shape[1]
-            if pad:
-                seg = jnp.pad(seg, ((0, 0), (0, pad)))
-            parts.append(
-                ap.device_call(seg)[:, :min(cap, total - w0)])
-            self.perf.inc("device_launches")
-        out_dev = parts[0] if len(parts) == 1 \
-            else jnp.concatenate(parts, axis=1)
+        with tr.section("seam_h2d"):
+            dev = jax.device_put(folded)
+        with tr.section("seam_launch"):
+            parts = []
+            for w0 in range(0, total, cap):
+                seg = dev[:, w0:w0 + cap]
+                pad = _bucket(seg.shape[1]) - seg.shape[1]
+                if pad:
+                    seg = jnp.pad(seg, ((0, 0), (0, pad)))
+                parts.append(
+                    ap.device_call(seg)[:, :min(cap, total - w0)])
+                self.perf.inc("device_launches")
+            out_dev = parts[0] if len(parts) == 1 \
+                else jnp.concatenate(parts, axis=1)
         # device-sync:begin group result fetch: one d2h for the whole
         # folded batch, on the ec-device executor thread — the event
         # loop only awaits run_in_executor
-        out = np.asarray(out_dev)
+        with tr.section("seam_d2h"):
+            out = np.asarray(out_dev)
         # device-sync:end
         self.perf.inc("device_requests", len(reqs))
         self.perf.inc("device_bytes", k * total)
@@ -312,9 +336,15 @@ class ECBatchQueue:
         from ceph_tpu.common import devstats
         devstats.note_bytes("ec_apply", k * total, device=True)
         self.perf.tinc("batch_fill", len(reqs))
-        res = []
-        off = 0
-        for ln in lens:
-            res.append(np.ascontiguousarray(out[:, off:off + ln]))
-            off += ln
+        with tr.section("seam_split"):
+            res = []
+            off = 0
+            for ln in lens:
+                res.append(np.ascontiguousarray(out[:, off:off + ln]))
+                off += ln
+        # the results are finished; from here they wait for the loop
+        # (the collector's resume, then each awaiter's)
+        t_done = tr.stamp()
+        for r in reqs:
+            r.t_done = t_done
         return res

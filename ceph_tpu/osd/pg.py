@@ -1695,7 +1695,8 @@ class PG:
             await self._do_client_op_inner(m)
         finally:
             # op done: release tracker + intake budget (backpressure)
-            self._finish_client_op(m)
+            with self.osd.ctx.tracer.section("loop_reply"):
+                self._finish_client_op(m)
 
     async def _do_client_op_inner(self, m: MOSDOp) -> None:
         if not self.is_primary():
@@ -1795,11 +1796,13 @@ class PG:
                     m._span.cut("op_exec", self.osd.ctx.tracer.hist)
         except PGIntervalChanged:
             result = -errno.EAGAIN
-        reply = MOSDOpReply(m.tid, result, m.ops, self.osd.osdmap.epoch)
-        if m._span is not None:
-            reply.trace_id = m._span.trace_id
-            reply.span_id = m._span.span_id
-        self.osd.reply_to(m, reply)
+        with self.osd.ctx.tracer.section("loop_reply"):
+            reply = MOSDOpReply(m.tid, result, m.ops,
+                                self.osd.osdmap.epoch)
+            if m._span is not None:
+                reply.trace_id = m._span.trace_id
+                reply.span_id = m._span.span_id
+            self.osd.reply_to(m, reply)
 
     # -------------------------------------------------------- watch/notify
     def handle_watch(self, m, op) -> None:

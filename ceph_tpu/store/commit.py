@@ -95,7 +95,8 @@ class KVSyncThread:
                  queue_max: int = QUEUE_MAX,
                  gather_window: float = 0.0,
                  auto_tune: bool = True,
-                 ack_on_apply: bool = False):
+                 ack_on_apply: bool = False,
+                 tracer=None):
         # unique per instance: co-located stores of the same backend
         # (a 4-OSD in-process cluster = four "memstore_commit"s) must
         # be distinguishable in the schedule explorer's commit-order
@@ -121,6 +122,10 @@ class KVSyncThread:
         #: see start().  Off = today's threaded behavior, bit-for-bit
         #: (osd_op_num_shards=1 and standalone stores keep it off).
         self.ack_on_apply = ack_on_apply
+        #: the mounting daemon's op tracer, or None: an inline commit
+        #: group is synchronous work on that daemon's event loop, and
+        #: with tracing on it is a loop section like the rest
+        self.tracer = tracer
         #: adapt the window to the measured barrier latency (EWMA),
         #: clamped to [0, 4x the static value].  Only engages on stores
         #: with a REAL barrier hook — a RAM store has no fsync signal
@@ -253,12 +258,16 @@ class KVSyncThread:
         # gil-atomic:end
         if not recs:
             return
-        if self._inline:
-            # sim mode: the loop-pass cork IS the commit group; no
-            # thread handoff, no gather linger — deterministic
+        if not self._inline:
+            self._q.put(recs)
+        # inline (sim / ack-on-apply) mode: the loop-pass cork IS the
+        # commit group; no thread handoff, no gather linger —
+        # deterministic
+        elif self.tracer is None:
             self._run_group(recs)
         else:
-            self._q.put(recs)
+            with self.tracer.section("loop_store_commit"):
+                self._run_group(recs)
 
     def _flush_staged(self) -> None:
         """Ship the CALLING loop's corked items now (flush()/stop()

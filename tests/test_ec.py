@@ -116,6 +116,24 @@ def test_pallas_fused_kernel_matches_host_apply():
         assert np.array_equal(want, got), (r, k, L)
 
 
+def test_fused_kernel_keeps_the_trace_name_the_benchmark_matches():
+    # The benchmark finds the kernel's device events by a part of the
+    # op's name in the profiler's trace (EC_APPLY_MATCH), and XLA names
+    # that op after the jitted entry: the name is a constant of
+    # ec/kernel.py, and it has to reach the lowered program.  A
+    # refactor that loses it nulls kernel.ec_apply_busy/_roofline.
+    import jax.numpy as jnp
+    from benchmark.readers import EC_APPLY_MATCH
+    from ceph_tpu.ec import kernel
+    assert all(part in kernel.EC_APPLY_TRACE_NAME
+               for part in EC_APPLY_MATCH)
+    bm = jnp.zeros((16, 32), jnp.int8)
+    data = jnp.zeros((4, 32768), jnp.uint8)
+    hlo = kernel._apply_bitmatrix_pallas_jit.lower(
+        bm, data, True, 32768, "bc", "or").as_text()
+    assert f"jit_{kernel.EC_APPLY_TRACE_NAME}" in hlo
+
+
 # -- codec matrices (reference-style per-plugin parameter sweeps) ------------
 
 PROFILES = [

@@ -195,6 +195,100 @@ def test_device_failure_falls_back_to_host(monkeypatch):
     asyncio.run(run())
 
 
+# -------------------------------------------- op tracing at the seam
+
+EXEC_SECTIONS = ("seam_fold", "seam_h2d", "seam_launch", "seam_d2h",
+                 "seam_split")
+
+
+def _seam_sums(q):
+    return {name: (h.count, h.sum) for name, h in
+            q.ctx.tracer.hist.histograms().items()
+            if name.startswith("seam_")}
+
+
+@pytest.mark.parametrize("grouping", ["own_groups", "one_group"])
+def test_seam_stages_tile_the_apply_await(grouping):
+    """With op_tracing on, a device request's trip is tiled by
+    seam_pending (enqueue -> the executor takes its group), the five
+    sections on the ec-device thread, and seam_resume (the executor's
+    last instant -> the awaiter runs again): together within 10% of
+    seam_apply.  Sections are per GROUP: when n requests share one
+    launch each of them waits through the same sections, so against the
+    per-request intervals they weigh n."""
+    n = 6
+
+    async def run():
+        q = make_queue(min_device_bytes=256, window_ms=2.0)
+        q.ctx.config.set("op_tracing", True)
+        rng = np.random.default_rng(11)
+        if grouping == "own_groups":
+            # distinct matrices: one group, one launch per request,
+            # each waiting behind the groups launched before it
+            mats = [rng.integers(1, 256, (2, 4), dtype=np.uint8)
+                    for _ in range(n)]
+        else:
+            mats = [gen_mat()] * n
+        ins = [rng.integers(0, 256, (4, 1 << 18), dtype=np.uint8)
+               for _ in range(n)]
+
+        async def burst():
+            outs = await asyncio.gather(
+                *[q.apply(m, c) for m, c in zip(mats, ins)])
+            for m, c, o in zip(mats, ins, outs):
+                assert np.array_equal(o, gf256.host_apply(m, c))
+
+        await burst()                      # compiles, imports
+        before = _seam_sums(q)
+        launches = q.perf.dump()["device_launches"]
+        await burst()
+        after = _seam_sums(q)
+        d = {k: (after[k][0] - before[k][0], after[k][1] - before[k][1])
+             for k in after}
+        groups = q.perf.dump()["device_launches"] - launches
+        assert groups == (n if grouping == "own_groups" else 1)
+        for name in ("seam_apply", "seam_pending", "seam_resume"):
+            assert d[name][0] == n, (name, d[name])
+        for name in EXEC_SECTIONS:
+            assert d[name][0] == groups, (name, d[name])
+        weight = n // groups
+        tiled = d["seam_pending"][1] + d["seam_resume"][1] \
+            + weight * sum(d[name][1] for name in EXEC_SECTIONS)
+        total = d["seam_apply"][1]
+        assert abs(tiled - total) <= 0.10 * total, (tiled, total, d)
+        await q.stop()
+    asyncio.run(run())
+
+
+def test_lone_small_request_on_the_host_kernel_records_seam_apply_only():
+    async def run():
+        q = make_queue(min_device_bytes=1 << 20)
+        q.ctx.config.set("op_tracing", True)
+        mat = gen_mat()
+        c = np.arange(4 * 512, dtype=np.uint8).reshape(4, 512)
+        out = await q.apply(mat, c)
+        assert np.array_equal(out, gf256.host_apply(mat, c))
+        assert q.perf.dump()["host_requests"] == 1
+        sums = _seam_sums(q)
+        assert set(sums) == {"seam_apply"} and sums["seam_apply"][0] == 1
+        await q.stop()
+    asyncio.run(run())
+
+
+def test_seam_records_nothing_with_tracing_off():
+    from ceph_tpu.common.tracer import STAGE_GROUP
+
+    async def run():
+        q = make_queue(min_device_bytes=256)
+        c = np.arange(4 * 4096, dtype=np.uint8).reshape(4, -1) \
+            .astype(np.uint8)
+        await asyncio.gather(q.apply(gen_mat(), c), q.apply(gen_mat(), c))
+        assert q.perf.dump()["device_requests"] == 2
+        assert STAGE_GROUP not in q.ctx.perf._groups
+        await q.stop()
+    asyncio.run(run())
+
+
 def test_ec_pool_writes_ride_the_device_queue():
     """E2E: cluster with osd_ec_batch_device=force — concurrent EC writes
     coalesce on the primary's device queue and read back intact."""

@@ -1041,6 +1041,19 @@ def test_stage18_undeclared_stage_name_trips():
     assert [v.rule for v in vio] == ["STAGE18"], vio
     ok = attr.replace("ringe_wait", "ring_wait")
     assert lint_project_sources([("osd/fixture.py", ok)]) == []
+    # the tracer's sections and intervals name stages the same way: a
+    # misspelt section falls out of every reader that sums loop_* / seam_*
+    for call, bad, good in (
+            ("with self.tracer.section(\"{}\"):\n        pass\n",
+             "loop_ec_hots", "loop_ec_host"),
+            ("self.tracer.interval(\"{}\", t0)\n",
+             "seam_pendign", "seam_pending")):
+        body = "def _f(self, t0):\n    " + call
+        vio = lint_project_sources([("osd/fixture.py",
+                                     body.format(bad))])
+        assert [v.rule for v in vio] == ["STAGE18"], vio
+        assert lint_project_sources([("osd/fixture.py",
+                                      body.format(good))]) == []
     # waiver escape hatch
     waived = src.replace(
         "    m._span.cut(",

@@ -122,6 +122,147 @@ def test_tracer_disabled_by_default_and_off_path():
     assert ctx.tracer.start() is None
 
 
+def test_section_and_interval_are_off_path_when_disabled(monkeypatch):
+    """Off, a section site is one attribute load and a no-op `with`:
+    the one shared object, no clock read, no histogram group, and
+    stamp/interval read no clock and record nothing either."""
+    from ceph_tpu.common.tracer import STAGE_GROUP
+    ctx = Context("osd.7")
+    tr = ctx.tracer
+
+    def no_clock():
+        raise AssertionError("clock read on the off path")
+
+    monkeypatch.setattr(tracer_mod.time, "monotonic", no_clock)
+    monkeypatch.setattr(tracer_mod.time, "thread_time", no_clock)
+    a, b = tr.section("loop_ec_host"), tr.section("seam_launch")
+    assert a is b is tracer_mod._NO_SECTION
+    with a as got:
+        assert got is None
+    assert tr.stamp() == 0.0
+    tr.interval("seam_apply", 0.0)
+    tr.interval("seam_apply", 12.5)                # switched off since
+    assert STAGE_GROUP not in ctx.perf._groups
+
+
+def test_tracing_on_in_a_process_without_jax_imports_none():
+    """A lane worker or a CPU-pinned daemon traces without the
+    profiler: a section there must not be what imports jax."""
+    import subprocess
+    code = (
+        "import sys\n"
+        "from ceph_tpu.common.context import Context\n"
+        "ctx = Context('osd.1')\n"
+        "ctx.config.set('op_tracing', True)\n"
+        "with ctx.tracer.section('loop_submit'):\n"
+        "    pass\n"
+        "t0 = ctx.tracer.stamp()\n"
+        "ctx.tracer.interval('seam_apply', t0)\n"
+        "d = ctx.perf.dump()['op_stages']\n"
+        "assert d['loop_submit']['count'] == 1, d\n"
+        "assert d['seam_apply']['count'] == 1, d\n"
+        "assert 'jax' not in sys.modules\n")
+    repo = __file__.rsplit("/", 2)[0]
+    p = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
+
+
+def test_section_records_histogram_last_stage_and_annotation():
+    """On: one name for the histogram key, the last-stage mark and the
+    profiler annotation; nests under an enclosing TraceAnnotation
+    without error when no profiler runs."""
+    import jax
+    ctx = Context("osd.8")
+    ctx.config.set("op_tracing", True)
+    tr = ctx.tracer
+    with jax.profiler.TraceAnnotation("outer"):
+        sec = tr.section("loop_store_apply")
+        assert sec is not tracer_mod._NO_SECTION
+        with sec:
+            time.sleep(0.003)
+            assert isinstance(sec.ann, jax.profiler.TraceAnnotation)
+    h = tr.hist.histograms()["loop_store_apply"]
+    assert h.count == 1 and 0.003 <= h.sum < 0.1
+    assert tracer_mod.last_stage() == "loop_store_apply"
+    # an exception leaves through the section and is still recorded
+    with pytest.raises(KeyError):
+        with tr.section("loop_store_apply"):
+            raise KeyError("x")
+    assert tr.hist.histograms()["loop_store_apply"].count == 2
+    # every section and interval name is a declared aux stage
+    for name in tracer_mod.SEAM_STAGES + tracer_mod.LOOP_STAGES:
+        assert name in tracer_mod.AUX_STAGES
+        assert name not in CHAIN_STAGES
+
+
+def test_interval_records_from_its_stamp():
+    ctx = Context("osd.9")
+    ctx.config.set("op_tracing", True)
+    tr = ctx.tracer
+    t0 = tr.stamp()
+    assert t0 > 0.0
+    time.sleep(0.002)
+    tr.interval("seam_pending", t0)
+    t1 = time.monotonic()
+    tr.interval("seam_resume", 0.0)        # never stamped: not recorded
+    hs = tr.hist.histograms()
+    assert hs["seam_pending"].count == 1
+    assert 0.002 <= hs["seam_pending"].sum <= t1 - t0
+    assert "seam_resume" not in hs
+
+
+def test_loop_sampler_one_per_loop_records_wall_and_cpu():
+    """The first enabled tracer on a loop starts its sampler; a second
+    tracer on the same loop starts none; the ratio of the two stages is
+    the loop's CPU share; switching tracing off ends it."""
+    first, second = Context("osd.10"), Context("osd.11")
+    for c in (first, second):
+        c.config.set("op_tracing", True)
+
+    async def run():
+        loop = asyncio.get_running_loop()
+        with first.tracer.section("loop_submit"):
+            pass
+        with second.tracer.section("loop_submit"):
+            pass
+        assert second.tracer.start() is not None
+        assert loop in tracer_mod._sampled_loops
+        spin_until = time.monotonic() + 0.15
+        while time.monotonic() < spin_until:
+            pass                                    # burn the loop's CPU
+        await asyncio.sleep(0.3)                    # then let it wait
+        hs = first.tracer.hist.histograms()
+        assert 2 <= hs["loop_wall"].count == hs["loop_cpu"].count
+        assert 0.1 <= hs["loop_cpu"].sum <= hs["loop_wall"].sum
+        assert hs["loop_cpu"].sum < 0.8 * hs["loop_wall"].sum
+        assert "loop_wall" not in second.tracer.hist.histograms()
+        first.config.set("op_tracing", False)
+        await asyncio.sleep(2.5 * tracer_mod.LOOP_SAMPLE_PERIOD)
+        assert loop not in tracer_mod._sampled_loops
+        n = hs["loop_wall"].count
+        await asyncio.sleep(2.5 * tracer_mod.LOOP_SAMPLE_PERIOD)
+        assert first.tracer.hist.histograms()["loop_wall"].count == n
+        # the next enabled tracer on the loop takes over
+        with second.tracer.section("loop_submit"):
+            pass
+        await asyncio.sleep(1.5 * tracer_mod.LOOP_SAMPLE_PERIOD)
+        assert second.tracer.hist.histograms()["loop_wall"].count >= 1
+        # off the loop (an executor thread) a section starts no sampler
+        second.config.set("op_tracing", False)
+        await asyncio.sleep(1.5 * tracer_mod.LOOP_SAMPLE_PERIOD)
+        first.config.set("op_tracing", True)
+
+        def off_loop():
+            with first.tracer.section("seam_fold"):
+                pass
+        await loop.run_in_executor(None, off_loop)
+        assert loop not in tracer_mod._sampled_loops
+        assert first.tracer.hist.histograms()["seam_fold"].count == 1
+
+    asyncio.run(run())
+
+
 # --------------------------------------------------- wire propagation
 
 def test_mosdop_trace_header_roundtrip_and_old_version_decode():
@@ -332,6 +473,93 @@ def test_admin_socket_tracer_commands():
         await cl.stop()
 
     asyncio.run(run())
+
+
+def test_loop_sections_never_nest_and_seam_sections_run_off_the_loop(
+        monkeypatch):
+    """The rule the readers lean on, on a live EC mini-cluster through
+    the device seam: `loop_*` sections run on the loop thread and never
+    inside one another (their sum is loop time with a name, counted
+    once); the `seam_*` sections run on the ec-device thread; and the
+    loop sampler's two stages are there beside them."""
+    import threading
+    from ceph_tpu.qa.cluster import Cluster, make_ctx
+
+    loop_thread = threading.get_ident()
+    open_loop = []
+    seen = {}
+    faults = []
+    real_enter = tracer_mod._Section.__enter__
+    real_exit = tracer_mod._Section.__exit__
+
+    def enter(self):
+        on_loop = threading.get_ident() == loop_thread
+        seen.setdefault(self.name, set()).add(on_loop)
+        if self.name.startswith("loop_"):
+            if open_loop:
+                faults.append(f"{self.name} inside {open_loop[-1]}")
+            open_loop.append(self.name)
+        return real_enter(self)
+
+    def exit_(self, *exc):
+        if self.name.startswith("loop_"):
+            open_loop.pop()
+        return real_exit(self, *exc)
+
+    monkeypatch.setattr(tracer_mod._Section, "__enter__", enter)
+    monkeypatch.setattr(tracer_mod._Section, "__exit__", exit_)
+
+    def ctx_f(name):
+        c = make_ctx(name)
+        c.config.set("ms_local_delivery", True)
+        c.config.set("op_tracing", True)
+        c.config.set("osd_op_num_shards", 4)
+        c.config.set("osd_shard_threads", False)    # the one-loop plane
+        c.config.set("osd_ec_batch_device", "force")
+        c.config.set("osd_ec_batch_min_bytes", 1024)
+        return c
+
+    async def run():
+        cl = Cluster(ctx_factory=ctx_f)
+        admin = await cl.start(4)
+        await admin.pool_create("ecs", pg_num=4, pool_type="erasure",
+                                k=2, m=1)
+        await admin.pool_create("rep", pg_num=4)
+        io, rio = admin.open_ioctx("ecs"), admin.open_ioctx("rep")
+        blobs = {f"o{i}": bytes([i + 1]) * (8192 + 64 * i)
+                 for i in range(12)}
+        await asyncio.gather(*[io.write_full(k, v)
+                               for k, v in blobs.items()])
+        await asyncio.gather(*[rio.write_full(k, v)
+                               for k, v in blobs.items()])
+        for k, v in blobs.items():
+            assert await io.read(k) == v
+        await asyncio.sleep(2.5 * tracer_mod.LOOP_SAMPLE_PERIOD)
+        merged = cl.stage_histograms()
+        await cl.stop()
+        return merged
+
+    merged = asyncio.run(run())
+    assert not faults, faults[:5]
+    assert not open_loop
+    for name in ("loop_client", "loop_dispatch", "loop_prepare",
+                 "loop_ec_host", "loop_store_apply", "loop_store_commit",
+                 "loop_submit", "loop_reply"):
+        assert seen[name] == {True}, (name, seen[name])
+    for name in ("seam_fold", "seam_h2d", "seam_launch", "seam_d2h",
+                 "seam_split"):
+        assert seen[name] == {False}, (name, seen[name])
+    for name in list(seen) + ["seam_apply", "seam_pending",
+                              "seam_resume", "loop_wall", "loop_cpu"]:
+        assert merged[name].count > 0, name
+    # 12 EC writes: one split and one shard-txn build each, and one
+    # assembly per read; every write of both pools applies at its
+    # primary and at each replica / shard (a traced sub-op's apply
+    # records repl_apply)
+    assert merged["loop_ec_host"].count == 12 * 2 + 12
+    assert merged["loop_submit"].count == 24
+    assert merged["loop_store_apply"].count == \
+        24 + merged["repl_apply"].count
 
 
 def test_per_daemon_disable_drops_foreign_spans():
