@@ -2,11 +2,15 @@
 
 Reference parity: common/crc32c.h — the digest used for chunk/object
 integrity (ECBackend hash info, scrub compares).  Uses the native
-slicing-by-8 kernel (native/src/native.cc) when built; a table fallback
-keeps pure-python environments working with identical digests.
+kernel (native/src/native.cc) when built: the CPU's CRC32C instruction
+where the build machine has it, slicing-by-8 otherwise (crc32c_impl()
+says which).  The byte-at-a-time table below keeps pure-python
+environments working with identical digests.
 """
 
 from __future__ import annotations
+
+from ceph_tpu import native
 
 _TABLE = None
 
@@ -24,10 +28,20 @@ def _table():
     return _TABLE
 
 
-def crc32c(data: bytes, crc: int = 0) -> int:
-    from ceph_tpu import native
+def crc32c_impl() -> str:
+    """The path crc32c takes in this process."""
+    return native.crc32c_impl() if native.available() else "python"
+
+
+def crc32c(data, crc: int = 0) -> int:
+    if not isinstance(data, (bytes, bytearray, memoryview)):
+        data = bytes(data)      # a lane's lazy ExtentRef materialises
     if native.available():
-        return native.crc32c(bytes(data), crc)
+        return native.crc32c(data, crc)
+    return crc32c_python(data, crc)
+
+
+def crc32c_python(data, crc: int = 0) -> int:
     t = _table()
     c = crc ^ 0xFFFFFFFF
     for b in data:

@@ -87,6 +87,10 @@ def _bind(lib: ctypes.CDLL) -> Optional[ctypes.CDLL]:
         u32p = ctypes.POINTER(ctypes.c_uint32)
         lib.ceph_crc32c.restype = ctypes.c_uint32
         lib.ceph_crc32c.argtypes = [ctypes.c_uint32, u8p, ctypes.c_uint64]
+        lib.ceph_crc32c_table.restype = ctypes.c_uint32
+        lib.ceph_crc32c_table.argtypes = lib.ceph_crc32c.argtypes
+        lib.ceph_crc32c_impl.restype = ctypes.c_char_p
+        lib.ceph_crc32c_impl.argtypes = []
         lib.ceph_rjenkins3.restype = ctypes.c_uint32
         lib.ceph_rjenkins3.argtypes = [ctypes.c_uint32] * 3
         lib.ceph_rjenkins3_batch.argtypes = [
@@ -132,13 +136,33 @@ def _u8p(a: np.ndarray):
     return a.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8))
 
 
-def crc32c(data: bytes, crc: int = 0) -> int:
-    """Castagnoli CRC (reference common/crc32c.h semantics)."""
+def _crc32c(fn: str, data, crc: int) -> int:
     lib = _load()
     if lib is None:
         raise RuntimeError("native crc32c unavailable (check available())")
     buf = np.frombuffer(data, np.uint8)
-    return int(lib.ceph_crc32c(crc, _u8p(buf), buf.size))
+    return int(getattr(lib, fn)(crc, _u8p(buf), buf.size))
+
+
+def crc32c(data, crc: int = 0) -> int:
+    """Castagnoli CRC (reference common/crc32c.h semantics) of any
+    contiguous buffer, on the path crc32c_impl() names."""
+    return _crc32c("ceph_crc32c", data, crc)
+
+
+def crc32c_table(data, crc: int = 0) -> int:
+    """The same digest by the slicing-by-8 table: crc32c's fallback
+    where the CPU lacks the instruction, and the tests' reference."""
+    return _crc32c("ceph_crc32c_table", data, crc)
+
+
+def crc32c_impl() -> str:
+    """Which path crc32c takes, decided by the build ("sse42x3": the
+    CPU's CRC32C instruction on three interleaved streams; "table")."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError("native crc32c unavailable (check available())")
+    return lib.ceph_crc32c_impl().decode()
 
 
 def xxh32(data: bytes, seed: int = 0) -> int:

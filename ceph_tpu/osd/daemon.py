@@ -17,6 +17,7 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from ceph_tpu.common.crc import crc32c_impl
 from ceph_tpu.msg.message import Message
 from ceph_tpu.msg.messenger import Dispatcher, Messenger
 from ceph_tpu.msg.types import EntityAddr, EntityName
@@ -333,7 +334,8 @@ class OSD(Dispatcher):
         self.ctx.cluster_log.info(
             f"osd.{self.whoami} boot at {self.messenger.addr}")
         self.logger.info(f"osd.{self.whoami} starting at "
-                         f"{self.messenger.addr}")
+                         f"{self.messenger.addr}; shard digests on "
+                         f"crc32c {crc32c_impl()}")
 
     async def _authenticate(self) -> None:
         """cephx boot: prove osd.N's key to the mon, fetch the 'osd'

@@ -384,7 +384,7 @@ def main() -> int:
     say(f"compile cache: {cache_dir}")
     check(native.available(), "native library unavailable (g++ build)")
     simd = "gfni_avx512" if native.gf_simd_available() else "scalar"
-    say(f"native host kernel: {simd}")
+    say(f"native host kernel: {simd}; crc32c: {native.crc32c_impl()}")
     say(f"sizes: {size}")
 
     rng = np.random.default_rng(args.seed)

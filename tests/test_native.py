@@ -1,10 +1,12 @@
-"""Native library tests: crc32c check vectors, rjenkins parity with the
-python hash, GF(2^8) apply parity with gf256.host_apply."""
+"""Native library tests: crc32c check vectors and the agreement of its
+three implementations, rjenkins parity with the python hash, GF(2^8)
+apply parity with gf256.host_apply."""
 
 import numpy as np
 import pytest
 
 from ceph_tpu import native
+from ceph_tpu.common.crc import crc32c, crc32c_python
 
 pytestmark = pytest.mark.skipif(not native.available(),
                                 reason="native lib unavailable")
@@ -32,6 +34,100 @@ def test_crc32c_check_vectors():
         u8p = ctypes.POINTER(ctypes.c_uint8)
         got = lib.ceph_crc32c(0, view.ctypes.data_as(u8p), view.size)
         assert got == native.crc32c(aligned.tobytes())
+
+
+# ceph_crc32c's interleave constants (native.cc): three streams of a
+# long block, then of a short block, then one stream
+CRC_LONG, CRC_SHORT = 8192, 256
+CRC_DATA = np.random.default_rng(25).integers(
+    0, 256, (1 << 20) + 5 + 16, dtype=np.uint8)
+CRC_LENGTHS = sorted(
+    set(range(71)) | {255, 256, 257, 1 << 20, (1 << 20) + 5}
+    | {n + d for n in (CRC_SHORT, 3 * CRC_SHORT, CRC_LONG, 3 * CRC_LONG)
+       for d in (-1, 0, 1)})
+
+
+def _view(length: int, off: int = 0) -> np.ndarray:
+    """`length` bytes of CRC_DATA starting `off` bytes past an 8-byte
+    boundary of the address space (what the C head loop looks at)."""
+    start = (-CRC_DATA.ctypes.data) % 8 + off
+    v = CRC_DATA[start:start + length]
+    assert v.size == length
+    assert not length or v.ctypes.data % 8 == off % 8
+    return v
+
+
+@pytest.mark.parametrize("length", CRC_LENGTHS)
+def test_crc32c_three_paths_agree(length):
+    v = _view(length)
+    want = crc32c_python(v.tobytes())
+    assert native.crc32c_table(v) == want
+    assert native.crc32c(v) == want
+    assert crc32c(memoryview(v)) == want
+
+
+@pytest.mark.parametrize("off", range(9))
+@pytest.mark.parametrize(
+    "length", [0, 7, 70, 3 * CRC_SHORT + 3, 3 * CRC_LONG + CRC_SHORT + 1])
+def test_crc32c_unaligned_start(off, length):
+    v = _view(length, off)
+    want = native.crc32c_table(v.copy())
+    assert native.crc32c(v) == want
+    assert native.crc32c_table(v) == want
+
+
+@pytest.mark.parametrize("cuts", [
+    (0,), (1,), (5, 6, 7), (63, 64, 65),
+    (CRC_SHORT,), (3 * CRC_SHORT - 1,), (3 * CRC_SHORT + 1,),
+    (100, 2 * CRC_SHORT + 9),                  # inside stream 0 and 2
+    (CRC_LONG,), (2 * CRC_LONG,), (3 * CRC_LONG,),   # on stream edges
+    (CRC_LONG - 3, CRC_LONG + 3),              # across an edge
+    (CRC_LONG // 2, CRC_LONG + 11, 2 * CRC_LONG + 4097),
+    (3 * CRC_LONG + 5, 6 * CRC_LONG + CRC_SHORT + 1),
+    (12345, 12346, 50000, 50001, 77777),
+], ids=lambda c: "-".join(map(str, c)))
+def test_crc32c_seed_chaining(cuts):
+    """crc32c(b, crc32c(a)) == crc32c(a + b) wherever a ends: inside a
+    stream, on a stream boundary, across one."""
+    v = _view(6 * CRC_LONG + 3 * CRC_SHORT + 101, off=3)
+    want = native.crc32c_table(v)
+    assert native.crc32c(v) == want
+    edges = [0, *cuts, v.size]
+    crc = crc_t = 0
+    for a, b in zip(edges, edges[1:]):
+        crc = native.crc32c(v[a:b], crc)
+        crc_t = native.crc32c_table(v[a:b], crc_t)
+    assert crc == want and crc_t == want
+
+
+@pytest.mark.parametrize("data,want", [
+    (bytes(32), 0x8A9136AA),
+    (b"\xff" * 32, 0x62A8AB43),
+    (bytes(range(32)), 0x46DD794E),
+    (bytes(range(31, -1, -1)), 0x113FDB5C),
+], ids=["zeros", "ones", "ascending", "descending"])
+def test_crc32c_iscsi_vectors(data, want):
+    # RFC 3720 B.4
+    assert native.crc32c(data) == want
+    assert native.crc32c_table(data) == want
+    assert crc32c_python(data) == want
+
+
+def test_crc32c_impl_follows_the_cpu():
+    """The build decides: a CPU with SSE4.2 gets the instruction."""
+    has = "sse4_2" in native._cpu_flags().split()   # /proc/cpuinfo's line
+    impl = native.crc32c_impl()
+    assert (impl != "table") if has else (impl == "table")
+
+
+def test_crc32c_accepts_what_callers_hand_it():
+    class Lazy:                     # osd/extents.ExtentRef's shape
+        def __bytes__(self):
+            return b"123456789"
+    for data in (b"123456789", bytearray(b"123456789"),
+                 memoryview(b"x123456789")[1:], Lazy(),
+                 np.frombuffer(b"123456789", np.uint8)):
+        assert crc32c(data) == 0xE3069283
 
 
 def test_rjenkins_matches_python():

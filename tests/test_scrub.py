@@ -14,7 +14,9 @@ import pytest
 sys.path.insert(0, __file__.rsplit("/", 1)[0])
 from test_osd import Cluster  # noqa: E402
 
+from ceph_tpu.common.crc import crc32c_impl, crc32c_python  # noqa: E402
 from ceph_tpu.osd.messages import MPGScrub  # noqa: E402
+from ceph_tpu.osd.scrub import CRC_XATTR  # noqa: E402
 from ceph_tpu.store.objectstore import Transaction  # noqa: E402
 
 
@@ -148,6 +150,41 @@ def test_deep_scrub_rebuilds_ec_shard():
         assert await io.read("obj") == payload
         res = await run_scrub(pg, deep=True)
         assert res["errors"] == 0
+        await cl.stop()
+    asyncio.run(run())
+
+
+@pytest.mark.parametrize("size", [131072, 131072 + 4099],
+                         ids=["stripe_aligned", "unaligned"])
+def test_ec_write_records_each_shard_digest(size):
+    """Every one of the six shards of a full write carries the `_crc` of
+    exactly the bytes stored, by the byte-at-a-time python table (the
+    write path's digest runs on the native kernel), and deep scrub,
+    which recomputes it, agrees.  32 KiB shards: long enough for the
+    kernel's interleaved streams."""
+    async def run():
+        cl = Cluster()
+        admin = await cl.start(6)
+        await admin.pool_create("ecpool", pg_num=4, pool_type="erasure",
+                                k=4, m=2)
+        io = admin.open_ioctx("ecpool")
+        for osd in cl.osds.values():   # each OSD says which path it is on
+            assert any(f"crc32c {crc32c_impl()}" in line
+                       for line in osd.ctx.log.dump_recent(10000))
+        payload = np.random.default_rng(size).integers(
+            0, 256, size, dtype=np.uint8).tobytes()
+        await io.write_full("obj", payload)
+        copies = find_copies(cl, "obj")
+        assert len(copies) == 6
+        for osd, cid, soid in copies:
+            stored = osd.store.read(cid, soid)
+            assert len(stored) >= size // 4
+            assert int(osd.store.getattr(cid, soid, CRC_XATTR)) == \
+                crc32c_python(stored)
+        pg, _ = primary_pg(cl, "ecpool", "obj")
+        res = await run_scrub(pg, deep=True)
+        assert res["errors"] == 0 and res["repaired"] == 0
+        assert await io.read("obj") == payload
         await cl.stop()
     asyncio.run(run())
 
