@@ -380,12 +380,16 @@ DEFAULT_OPTIONS: List[Option] = [
            "flush the collector early once this many pending encode "
            "bytes accumulate (bytes-quorum; window is the ceiling)"),
     Option("objectstore", "str", "memstore",
-           "backend: memstore|filestore|blockstore"),
+           "backend: memstore|filestore|blockstore|kstore"),
     Option("blockstore_compression", "str", "",
            "blob compressor: zlib|bz2|lzma|'' (bluestore_compression_*)"),
     Option("blockstore_compression_min_blob", "size", "4k",
            "smallest blob worth compressing"),
-    Option("objectstore_path", "str", "", "data dir for filestore"),
+    Option("objectstore_path", "str", "",
+           "directory under which every OSD of a disk-backed "
+           "objectstore keeps its own osd.<id>/ (an in-process "
+           "cluster; daemon processes use their --dir).  Relative: "
+           "<TMPDIR>/<path>.<pid>, removed when the cluster stops"),
     Option("filestore_journal_size", "size", "64m", "WAL size"),
     Option("filestore_kill_at", "int", 0,
            "crash injection countdown in queue_transactions batches: "

@@ -38,7 +38,8 @@ Design:
     lies in the profiler's trace on the profiler's clock.  ``loop_*``
     sections run on the event-loop thread and never nest (their sum
     is loop time with a name); ``seam_*`` sections run on the EC
-    queue's device thread.  INTERVALS (``Tracer.interval``) are the
+    queue's device thread, ``store_*`` sections on a store's kv-sync
+    thread.  INTERVALS (``Tracer.interval``) are the
     awaited counterpart: histogram only, from a ``Tracer.stamp()``.
 
   * While tracing is on, one sampler per event loop records the loop
@@ -143,6 +144,17 @@ LOOP_STAGES = (
     "loop_cpu",           # sampler: time.thread_time() delta per tick
 )
 
+#: A store's THREADED commit group (store/commit.py), the off-loop twin
+#: of loop_store_commit: what durability adds to a transaction.  Per
+#: transaction store_commit_wait = wait for the kv-sync thread + gather
+#: + store_data_sync + store_kv_sync + store_resume.
+STORE_STAGES = (
+    "store_commit_wait",  # interval: submit() -> completion record on the loop
+    "store_data_sync",    # section, kv-sync thread: the group's data barrier
+    "store_kv_sync",      # section, kv-sync thread: the group's kv WAL sync
+    "store_resume",       # interval: barriers done -> completion record runs
+)
+
 #: Auxiliary (non-chain) stages, for dump annotation.  recovery_pull
 #: (one recovered object: gather -> decode -> push ack) and
 #: decode_rebuild (the decode slice alone, batched through the EC
@@ -156,7 +168,8 @@ LOOP_STAGES = (
 #: the evidence the copy moved rather than vanished.
 AUX_STAGES = ("op_total", "repl_apply", "repl_commit",
               "recovery_pull", "decode_rebuild",
-              "extent_write", "extent_read") + SEAM_STAGES + LOOP_STAGES
+              "extent_write", "extent_read") \
+    + SEAM_STAGES + LOOP_STAGES + STORE_STAGES
 
 STAGE_GROUP = "op_stages"
 
@@ -354,6 +367,9 @@ class Tracer:
     def __init__(self, ctx):
         self.ctx = ctx
         self._hist = None
+        if ctx is None:         # OFF, below: belongs to no daemon
+            self.enabled = False
+            return
         try:
             self.enabled = bool(ctx.config["op_tracing"])
         except KeyError:
@@ -416,6 +432,13 @@ class Tracer:
 
     def finish(self, span: Span) -> float:
         return span.finish(self.hist)
+
+
+#: The tracer of code that no daemon has mounted or started (a store in
+#: a tool or a test, before an OSD hands it its own): never on, so every
+#: section is the shared no-op, every stamp 0.0 with no clock read, and
+#: every interval records nothing.
+OFF = Tracer(None)
 
 
 # ---------------------------------------------------------- aggregation

@@ -425,7 +425,9 @@ _WIRE_CTOR_EXTRA = {"PGId", "EVersion", "EntityAddr", "EntityName",
                     "CollectionId", "ObjectId", "PGInfo"}
 #: method calls whose result is portable
 _PORTABLE_CALLS = {"without_shard", "with_shard", "monotonic",
-                   "perf_counter", "get_ident", "local_cost"}
+                   "perf_counter", "get_ident", "local_cost",
+                   # Tracer.stamp(): time.monotonic() or 0.0
+                   "stamp"}
 _WIRE_CALLS = {"local_view", "mutable", "mutable_copy", "peek"}
 _LIVE_SOURCES = {"_pg_for", "_load_stray_pg", "get_running_loop",
                  "get_event_loop"}

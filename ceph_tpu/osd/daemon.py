@@ -270,15 +270,21 @@ class OSD(Dispatcher):
 
     # ------------------------------------------------------------ lifecycle
     async def start(self) -> None:
-        # sharded-plane commit semantics: barrier-less (RAM) stores
-        # ack-on-apply — the commit thread's GIL handoff is the
-        # tracer's repl_commit cost, and there is no durability point
-        # it buys.  shards=1 keeps today's threaded handoff.
-        if self.shards.enabled:
+        # this daemon's tracer names the store's commit groups: an
+        # inline group is a section of this loop (loop_store_commit),
+        # a threaded group's barriers are sections of its kv-sync
+        # thread (store_data_sync, store_kv_sync)
+        self.store.tracer = self.ctx.tracer
+        # sharded-plane commit semantics.  Ack-on-apply is asked ONLY
+        # of a store that has no barrier (RAM): its commit thread buys
+        # no durability point, only a GIL handoff (the tracer's
+        # repl_commit cost), so its groups commit inline on this loop.
+        # A store WITH barriers (blockstore, kstore, filestore) is not
+        # asked: it commits on its own thread behind its data barrier
+        # and kv sync, and every ack rides on_commit.  shards=1 keeps
+        # the threaded handoff for every store.
+        if self.shards.enabled and not self.store.barriers:
             self.store.ack_on_apply = True
-            # the inline commit groups then run on this daemon's loop:
-            # its tracer names them (loop_store_commit)
-            self.store.tracer = self.ctx.tracer
         # the EC queue's backend is decided ONCE, before this OSD takes
         # ops; osd_ec_batch_device=on without an accelerator raises
         # here and fails the start

@@ -9,6 +9,9 @@ reach into daemon state (PGs, stores) directly.
 from __future__ import annotations
 
 import asyncio
+import os
+import shutil
+import tempfile
 
 from ceph_tpu.client import Rados
 from ceph_tpu.common.context import Context
@@ -18,7 +21,7 @@ from ceph_tpu.msg.messenger import Messenger
 from ceph_tpu.msg.types import EntityName
 from ceph_tpu.osd import OSD
 from ceph_tpu.store.kv import MemDB
-from ceph_tpu.store.memstore import MemStore
+from ceph_tpu.store.objectstore import ObjectStore
 
 FAST_CFG = {
     "mon_election_timeout": 0.3,
@@ -88,10 +91,14 @@ class Cluster:
         self.osds = {}
         self.clients = []
         self.make_ctx = ctx_factory or make_ctx
-        # store_factory(osd_id) -> ObjectStore lets tests run OSDs on a
-        # durable backend (e.g. BlockStore on a tmp dir) instead of the
-        # MemStore default
+        # store_factory(osd_id) -> ObjectStore lets a test hand every
+        # OSD a store of its own making; without one an OSD runs the
+        # store its configuration states (objectstore, objectstore_path;
+        # MemStore by default)
         self.store_factory = store_factory
+        #: the directory this cluster made for its OSDs' stores out of
+        #: a RELATIVE objectstore_path; stop() removes it
+        self._own_store_dir = ""
         self._stall_monitor = None
 
     async def start(self, n_osds: int, osds_per_host: int = 1):
@@ -137,14 +144,35 @@ class Cluster:
         # recovery-from-peers instead
         fresh = store is None
         if store is None:
-            store = (self.store_factory(i) if self.store_factory
-                     else MemStore())
+            if self.store_factory:
+                store = self.store_factory(i)
+            else:
+                # the deployment's own store: the backend and directory
+                # its configuration states, laid out as a daemon
+                # process lays it out (tools/daemons.py); a fresh start
+                # begins from an empty directory
+                store = ObjectStore.for_osd(
+                    ctx.config, self._store_dir(ctx.config), i)
+                store.wipe()
         if fresh:
             store.mkfs()
         osd = OSD(ctx, i, store, msgr, self.monmap)
         await osd.start()
         self.osds[i] = osd
         return osd
+
+    def _store_dir(self, config) -> str:
+        """objectstore_path as this cluster takes it.  An absolute path
+        is the deployment's own and is used, and left, as it stands.  A
+        relative one names a directory of THIS process under the temp
+        directory (TMPDIR): `<tmp>/<path>.<pid>`, so two clusters on
+        one machine never meet in it, and stop() removes it."""
+        path = config["objectstore_path"]
+        if not path or os.path.isabs(path):
+            return path
+        self._own_store_dir = os.path.join(
+            tempfile.gettempdir(), f"{path}.{os.getpid()}")
+        return self._own_store_dir
 
     async def kill_osd(self, i: int):
         osd = self.osds.pop(i)
@@ -269,6 +297,13 @@ class Cluster:
                                     measured_e2e_s)
 
     async def stop(self):
+        try:
+            await self._stop()
+        finally:
+            if self._own_store_dir:
+                shutil.rmtree(self._own_store_dir, ignore_errors=True)
+
+    async def _stop(self):
         try:
             for c in self.clients:
                 await c.shutdown()

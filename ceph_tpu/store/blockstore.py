@@ -244,6 +244,9 @@ class _Batch:
 
 
 class BlockStore(ObjectStore):
+    #: one commit group: the block file's fsync, then the kv WAL's
+    barriers = ("data", "kv")
+
     #: selectable per-extent checksum (bluestore csum_type: crc32c is
     #: the default; xxhash32/xxhash64 as in bluestore_types.h
     #: Checksummer).  Stored crcs are alg-agnostic 32-bit values, so
@@ -298,6 +301,54 @@ class BlockStore(ObjectStore):
         if not os.path.exists(self._block_path()):
             self.mkfs()
         self.db = FileDB(os.path.join(self.path, "db"))
+        self._load_csum_pin()
+        txn = self.db.create_transaction()
+        txn.set("meta", b"csum_type", self._csum_name.encode())
+        self.db.submit(txn)
+        self._fd = os.open(self._block_path(), os.O_RDWR)
+        self._rebuild_allocator()
+        self._onodes = {}
+        # group-commit pipeline (BlueStore kv_sync_thread role): the
+        # event loop applies in memory; this thread batches the data
+        # fsync + kv WAL sync for every transaction in flight
+        self.db.pre_compact_hook = self._data_barrier
+        # small static gather base: the auto-tuner tracks the MEASURED
+        # barrier cost (EWMA) clamped to 4x this — on tmpfs the window
+        # stays at the ~0.1ms a cheap fsync costs, on a real disk it
+        # grows to the clamp so co-arriving txns share the 4ms+ fsync
+        self._committer = KVSyncThread(
+            "blockstore_commit",
+            data_sync=self._data_barrier,
+            kv_sync=self.db.log_deferred,
+            gather_window=0.001,
+            # the mounting OSD's op tracer names the group's barriers
+            # (store_data_sync, store_kv_sync) and each transaction's
+            # wait for them (store_commit_wait, store_resume)
+            tracer=self.tracer)
+        self._committer.start()
+        self.mounted = True
+
+    def mount_read_only(self, db_path: Optional[str] = None) -> None:
+        """Mount what the files hold NOW, for reading alone: snapshot +
+        WAL replayed into memory, the block file opened O_RDONLY.
+        Writes nothing to the directory (no csum pin, no torn-tail
+        truncation, no compaction at umount), starts no commit thread;
+        queue_transactions raises.  `db_path` reads the metadata from
+        another directory than <path>/db: a copy taken with
+        ``FileDB.copy_files`` beside the live ``block`` file is the
+        store as a crash at the copy's instant would have left it, for
+        as long as no block the copy references is reused."""
+        if self.mounted:
+            raise StoreError("blockstore is already mounted")
+        self.db = FileDB(db_path or os.path.join(self.path, "db"),
+                         read_only=True)
+        self._load_csum_pin()
+        self._fd = os.open(self._block_path(), os.O_RDONLY)
+        self._rebuild_allocator()       # in memory: statfs reads it
+        self._onodes = {}
+        self.mounted = True
+
+    def _load_csum_pin(self) -> None:
         # the csum alg is a STORE property (extents carry only the
         # 32-bit value): the pinned type wins over the constructor
         # argument, so reopening with a different default can't
@@ -314,12 +365,10 @@ class BlockStore(ObjectStore):
                     f"(supported: {sorted(self.CSUM_FNS)})")
             self._csum_name = name
             self._csum = self.CSUM_FNS[name]
-        txn = self.db.create_transaction()
-        txn.set("meta", b"csum_type", self._csum_name.encode())
-        self.db.submit(txn)
-        self._fd = os.open(self._block_path(), os.O_RDWR)
-        # allocator rebuild: everything is free except extents referenced
-        # by some onode (FreelistManager role, derived not persisted)
+
+    def _rebuild_allocator(self) -> None:
+        # everything is free except extents referenced by some onode
+        # (FreelistManager role, derived not persisted)
         self.alloc = Allocator()
         # the file ends at the last written byte, which can be mid-block:
         # round up so rebuild carving stays block-aligned
@@ -331,22 +380,6 @@ class BlockStore(ObjectStore):
             for ext in on.extents:
                 self.alloc.init_rm_free(ext.disk,
                                         _align_up(ext.disk_len))
-        self._onodes = {}
-        # group-commit pipeline (BlueStore kv_sync_thread role): the
-        # event loop applies in memory; this thread batches the data
-        # fsync + kv WAL sync for every transaction in flight
-        self.db.pre_compact_hook = self._data_barrier
-        # small static gather base: the auto-tuner tracks the MEASURED
-        # barrier cost (EWMA) clamped to 4x this — on tmpfs the window
-        # stays at the ~0.1ms a cheap fsync costs, on a real disk it
-        # grows to the clamp so co-arriving txns share the 4ms+ fsync
-        self._committer = KVSyncThread(
-            "blockstore_commit",
-            data_sync=self._data_barrier,
-            kv_sync=self.db.log_deferred,
-            gather_window=0.001)
-        self._committer.start()
-        self.mounted = True
 
     def _data_barrier(self) -> None:
         if self._fd >= 0:
@@ -363,8 +396,9 @@ class BlockStore(ObjectStore):
     def umount(self) -> None:
         if not self.mounted:
             return
-        self._committer.stop()
-        self._committer = None
+        if self._committer is not None:     # None: mounted read-only
+            self._committer.stop()
+            self._committer = None
         # close the db BEFORE the block fd: close() may still flush
         # deferred kv records (dead commit thread) and its data barrier
         # (pre_compact_hook -> _data_barrier) needs the fd open
@@ -418,7 +452,9 @@ class BlockStore(ObjectStore):
         fires inline (state is readable); on_commit fires from the
         commit thread once the batch is durable, in submission order."""
         assert self.mounted, "blockstore not mounted"
-        if self._committer is not None and self._committer.dead:
+        if self._committer is None:
+            raise StoreError("blockstore is mounted read-only")
+        if self._committer.dead:
             # the commit thread died (fsync error / injected crash):
             # accepting more writes would apply them in memory with no
             # path to durability and no acks — fail loudly so the OSD
@@ -707,8 +743,11 @@ class BlockStore(ObjectStore):
                 hi = max(hi, ext.logical + ext.length)
         span = bytearray(hi - lo)
         for ext in drop:
-            span[ext.logical - lo:ext.logical - lo + ext.length] = \
-                self._pread_checked(ext)
+            if ext.logical < off or ext.logical + ext.length > end:
+                # only an extent the write does not wholly cover has
+                # bytes that survive it
+                span[ext.logical - lo:ext.logical - lo + ext.length] = \
+                    self._pread_checked(ext)
             b.freed.append((ext.disk, _align_up(ext.disk_len)))
         span[off - lo:end - lo] = data
         on.extents = sorted(keep + self._rewrite(lo, bytes(span), b),
@@ -726,8 +765,10 @@ class BlockStore(ObjectStore):
             if e_end <= off or ext.logical >= end:
                 out.append(ext)
                 continue
-            data = self._pread_checked(ext)
             b.freed.append((ext.disk, _align_up(ext.disk_len)))
+            if ext.logical >= off and e_end <= end:
+                continue        # wholly punched out: nothing to keep
+            data = self._pread_checked(ext)
             if ext.logical < off:
                 head = data[:off - ext.logical]
                 out.extend(self._rewrite(ext.logical, head, b))

@@ -16,6 +16,18 @@ Invariants the thread preserves:
   * bounded backlog — the queue is bounded; a producer outrunning the
     disk blocks on enqueue (Throttle role) instead of ballooning RAM.
 
+Spans (common/tracer.py, all on ``op_tracing``; off, none of them reads
+a clock or allocates).  The THREADED path, which a store with barriers
+takes, is the off-loop twin of the inline group's ``loop_store_commit``:
+two sections on the kv-sync thread, per group, ``store_data_sync`` (the
+data barrier) and ``store_kv_sync`` (the kv submit); two intervals per
+transaction, ``store_commit_wait`` (submit() -> its completion record
+runs on the submitting loop: all that durability adds to an op) and
+``store_resume`` (the group's barriers done on the thread -> the same
+instant on the loop: a finished group waiting for the loop).  So per
+transaction store_commit_wait = wait for the thread + gather +
+the group's two sections + store_resume.
+
 Fault injection for crash-ordering tests: ``crash_at`` kills the thread
 at a named point ("before_data_sync" | "before_kv") leaving the store
 exactly as a power cut at that instant would; ``trace`` observes the
@@ -32,6 +44,7 @@ from typing import Callable, List, Optional
 
 from ceph_tpu.common.lockdep import make_thread_lock
 from ceph_tpu.common.perf_counters import PerfCounters
+from ceph_tpu.common.tracer import OFF
 
 _log = logging.getLogger("ceph-tpu.store.commit")
 
@@ -61,12 +74,16 @@ class _Item:
     record the owning lane resolves (the process-lane form the seam
     inventory prescribed)."""
 
-    __slots__ = ("seq", "wrote_data", "t0", "idx")
+    __slots__ = ("seq", "wrote_data", "t0", "idx", "synced")
 
     def __init__(self, seq, wrote_data, idx=0):
         self.seq = seq
         self.wrote_data = wrote_data
         self.t0 = time.perf_counter()
+        #: its group's barriers have returned (_commit): a completion
+        #: record posted while this is False is an ack ahead of its
+        #: commit, and _complete counts it (acks_before_commit)
+        self.synced = False
         #: process-unique submission index (the seq field is
         #: store-assigned and 0 for RAM stores): the explorer's
         #: phantom-ack check keys on this, and the callback table
@@ -96,7 +113,7 @@ class KVSyncThread:
                  gather_window: float = 0.0,
                  auto_tune: bool = True,
                  ack_on_apply: bool = False,
-                 tracer=None):
+                 tracer=OFF):
         # unique per instance: co-located stores of the same backend
         # (a 4-OSD in-process cluster = four "memstore_commit"s) must
         # be distinguishable in the schedule explorer's commit-order
@@ -122,9 +139,11 @@ class KVSyncThread:
         #: see start().  Off = today's threaded behavior, bit-for-bit
         #: (osd_op_num_shards=1 and standalone stores keep it off).
         self.ack_on_apply = ack_on_apply
-        #: the mounting daemon's op tracer, or None: an inline commit
-        #: group is synchronous work on that daemon's event loop, and
-        #: with tracing on it is a loop section like the rest
+        #: the mounting daemon's op tracer (tracer.OFF where no daemon
+        #: mounted the store).  An inline commit group is synchronous
+        #: work on that daemon's event loop: the one section
+        #: loop_store_commit.  A threaded group has the store_* spans
+        #: of the module docstring
         self.tracer = tracer
         #: adapt the window to the measured barrier latency (EWMA),
         #: clamped to [0, 4x the static value].  Only engages on stores
@@ -133,8 +152,13 @@ class KVSyncThread:
         self.auto_tune = auto_tune
         self._barrier_ewma: Optional[float] = None
         self.perf = PerfCounters(name)
+        # data_groups: groups that held a data-writing transaction, so
+        # each owes one data barrier; acks_before_commit: completion
+        # records posted for transactions whose barriers had not
+        # returned.  A sound store reads data_groups == data_fsyncs,
+        # commit_batches == kv_syncs and acks_before_commit == 0
         for key in ("commit_batches", "txns", "data_fsyncs", "kv_syncs",
-                    "fsyncs_saved"):
+                    "fsyncs_saved", "data_groups", "acks_before_commit"):
             self.perf.add_u64(key)
         self.perf.add_avg("txns_per_batch")
         self.perf.add_avg("commit_inflight")
@@ -226,7 +250,10 @@ class KVSyncThread:
             self._submitted += 1
             idx = self._submitted
             if on_commit is not None or post is not None:
-                self._cbs[idx] = (on_commit, post, loop)
+                # the stamp opens store_commit_wait: threaded path only
+                self._cbs[idx] = (
+                    on_commit, post, loop,
+                    0.0 if self._inline else self.tracer.stamp())
         rec = _Item(seq, wrote_data, idx=idx)
         if loop is None:
             if self._inline:
@@ -263,8 +290,6 @@ class KVSyncThread:
         # inline (sim / ack-on-apply) mode: the loop-pass cork IS the
         # commit group; no thread handoff, no gather linger —
         # deterministic
-        elif self.tracer is None:
-            self._run_group(recs)
         else:
             with self.tracer.section("loop_store_commit"):
                 self._run_group(recs)
@@ -438,15 +463,19 @@ class KVSyncThread:
         n_data = sum(1 for it in group if it.wrote_data)
         t_barrier0 = time.perf_counter()
         ran_barrier = False
+        if n_data:
+            self.perf.inc("data_groups")
         if n_data and self.data_sync is not None:
-            self.data_sync()            # ONE barrier for the whole group
+            with self.tracer.section("store_data_sync"):
+                self.data_sync()        # ONE barrier for the whole group
             self.perf.inc("data_fsyncs")
             ran_barrier = True
         self._inject("before_kv", group)
         if self.kv_sync is not None:
             # ONE atomic kv submit covering every record of the group,
             # strictly after the data barrier (data-before-metadata)
-            self.kv_sync(max(it.seq for it in group))
+            with self.tracer.section("store_kv_sync"):
+                self.kv_sync(max(it.seq for it in group))
             self.perf.inc("kv_syncs")
             ran_barrier = True
         if ran_barrier:
@@ -469,6 +498,7 @@ class KVSyncThread:
             + (1 if self.kv_sync is not None else 0)
         self.perf.inc("fsyncs_saved", max(0, would_have - actual))
         for it in group:
+            it.synced = True    # every barrier of its group has returned
             self.perf.tinc("commit_lat", now - it.t0)
             self.perf.hinc("commit_lat_hist", now - it.t0)
         self._complete(group)
@@ -499,6 +529,12 @@ class KVSyncThread:
         # one per transaction.
         by_loop: dict = {}
         direct: List[int] = []
+        early = sum(1 for it in group if not it.synced)
+        if early:
+            self.perf.inc("acks_before_commit", early)
+        t_done = 0.0
+        if not self._inline:    # opens store_resume: threaded path only
+            t_done = self.tracer.stamp()
         with self._lock:
             metas = [(it.idx, self._cbs.get(it.idx)) for it in group]
         for idx, meta in metas:
@@ -512,23 +548,30 @@ class KVSyncThread:
         if direct:
             # no submitting loop (tools, teardown): resolve on the
             # commit thread itself, still in order
-            self._run_completion_records(direct)
+            self._run_completion_records(direct, t_done)
         for loop, records in by_loop.values():
             try:
                 loop.call_soon_threadsafe(
-                    self._run_completion_records, records)
+                    self._run_completion_records, records, t_done)
             except RuntimeError:
                 self._run_completion_records(records)  # loop closed
 
-    def _run_completion_records(self, records: List[int]) -> None:
+    def _run_completion_records(self, records: List[int],
+                                t_done: float = 0.0) -> None:
         """Resolve idx-keyed completion records on the owning lane:
         pop each idx's callbacks from the submitter-side table and run
-        them in record (== submission) order."""
+        them in record (== submission) order.  `t_done` is the
+        committing thread's stamp once the group was durable (0.0:
+        not traced)."""
+        tr = self.tracer
         for idx in records:
             with self._lock:
                 meta = self._cbs.pop(idx, None)
             if meta is None:
                 continue
+            if t_done:
+                tr.interval("store_commit_wait", meta[3])
+                tr.interval("store_resume", t_done)
             for f in meta[:2]:
                 if f is not None:
                     self._guard(f)
@@ -557,6 +600,8 @@ class KVSyncThread:
             "kv_syncs": d.get("kv_syncs", 0),
             "fsyncs": d.get("data_fsyncs", 0) + d.get("kv_syncs", 0),
             "fsyncs_saved": d.get("fsyncs_saved", 0),
+            "data_groups": d.get("data_groups", 0),
+            "acks_before_commit": d.get("acks_before_commit", 0),
             "txns_per_batch": (tpb.get("sum", 0.0) / n_b) if n_b else 0.0,
             "commit_lat_ms": (lat.get("sum", 0.0) / n_l * 1e3)
             if n_l else 0.0,

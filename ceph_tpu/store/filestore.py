@@ -32,6 +32,7 @@ class KilledAt(StoreError):
 
 class FileStore(MemStore):
     COMPACT_BYTES = 64 << 20
+    barriers = ("journal",)
 
     def __init__(self, path: str):
         if not path:

@@ -127,6 +127,8 @@ class _Txn:
 
 
 class KStore(ObjectStore):
+    barriers = ("kv",)
+
     def __init__(self, path: str = ""):
         super().__init__(path)
         self.db: Optional[KeyValueDB] = None

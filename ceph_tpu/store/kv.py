@@ -19,6 +19,7 @@ Keys are namespaced by a string prefix like the reference
 from __future__ import annotations
 
 import os
+import shutil
 import struct
 import threading
 from bisect import bisect_left
@@ -226,16 +227,23 @@ class FileDB(MemDB):
         backlog STAGED under ``_mu`` and flushed outside it.
     Lock order is strictly ``_io`` -> ``_mu``; readers take ``_mu``
     only; ``iterate`` materializes its rows under the lock.
+
+    ``read_only=True`` replays snapshot + WAL into memory and touches
+    nothing in ``path``: no directory made, no torn tail truncated, no
+    log opened for append; every write raises.  Offline inspection, and
+    the view of "what the files held at this instant" (``copy_files``).
     """
 
     COMPACT_BYTES = 8 << 20
 
     supports_deferred = True
 
-    def __init__(self, path: str):
+    def __init__(self, path: str, read_only: bool = False):
         super().__init__()
         self.path = path
-        os.makedirs(path, exist_ok=True)
+        self.read_only = read_only
+        if not read_only:
+            os.makedirs(path, exist_ok=True)
         self.seq = 0
         # built through the lockdep factory: with the sanitizer enabled
         # (qa clusters) the documented _io -> _mu order is a CHECKED
@@ -257,15 +265,31 @@ class FileDB(MemDB):
         self._broken: Optional[str] = None
         self._load_snapshot()
         self._wal = WriteAheadLog(self._wal_path())
-        for seq, payload in self._wal.replay():
+        for seq, payload in (self._wal.read_records()[0] if read_only
+                             else self._wal.replay()):
             if seq > self.seq:
                 super().submit(KVTransaction.decode(payload))
                 self.seq = seq
 
     def _check_broken(self) -> None:
+        if self.read_only:
+            raise RuntimeError(f"FileDB {self.path} is read-only")
         if self._broken is not None:
             raise RuntimeError(f"FileDB {self.path} is broken "
                                f"(memory ahead of WAL): {self._broken}")
+
+    def copy_files(self, dst: str) -> None:
+        """Copy snapshot + WAL as the directory holds them NOW into
+        `dst` (made if missing).  Under ``_io``, so no append, fsync or
+        compaction is half done in the copy: exactly what a mount after
+        a crash at this instant would replay.  Both files are metadata
+        and small (the WAL compacts at COMPACT_BYTES)."""
+        os.makedirs(dst, exist_ok=True)
+        with self._io:
+            for src in (self._snap_path(), self._wal_path()):
+                if os.path.exists(src):
+                    shutil.copyfile(
+                        src, os.path.join(dst, os.path.basename(src)))
 
     # --- persistence ---
     def _snap_path(self):
@@ -373,6 +397,8 @@ class FileDB(MemDB):
         return len(take)
 
     def compact(self) -> None:
+        if self.read_only:
+            raise RuntimeError(f"FileDB {self.path} is read-only")
         with self._io:
             self._compact_io()
 
@@ -412,7 +438,7 @@ class FileDB(MemDB):
 
     def close(self) -> None:
         with self._io:
-            if self._wal.closed:
+            if self._wal.closed:    # never opened when read-only
                 return
             with self._mu:
                 upto = self.seq

@@ -71,9 +71,9 @@ class MemStore(ObjectStore):
             # enabled: RAM stores then ack-on-apply (inline commit
             # groups — no barrier exists to wait for); default off =
             # today's threaded handoff, bit-for-bit
-            ack_on_apply=getattr(self, "ack_on_apply", False),
+            ack_on_apply=self.ack_on_apply,
             # likewise the mounting OSD's op tracer (loop sections)
-            tracer=getattr(self, "tracer", None))
+            tracer=self.tracer)
         self._committer.start()
         self.mounted = True
 

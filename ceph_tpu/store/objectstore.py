@@ -11,8 +11,11 @@ without disks) and FileStore (WAL journal + checkpoint, filestore.py).
 
 from __future__ import annotations
 
+import os
+import shutil
 from typing import Callable, Dict, List, Optional, Tuple
 
+from ceph_tpu.common import tracer as _tracer
 from ceph_tpu.common.encoding import Decoder, Encodable, Encoder
 from ceph_tpu.store.types import CollectionId, ObjectId
 
@@ -242,6 +245,22 @@ class NoSuchObject(StoreError):
 class ObjectStore:
     """Abstract store (factory: create())."""
 
+    #: the durability barriers one commit group of this store issues,
+    #: in order, by name; empty: the apply IS the commit (a RAM store)
+    barriers: Tuple[str, ...] = ()
+    #: set by the mounting OSD BEFORE mount(): a store WITHOUT barriers
+    #: may then commit its groups inline on the daemon's loop
+    #: (store/commit.py); a store with barriers is never asked
+    ack_on_apply = False
+    #: the mounting daemon's op tracer (common/tracer.py; OFF until a
+    #: daemon hands the store its own): it names the store's commit
+    #: groups, inline or on the kv-sync thread
+    tracer = _tracer.OFF
+    #: what a store's mkfs leaves in its directory, by which a
+    #: directory is known as some store's own: blockstore's block file,
+    #: filestore's fsid, kstore's (a FileDB's) wal
+    MARKERS = ("block", "fsid", "wal")
+
     def __init__(self, path: str = ""):
         self.path = path
         self.applied_seq = 0
@@ -263,7 +282,55 @@ class ObjectStore:
             return KStore(path)
         raise ValueError(f"unknown objectstore kind {kind!r}")
 
+    @staticmethod
+    def for_osd(config, base_dir: str, osd_id,
+                durable: bool = False) -> "ObjectStore":
+        """The store of osd.<osd_id> as its configuration asks for it:
+        ``create(config["objectstore"], <base_dir>/osd.<osd_id>)`` with
+        that backend's own options applied; neither mkfs'd nor mounted.
+        Every daemon start goes through here (tools/daemons.py,
+        qa/cluster.py), so a deployment's directory has one layout.
+        `durable`: the caller has to find its objects again after a
+        restart (a daemon process), so memstore becomes filestore."""
+        kind = config["objectstore"]
+        if kind == "memstore":
+            if not durable:
+                return ObjectStore.create(kind)
+            kind = "filestore"
+        if not base_dir:
+            raise StoreError(
+                f"objectstore {kind!r} needs a directory and "
+                f"objectstore_path is empty")
+        store = ObjectStore.create(
+            kind, os.path.join(base_dir, f"osd.{osd_id}"))
+        if kind == "blockstore" and config["blockstore_compression"]:
+            store.set_compression(
+                config["blockstore_compression"],
+                config["blockstore_compression_min_blob"])
+        if kind == "filestore" and config["filestore_kill_at"]:
+            # crash injection countdown (config_opts.h filestore_kill_at)
+            store.kill_at = int(config["filestore_kill_at"])
+        return store
+
     # lifecycle
+    def made(self) -> bool:
+        """Some store's mkfs has run in this store's directory."""
+        return bool(self.path) and any(
+            os.path.exists(os.path.join(self.path, m))
+            for m in self.MARKERS)
+
+    def wipe(self) -> None:
+        """Remove what an earlier store left at `path`, for a fresh
+        mkfs.  Only a directory that a store made, or an empty one, is
+        removed: any other is someone else's, and raises."""
+        if not self.path or not os.path.isdir(self.path):
+            return
+        if os.listdir(self.path) and not self.made():
+            raise StoreError(
+                f"{self.path} is not empty and holds none of "
+                f"{self.MARKERS}: not a store's directory, not wiped")
+        shutil.rmtree(self.path)
+
     def mkfs(self) -> None: ...
     def mount(self) -> None: ...
     def umount(self) -> None: ...

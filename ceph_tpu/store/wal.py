@@ -35,8 +35,10 @@ class WriteAheadLog:
         self.path = path
         self._f = None
 
-    def replay(self) -> List[Tuple[int, bytes]]:
-        """Read valid records, truncate any torn tail, open for append."""
+    def read_records(self) -> Tuple[List[Tuple[int, bytes]], int, int]:
+        """(valid records, their end offset, the file's length): reads
+        only, touches nothing.  A torn tail is whatever lies between
+        the two offsets."""
         try:
             with open(self.path, "rb") as f:
                 data = f.read()
@@ -52,7 +54,12 @@ class WriteAheadLog:
             records.append((seq, payload))
             off += _REC_HDR.size + ln
             valid_end = off
-        if valid_end < len(data):
+        return records, valid_end, len(data)
+
+    def replay(self) -> List[Tuple[int, bytes]]:
+        """Read valid records, truncate any torn tail, open for append."""
+        records, valid_end, length = self.read_records()
+        if valid_end < length:
             with open(self.path, "r+b") as f:
                 f.truncate(valid_end)
                 f.flush()

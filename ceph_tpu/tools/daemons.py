@@ -56,21 +56,10 @@ async def run_osd(args) -> None:
     ctx = Context(f"osd.{args.id}")
     apply_conf(ctx, args.dir)
     monmap = load_monmap(args.dir)
-    path = os.path.join(args.dir, f"osd.{args.id}")
-    kind = ctx.config["objectstore"]
-    if kind == "memstore":        # memstore can't back a daemon restart
-        kind = "filestore"
-    store = ObjectStore.create(kind, path)
-    if kind == "blockstore" and ctx.config["blockstore_compression"]:
-        store.set_compression(
-            ctx.config["blockstore_compression"],
-            ctx.config["blockstore_compression_min_blob"])
-    if kind == "filestore" and ctx.config["filestore_kill_at"]:
-        # crash injection countdown (config_opts.h filestore_kill_at)
-        store.kill_at = int(ctx.config["filestore_kill_at"])
-    fresh_marker = os.path.join(
-        path, "fsid" if kind == "filestore" else "block")
-    if not os.path.exists(fresh_marker):
+    # durable: memstore can't back a daemon restart
+    store = ObjectStore.for_osd(ctx.config, args.dir, args.id,
+                                durable=True)
+    if not store.made():
         store.mkfs()
     msgr = Messenger(ctx, EntityName("osd", args.id))
     osd = OSD(ctx, int(args.id), store, msgr, monmap)

@@ -33,10 +33,18 @@ def detect_type(path: str) -> str:
     return "filestore"
 
 
+#: ops that change the store; every other op mounts a blockstore
+#: read-only and leaves each byte of the directory as it was
+WRITE_OPS = ("remove", "import")
+
+
 def open_store(args) -> ObjectStore:
     kind = args.type or detect_type(args.data_path)
     s = ObjectStore.create(kind, args.data_path)
-    s.mount()
+    if kind == "blockstore" and args.op not in WRITE_OPS:
+        s.mount_read_only()
+    else:
+        s.mount()
     return s
 
 
