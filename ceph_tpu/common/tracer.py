@@ -39,7 +39,9 @@ Design:
     sections run on the event-loop thread and never nest (their sum
     is loop time with a name); ``seam_*`` sections run on the EC
     queue's device thread, ``store_*`` sections on a store's kv-sync
-    thread.  INTERVALS (``Tracer.interval``) are the
+    thread (``store_data_write``: a write-behind store's staged data
+    written out; then the group's two barriers, ``store_data_sync``
+    and ``store_kv_sync``).  INTERVALS (``Tracer.interval``) are the
     awaited counterpart: histogram only, from a ``Tracer.stamp()``.
 
   * While tracing is on, one sampler per event loop records the loop
@@ -147,9 +149,10 @@ LOOP_STAGES = (
 #: A store's THREADED commit group (store/commit.py), the off-loop twin
 #: of loop_store_commit: what durability adds to a transaction.  Per
 #: transaction store_commit_wait = wait for the kv-sync thread + gather
-#: + store_data_sync + store_kv_sync + store_resume.
+#: + store_data_write + store_data_sync + store_kv_sync + store_resume.
 STORE_STAGES = (
     "store_commit_wait",  # interval: submit() -> completion record on the loop
+    "store_data_write",   # section, kv-sync thread: staged data written out
     "store_data_sync",    # section, kv-sync thread: the group's data barrier
     "store_kv_sync",      # section, kv-sync thread: the group's kv WAL sync
     "store_resume",       # interval: barriers done -> completion record runs

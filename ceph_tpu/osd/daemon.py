@@ -272,8 +272,9 @@ class OSD(Dispatcher):
     async def start(self) -> None:
         # this daemon's tracer names the store's commit groups: an
         # inline group is a section of this loop (loop_store_commit),
-        # a threaded group's barriers are sections of its kv-sync
-        # thread (store_data_sync, store_kv_sync)
+        # a threaded group's write-out and barriers are sections of its
+        # kv-sync thread (store_data_write, store_data_sync,
+        # store_kv_sync)
         self.store.tracer = self.ctx.tracer
         # sharded-plane commit semantics.  Ack-on-apply is asked ONLY
         # of a store that has no barrier (RAM): its commit thread buys

@@ -26,24 +26,37 @@ Redesign notes (vs the C++ original):
   * clone copies extents (no shared-blob refcounting); clone_range and
     zero/truncate trim or copy at extent granularity.
   * Commit is a group-committed pipeline (BlueStore kv_sync_thread):
-    queue_transactions applies data (pwrite) and metadata (kv memory)
-    inline — immediately readable — and a dedicated commit thread
-    issues ONE data fsync + ONE atomic kv WAL submit for every batch in
-    flight, preserving data-before-metadata and submission order, then
-    fires on_commit callbacks back on the event loop.  Freed COW blocks
-    return to the allocator only after their dereferencing metadata is
-    durable.
+    queue_transactions applies metadata (kv memory) inline and STAGES
+    data by reference — immediately readable — and a dedicated commit
+    thread writes the staged data out, then issues ONE data fsync + ONE
+    atomic kv WAL submit for every batch in flight, preserving
+    data-before-metadata and submission order, then fires on_commit
+    callbacks back on the event loop.  Freed COW blocks return to the
+    allocator only after their dereferencing metadata is durable.
+  * Data is written BEHIND the caller (BlueStore's aio_write +
+    STATE_AIO_WAIT): the caller never calls pwrite.  _store_piece
+    queues (disk offset, bytes) for the commit thread and enters the
+    bytes in an in-flight table keyed by disk offset, from which reads
+    are served until the pwrite has returned (BufferSpace's writing
+    buffers).  Whoever is about to fsync the block file first writes
+    every staged record (_write_pending), and such drains exclude each
+    other, so no fsync passes a record that someone else has taken and
+    not yet written.  No offset is ever staged twice: a block is
+    reused only after the transaction that freed it is durable, whose
+    group comes after the group that wrote the block.
 """
 
 from __future__ import annotations
 
+import collections
 import os
 import struct
-from typing import Dict, List, Optional, Tuple
+from typing import Deque, Dict, List, Optional, Tuple
 
 from ceph_tpu.common.crc import crc32c
 from ceph_tpu.common.xxhash import xxh32, xxh64
 from ceph_tpu.common.encoding import Decoder, Encodable, Encoder
+from ceph_tpu.common.lockdep import make_thread_lock
 from ceph_tpu.store.commit import KVSyncThread
 from ceph_tpu.store.kv import FileDB, KVTransaction
 from ceph_tpu.store.objectstore import (
@@ -137,7 +150,6 @@ class Allocator:
     referencing them is durable, while the event loop allocates."""
 
     def __init__(self):
-        from ceph_tpu.common.lockdep import make_thread_lock
         self._mu = make_thread_lock("blockstore:alloc:_mu")
         self.free: List[List[int]] = []   # sorted [off, len]
         self.device_size = 0
@@ -229,7 +241,7 @@ class _Batch:
     staged kv — and the async commit path makes interleaving the norm.
     """
 
-    __slots__ = ("ov", "freed", "dirty", "wrote_data")
+    __slots__ = ("ov", "freed", "dirty", "wrote_data", "writes")
 
     def __init__(self):
         # staged kv mutations: (prefix, key) -> value | None(delete).
@@ -241,6 +253,10 @@ class _Batch:
         self.freed: List[Tuple[int, int]] = []
         self.dirty: Dict[bytes, Optional[Onode]] = {}
         self.wrote_data = False
+        # data records (disk offset, stored bytes) of this batch: in
+        # the in-flight table from _store_piece on, queued for the
+        # commit thread only once the whole batch has applied
+        self.writes: List[Tuple[int, bytes]] = []
 
 
 class BlockStore(ObjectStore):
@@ -267,6 +283,16 @@ class BlockStore(ObjectStore):
         self._onodes: Dict[bytes, Onode] = {}    # write-through cache
         self.mounted = False
         self._committer: Optional[KVSyncThread] = None
+        # write-behind (module docstring): records staged and not yet
+        # taken by a drain; disk offset -> bytes of every record whose
+        # pwrite has not returned; records staged / written since mount
+        self._pending: Deque[Tuple[int, bytes]] = collections.deque()
+        self._inflight: Dict[int, bytes] = {}
+        self._drain_mu = make_thread_lock(f"blockstore:{path}:_drain_mu")
+        self._staged_n = 0
+        self._written_n = 0
+        self._written_bytes = 0
+        self._inflight_read_hits = 0
         self._comp = None
         if csum_type not in self.CSUM_FNS:
             raise StoreError(
@@ -318,12 +344,14 @@ class BlockStore(ObjectStore):
         # grows to the clamp so co-arriving txns share the 4ms+ fsync
         self._committer = KVSyncThread(
             "blockstore_commit",
-            data_sync=self._data_barrier,
+            data_write=self._write_pending,
+            data_sync=self._fsync_block,
             kv_sync=self.db.log_deferred,
             gather_window=0.001,
-            # the mounting OSD's op tracer names the group's barriers
-            # (store_data_sync, store_kv_sync) and each transaction's
-            # wait for them (store_commit_wait, store_resume)
+            # the mounting OSD's op tracer names the group's write-out
+            # and barriers (store_data_write, store_data_sync,
+            # store_kv_sync) and each transaction's wait for them
+            # (store_commit_wait, store_resume)
             tracer=self.tracer)
         self._committer.start()
         self.mounted = True
@@ -381,9 +409,39 @@ class BlockStore(ObjectStore):
                 self.alloc.init_rm_free(ext.disk,
                                         _align_up(ext.disk_len))
 
-    def _data_barrier(self) -> None:
+    def _write_pending(self) -> int:
+        """Write every staged data record to the block file, oldest
+        first; -> records written since mount.  Any thread about to
+        fsync the file calls this first.  The lock is held for the
+        whole drain: a second drainer finds the queue empty only after
+        the first one's last pwrite has RETURNED, so its fsync cannot
+        pass a record that the first has popped and not yet written.
+        A record leaves the in-flight table the instant after its
+        pwrite returned: from then on the file answers for it."""
+        with self._drain_mu:
+            while self._pending:
+                d_off, stored = self._pending.popleft()
+                done = os.pwrite(self._fd, stored, d_off)
+                while done < len(stored):       # a short write
+                    done += os.pwrite(self._fd, stored[done:],
+                                      d_off + done)
+                self._inflight.pop(d_off, None)
+                self._written_n += 1
+                self._written_bytes += len(stored)
+            return self._written_n
+
+    def _fsync_block(self) -> None:
         if self._fd >= 0:
             os.fsync(self._fd)
+
+    def _data_barrier(self) -> None:
+        """Every record staged so far is in the file and flushed: what
+        FileDB asks for (pre_compact_hook) before it persists metadata
+        from a thread of its own choosing.  The commit thread runs the
+        two halves as its own two steps."""
+        if self._fd >= 0:
+            self._write_pending()
+            self._fsync_block()
 
     def sync(self) -> None:
         """Block until every queued transaction is durable (flush)."""
@@ -391,7 +449,15 @@ class BlockStore(ObjectStore):
             self._committer.flush()
 
     def commit_counters(self) -> Dict[str, float]:
-        return self._committer.counters() if self._committer else {}
+        if self._committer is None:
+            return {}
+        c = self._committer.counters()
+        # the write-behind's own: records and bytes the drains wrote,
+        # extents a read was served from the in-flight table
+        c["deferred_writes"] = self._written_n
+        c["deferred_bytes"] = self._written_bytes
+        c["inflight_read_hits"] = self._inflight_read_hits
+        return c
 
     def umount(self) -> None:
         if not self.mounted:
@@ -407,6 +473,8 @@ class BlockStore(ObjectStore):
         os.close(self._fd)
         self._fd = -1
         self._onodes = {}
+        self._pending.clear()
+        self._inflight = {}
         self.mounted = False
 
     # ------------------------------------------------------------- helpers
@@ -446,11 +514,12 @@ class BlockStore(ObjectStore):
     # -------------------------------------------------------------- writes
     def queue_transactions(self, txns, on_applied=None,
                            on_commit=None) -> None:
-        """Apply data + metadata in memory, then hand the staged kv
-        batch to the commit thread: ONE data fsync + ONE atomic kv
-        submit cover every batch in flight (group commit).  on_applied
-        fires inline (state is readable); on_commit fires from the
-        commit thread once the batch is durable, in submission order."""
+        """Apply metadata in memory and stage data by reference, then
+        hand both to the commit thread: its write-out of the staged
+        data, ONE data fsync and ONE atomic kv submit cover every batch
+        in flight (group commit).  on_applied fires inline (state is
+        readable); on_commit fires from the commit thread once the
+        batch is durable, in submission order."""
         assert self.mounted, "blockstore not mounted"
         if self._committer is None:
             raise StoreError("blockstore is mounted read-only")
@@ -467,10 +536,13 @@ class BlockStore(ObjectStore):
                     self._apply_op(op, b)
         except Exception:
             # roll back every trace of the failed batch: staged kv is
-            # dropped, the onode cache may hold in-place mutations so it
-            # is flushed wholesale (it is only a cache), and blocks
-            # allocated for the doomed writes leak until the next mount
-            # rebuild reclaims them
+            # dropped, its data records never reach the commit thread
+            # and leave the in-flight table, the onode cache may hold
+            # in-place mutations so it is flushed wholesale (it is only
+            # a cache), and blocks allocated for the doomed writes leak
+            # until the next mount rebuild reclaims them
+            for d_off, _ in b.writes:
+                self._inflight.pop(d_off, None)
             self._onodes = {}
             raise
         for key, on in b.dirty.items():
@@ -486,6 +558,11 @@ class BlockStore(ObjectStore):
                 batch.rmkey(prefix, key)
             else:
                 batch.set(prefix, key, val)
+        # the data records are queued BEFORE the kv record is staged:
+        # a drain that starts once the commit thread holds this
+        # transaction, or once FileDB sees its record, finds them all
+        self._pending.extend(b.writes)
+        self._staged_n += len(b.writes)
         # memory-apply now (read-your-writes for every later caller);
         # the WAL record becomes durable on the commit thread
         seq = self.db.submit_deferred(batch)
@@ -504,7 +581,8 @@ class BlockStore(ObjectStore):
                 for off, ln in freed:
                     self.alloc.release(off, ln)
         self._committer.submit(seq=seq, wrote_data=b.wrote_data,
-                               on_commit=on_commit, post=post)
+                               on_commit=on_commit, post=post,
+                               data_mark=self._staged_n)
 
     # --- staged kv views (overlay over the committed db) ---
     @staticmethod
@@ -536,7 +614,8 @@ class BlockStore(ObjectStore):
         return sorted(keys)
 
     def _apply_op(self, op, b: _Batch) -> None:
-        """Apply one op; any block-file write sets b.wrote_data."""
+        """Apply one op; any staged block-file write sets
+        b.wrote_data."""
         c, o = op.cid, op.oid
         freed, dirty = b.freed, b.dirty
         if op.op == OP_NOP:
@@ -721,8 +800,9 @@ class BlockStore(ObjectStore):
         b.dirty[_onode_key(cid, oid)] = None
         self._onodes.pop(_onode_key(cid, oid), None)
 
-    # COW write: merge-affected old extents are read, the merged span is
-    # written to fresh blocks, old blocks freed post-commit
+    # COW write: old extents the write cuts are read, the merged span
+    # (or, with none cut, the caller's own bytes) goes to fresh blocks,
+    # old blocks freed post-commit
     def _write_range(self, on: Onode, off: int, data: bytes,
                      b: _Batch) -> None:
         if not data:
@@ -741,16 +821,26 @@ class BlockStore(ObjectStore):
                 drop.append(ext)
                 lo = min(lo, ext.logical)
                 hi = max(hi, ext.logical + ext.length)
-        span = bytearray(hi - lo)
-        for ext in drop:
-            if ext.logical < off or ext.logical + ext.length > end:
-                # only an extent the write does not wholly cover has
-                # bytes that survive it
+        # only an extent the write does not wholly cover has bytes that
+        # survive it
+        cut = [ext for ext in drop
+               if ext.logical < off or ext.logical + ext.length > end]
+        if cut:
+            span = bytearray(hi - lo)
+            for ext in cut:
                 span[ext.logical - lo:ext.logical - lo + ext.length] = \
                     self._pread_checked(ext)
+            span[off - lo:end - lo] = data
+            new = bytes(span)
+        else:
+            # nothing survives (lo == off, hi == end): the bytes to
+            # store ARE the caller's, staged by reference.  A buffer
+            # the caller could still change is copied, once, so that
+            # the record owns what the commit thread will write
+            new = data if type(data) is bytes else bytes(data)
+        for ext in drop:
             b.freed.append((ext.disk, _align_up(ext.disk_len)))
-        span[off - lo:end - lo] = data
-        on.extents = sorted(keep + self._rewrite(lo, bytes(span), b),
+        on.extents = sorted(keep + self._rewrite(lo, new, b),
                             key=lambda e: e.logical)
         on.size = max(on.size, end)
 
@@ -779,6 +869,9 @@ class BlockStore(ObjectStore):
 
     def _rewrite(self, logical: int, data: bytes,
                  b: _Batch) -> List[Extent]:
+        """Fresh blocks for `data` (immutable: its pieces are staged by
+        reference, and a slice that takes all of a bytes object is that
+        object)."""
         exts = []
         pos = 0
         for d_off, d_len in self.alloc.allocate(_align_up(len(data))):
@@ -794,16 +887,18 @@ class BlockStore(ObjectStore):
 
     def _store_piece(self, logical: int, chunk: bytes, d_off: int,
                      d_len: int, b: _Batch) -> Extent:
-        """Write one contiguous piece, compressing when it pays
-        (bluestore_compression_required_ratio role: stored bytes must
-        save at least one alloc unit)."""
+        """Stage one contiguous piece for the commit thread's write,
+        compressing when it pays (bluestore_compression_required_ratio
+        role: stored bytes must save at least one alloc unit).  `chunk`
+        is immutable and the record keeps it, not a copy of it."""
         stored, alg = chunk, ""
         if (self._comp is not None
                 and len(chunk) >= self.compression_min_blob):
             cand = self._comp.compress(chunk)
             if _align_up(len(cand)) < _align_up(len(chunk)):
                 stored, alg = cand, self._comp.name
-        os.pwrite(self._fd, stored, d_off)
+        b.writes.append((d_off, stored))
+        self._inflight[d_off] = stored
         b.wrote_data = True
         used = _align_up(len(stored))
         if used < d_len:
@@ -813,7 +908,12 @@ class BlockStore(ObjectStore):
 
     # --------------------------------------------------------------- reads
     def _pread_checked(self, ext: Extent) -> bytes:
-        data = os.pread(self._fd, ext.disk_len, ext.disk)
+        # staged and not yet written: the table answers, not the file
+        data = self._inflight.get(ext.disk)
+        if data is None:
+            data = os.pread(self._fd, ext.disk_len, ext.disk)
+        else:
+            self._inflight_read_hits += 1
         if len(data) != ext.disk_len or self._csum(data) != ext.crc:
             raise StoreError(
                 f"blockstore: csum mismatch at {ext!r} "

@@ -19,19 +19,34 @@ Invariants the thread preserves:
 Spans (common/tracer.py, all on ``op_tracing``; off, none of them reads
 a clock or allocates).  The THREADED path, which a store with barriers
 takes, is the off-loop twin of the inline group's ``loop_store_commit``:
-two sections on the kv-sync thread, per group, ``store_data_sync`` (the
-data barrier) and ``store_kv_sync`` (the kv submit); two intervals per
+three sections on the kv-sync thread, per group, ``store_data_write``
+(a write-behind store's staged data written out: the ``data_write``
+hook, only where a store gives one), ``store_data_sync`` (the data
+barrier) and ``store_kv_sync`` (the kv submit); two intervals per
 transaction, ``store_commit_wait`` (submit() -> its completion record
 runs on the submitting loop: all that durability adds to an op) and
 ``store_resume`` (the group's barriers done on the thread -> the same
 instant on the loop: a finished group waiting for the loop).  So per
 transaction store_commit_wait = wait for the thread + gather +
-the group's two sections + store_resume.
+the group's three sections + store_resume.
+
+Write-behind (BlueStore's aio submit + STATE_AIO_WAIT): a store may
+stage a transaction's data by reference on the submitting loop and
+give the thread a ``data_write`` hook that writes every staged record
+out, in FIFO order, and returns how many records the store has written
+since it was mounted.  ``submit(data_mark=)`` carries the same count as
+it stood once the transaction's own records were staged, so a group
+whose data barrier starts with one of its records unwritten is counted
+(``writes_after_data_sync``; 0 in a sound store).
 
 Fault injection for crash-ordering tests: ``crash_at`` kills the thread
-at a named point ("before_data_sync" | "before_kv") leaving the store
-exactly as a power cut at that instant would; ``trace`` observes the
-stage sequence without perturbing it.
+at a named point ("before_data_write" | "before_data_sync" |
+"before_kv") leaving the store exactly as a power cut at that instant
+would; ``trace`` observes the stage sequence without perturbing it.
+"before_data_write" exists only where the store gave a write hook and
+fires before any staged record of the group reaches the file;
+"before_data_sync" fires with every record of the group written and
+none of them flushed.
 """
 
 from __future__ import annotations
@@ -74,11 +89,14 @@ class _Item:
     record the owning lane resolves (the process-lane form the seam
     inventory prescribed)."""
 
-    __slots__ = ("seq", "wrote_data", "t0", "idx", "synced")
+    __slots__ = ("seq", "wrote_data", "t0", "idx", "synced", "data_mark")
 
-    def __init__(self, seq, wrote_data, idx=0):
+    def __init__(self, seq, wrote_data, idx=0, data_mark=0):
         self.seq = seq
         self.wrote_data = wrote_data
+        #: write-behind store: records it had staged, since mount, once
+        #: this transaction's own were (0: it stages none)
+        self.data_mark = data_mark
         self.t0 = time.perf_counter()
         #: its group's barriers have returned (_commit): a completion
         #: record posted while this is False is an ack ahead of its
@@ -98,6 +116,9 @@ class InjectedCrash(Exception):
 class KVSyncThread:
     """One per mounted store.
 
+    data_write() -- write out every data record the store has staged,
+    oldest first, and return the count written since mount (optional:
+    a store that writes its data before submit() gives none).
     data_sync() -- durability barrier for the data device (optional).
     kv_sync(upto_seq) -- make every staged kv record with seq <=
     upto_seq durable in ONE atomic submit (optional).
@@ -109,6 +130,7 @@ class KVSyncThread:
     def __init__(self, name: str,
                  data_sync: Optional[Callable[[], None]] = None,
                  kv_sync: Optional[Callable[[int], None]] = None,
+                 data_write: Optional[Callable[[], int]] = None,
                  queue_max: int = QUEUE_MAX,
                  gather_window: float = 0.0,
                  auto_tune: bool = True,
@@ -122,6 +144,7 @@ class KVSyncThread:
         self.name = f"{name}#{KVSyncThread._instances}"
         self.data_sync = data_sync
         self.kv_sync = kv_sync
+        self.data_write = data_write
         #: seconds to linger after the first item of a group so bursts
         #: coalesce.  Stores whose commit has real cost (fsync) batch
         #: naturally and leave this 0; RAM-backed stores set a tiny
@@ -155,10 +178,14 @@ class KVSyncThread:
         # data_groups: groups that held a data-writing transaction, so
         # each owes one data barrier; acks_before_commit: completion
         # records posted for transactions whose barriers had not
-        # returned.  A sound store reads data_groups == data_fsyncs,
-        # commit_batches == kv_syncs and acks_before_commit == 0
+        # returned; writes_after_data_sync: staged data records of a
+        # group's transactions that the write hook had not written when
+        # the group's data barrier started.  A sound store reads
+        # data_groups == data_fsyncs, commit_batches == kv_syncs and 0
+        # in acks_before_commit and writes_after_data_sync
         for key in ("commit_batches", "txns", "data_fsyncs", "kv_syncs",
-                    "fsyncs_saved", "data_groups", "acks_before_commit"):
+                    "fsyncs_saved", "data_groups", "acks_before_commit",
+                    "writes_after_data_sync"):
             self.perf.add_u64(key)
         self.perf.add_avg("txns_per_batch")
         self.perf.add_avg("commit_inflight")
@@ -231,7 +258,8 @@ class KVSyncThread:
 
     def submit(self, seq: int = 0, wrote_data: bool = False,
                on_commit: Optional[Callable[[], None]] = None,
-               post: Optional[Callable[[], None]] = None) -> None:
+               post: Optional[Callable[[], None]] = None,
+               data_mark: int = 0) -> None:
         """Enqueue one staged transaction batch.  Blocks (backpressure)
         when the commit backlog is full.  Captures the running event
         loop, if any, so callbacks are posted back to it; without a
@@ -254,7 +282,7 @@ class KVSyncThread:
                 self._cbs[idx] = (
                     on_commit, post, loop,
                     0.0 if self._inline else self.tracer.stamp())
-        rec = _Item(seq, wrote_data, idx=idx)
+        rec = _Item(seq, wrote_data, idx=idx, data_mark=data_mark)
         if loop is None:
             if self._inline:
                 self._run_group([rec])
@@ -459,6 +487,16 @@ class KVSyncThread:
             # the write-path pipelining evidence `perf dump` reports
             self.perf.tinc("commit_inflight",
                            self._submitted - self._completed)
+        if self.data_write is not None:
+            self._inject("before_data_write", group)
+            # write-behind: every record staged so far, so every record
+            # of this group (each was staged before its submit()), is
+            # in the file before the barrier below starts
+            with self.tracer.section("store_data_write"):
+                written = self.data_write()
+            late = max(it.data_mark for it in group) - written
+            if late > 0:
+                self.perf.inc("writes_after_data_sync", late)
         self._inject("before_data_sync", group)
         n_data = sum(1 for it in group if it.wrote_data)
         t_barrier0 = time.perf_counter()
@@ -602,6 +640,7 @@ class KVSyncThread:
             "fsyncs_saved": d.get("fsyncs_saved", 0),
             "data_groups": d.get("data_groups", 0),
             "acks_before_commit": d.get("acks_before_commit", 0),
+            "writes_after_data_sync": d.get("writes_after_data_sync", 0),
             "txns_per_batch": (tpb.get("sum", 0.0) / n_b) if n_b else 0.0,
             "commit_lat_ms": (lat.get("sum", 0.0) / n_l * 1e3)
             if n_l else 0.0,

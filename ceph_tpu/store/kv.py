@@ -255,8 +255,9 @@ class FileDB(MemDB):
         self._deferred: List[Tuple[int, bytes]] = []
         #: called under _io (NOT _mu — it must never block readers)
         #: right before a snapshot compaction / backlog flush persists;
-        #: BlockStore points it at its data-device fsync so a snapshot
-        #: can never persist metadata whose data blocks aren't durable
+        #: BlockStore points it at its data barrier (staged data
+        #: written out, then the data-device fsync) so a snapshot can
+        #: never persist metadata whose data blocks aren't durable
         self.pre_compact_hook: Optional[Callable[[], None]] = None
         #: set when a WAL append failed AFTER memory was applied: the
         #: in-memory state is ahead of the durable log and can never be
@@ -333,9 +334,11 @@ class FileDB(MemDB):
             if backlog:
                 # flush the lower-seq backlog before appending our
                 # record — after the data barrier, since those records'
-                # data blocks may be pwritten but not yet fsync'd
-                # (data-before-metadata; their pwrites happened before
-                # their submit_deferred returned, i.e. before the hook)
+                # data blocks may be staged or pwritten but not yet
+                # fsync'd (data-before-metadata; their data was handed
+                # to the store before their submit_deferred returned,
+                # i.e. before the hook, which writes out whatever is
+                # still staged and then fsyncs)
                 if self.pre_compact_hook is not None:
                     self.pre_compact_hook()
                 self._log_deferred_io(seq - 1)
@@ -406,10 +409,12 @@ class FileDB(MemDB):
         """Caller holds ``_io``.  The snapshot image is built under
         ``_mu`` (consistent seq + state); the data-device barrier and
         the snapshot write/rename/rotate run outside it.  Ordering: any
-        record in the image had its data pwritten before its
-        submit_deferred returned (i.e. before the image was built), so
-        the barrier AFTER building still covers every block the
-        snapshot references (COW data-before-metadata)."""
+        record in the image had its data pwritten, or staged with the
+        store for its write-behind, before its submit_deferred returned
+        (i.e. before the image was built), and the hook writes out all
+        that is staged before it fsyncs, so the barrier AFTER building
+        still covers every block the snapshot references (COW
+        data-before-metadata)."""
         with self._mu:
             out = bytearray(struct.pack("<QI", self.seq, len(self._keys)))
             for k in self._keys:
@@ -445,8 +450,9 @@ class FileDB(MemDB):
                 backlog = bool(self._deferred)
             if backlog:
                 # records can still be pending here when the commit
-                # thread died: their data blocks may be pwritten but
-                # never fsync'd — run the data barrier FIRST so the
+                # thread died: their data blocks may be staged and
+                # never written, or pwritten but never fsync'd — run
+                # the data barrier (write-out, then fsync) FIRST so the
                 # WAL flush can't persist metadata ahead of its data
                 # (data-before-metadata, same rule as compact)
                 if self.pre_compact_hook is not None:

@@ -39,9 +39,9 @@ class SlowBarrierBlockStore(BlockStore):
         # measure the tuner, not the group-commit machinery
         self._committer.auto_tune = False
 
-    def _data_barrier(self):
+    def _fsync_block(self):
         time.sleep(0.001)
-        super()._data_barrier()
+        super()._fsync_block()
 
 
 def _counters(cl):

@@ -5,6 +5,7 @@ value-parameterized over backends) plus journal replay/crash tests
 (DeterministicOpSequence / run_seed_to.sh analog).
 """
 
+import collections
 import os
 
 import numpy as np
@@ -943,13 +944,17 @@ def test_blockstore_group_commit_shares_fsyncs(tmp_path):
     s.umount()
 
 
-@pytest.mark.parametrize("point", ["before_data_sync", "before_kv"])
+COMMIT_POINTS = ["before_data_write", "before_data_sync", "before_kv"]
+
+
+@pytest.mark.parametrize("point", COMMIT_POINTS)
 def test_blockstore_crash_ordering_data_before_metadata(tmp_path, point):
     """Fault-inject a power cut on the commit thread: a kv batch must
-    never be visible (replayable) before its data blocks are fsync'd.
-    The trace hook proves the data barrier strictly precedes the kv
-    submit; a crash at either point leaves the object invisible on
-    replay and fires NO commit callback."""
+    never be visible (replayable) before its data blocks are written
+    and fsync'd.  The trace hook proves the write-out strictly precedes
+    the data barrier and that the kv submit; a crash at any of the
+    three points leaves the object invisible on replay and fires NO
+    commit callback."""
     from ceph_tpu.store.blockstore import BlockStore, StoreError
     path = str(tmp_path / "bs")
     s = BlockStore(path)
@@ -973,11 +978,9 @@ def test_blockstore_crash_ordering_data_before_metadata(tmp_path, point):
             CID, ObjectId("after", pool=1), 0, b"x")])
     # applied state WAS readable in memory (apply/commit split) ...
     assert s.read(CID, OID) == b"doomed"
-    if point == "before_kv":
-        # ... and the data barrier ran strictly before the kv submit
-        assert stages == ["before_data_sync", "before_kv"]
-    else:
-        assert stages == ["before_data_sync"]
+    # ... and the thread went write-out, data barrier, kv submit, as
+    # far as the cut let it
+    assert stages == COMMIT_POINTS[:COMMIT_POINTS.index(point) + 1]
     # power cut: abandon without umount (umount would flush), reopen
     s2 = BlockStore(path)
     s2.mount()
@@ -985,3 +988,220 @@ def test_blockstore_crash_ordering_data_before_metadata(tmp_path, point):
     with pytest.raises(NoSuchObject):
         s2.read(CID, OID)             # the un-fsync'd batch never lands
     s2.umount()
+
+
+# ------------------------------------ write-behind: order and power cuts
+
+def _write_behind_store(path):
+    from ceph_tpu.store.blockstore import BlockStore
+    s = BlockStore(path)
+    s.mkfs()
+    s.mount()
+    s.apply_transaction(Transaction().create_collection(CID))
+    return s
+
+
+def _write_behind_load(s, n, acked, model, seed=11):
+    """n transactions, each an overwrite of the one hot object (whole,
+    or cutting its extent) and a write of one of many others; the
+    model holds every object's bytes after each transaction."""
+    rng = np.random.default_rng(seed)
+    hot = ObjectId("hot", pool=1)
+    state = dict(model[-1]) if model else {}
+    for i in range(n):
+        txn = Transaction()
+        size = int(rng.integers(1, 5)) * 4096
+        off = int(rng.integers(0, 3)) * 4096 if i % 3 else 0
+        data = rng.integers(0, 256, size, np.uint8).tobytes()
+        old = bytearray(state.get(hot, b""))
+        if len(old) < off + size:
+            old.extend(bytes(off + size - len(old)))
+        old[off:off + size] = data
+        state[hot] = bytes(old)
+        txn.write(CID, hot, off, data)
+        other = ObjectId(f"many{int(rng.integers(0, 12))}", pool=1)
+        state[other] = rng.integers(0, 256, 8192, np.uint8).tobytes()
+        txn.remove(CID, other).write(CID, other, 0, state[other])
+        s.queue_transactions(
+            [txn], on_commit=lambda k=len(model): acked.append(k))
+        model.append(dict(state))       # applied: the store took it
+
+
+def test_write_behind_group_writes_then_syncs_then_commits(
+        tmp_path, monkeypatch):
+    """Every commit group: before_data_write -> every pwrite of every
+    one of its transactions has RETURNED -> the block file's fsync ->
+    the kv WAL's -> the callbacks; nothing is written on the caller's
+    thread, and the thread's counters say the same."""
+    import threading
+    s = _write_behind_store(str(tmp_path / "bs"))
+    com = s._committer
+    events, offsets_of, caller = [], {}, threading.get_ident()
+    real_pwrite, real_fsync, real_piece = os.pwrite, os.fsync, \
+        s._store_piece
+
+    def pwrite(fd, data, off):
+        assert threading.get_ident() != caller
+        n = real_pwrite(fd, data, off)
+        events.append(("pwritten", off))
+        return n
+
+    def fsync(fd):
+        real_fsync(fd)
+        events.append(("fsync", "block" if fd == s._fd else "kv"))
+
+    def piece(logical, chunk, d_off, d_len, b):
+        offsets_of.setdefault(len(model), []).append(d_off)
+        return real_piece(logical, chunk, d_off, d_len, b)
+
+    monkeypatch.setattr(os, "pwrite", pwrite)
+    monkeypatch.setattr(os, "fsync", fsync)
+    s._store_piece = piece
+    com.trace = lambda point, n: events.append((point, n))
+    acked, model = [], []
+    real_complete = com._complete
+    com._complete = lambda group: (
+        events.append(("acks", len(acked))), real_complete(group))
+    try:
+        base = s.commit_counters()
+        for burst in (7, 1, 12, 3, 9):
+            com.gate = threading.Event()    # the burst lands in few groups
+            _write_behind_load(s, burst, acked, model, seed=burst)
+            com.gate.set()
+            _write_behind_load(s, 5, acked, model, seed=100 + burst)
+            s.sync()
+        n_txn = len(model)
+        assert acked == list(range(n_txn))
+        # cut the thread's log into groups at each before_data_write
+        starts = [i for i, ev in enumerate(events)
+                  if ev[0] == "before_data_write"]
+        assert len(starts) >= 5
+        # blocks are reused as overwrites free them: count per offset
+        written, owed, first = collections.Counter(), \
+            collections.Counter(), 0
+        for g, start in enumerate(starts):
+            end = starts[g + 1] if g + 1 < len(starts) else len(events)
+            names = [ev[0] for ev in events[start:end]]
+            size = events[start][1]
+            # one of each, in this order, with the pwrites all between
+            # the first two
+            order = [names.index(p) for p in (
+                "before_data_write", "before_data_sync", "fsync",
+                "before_kv", "committed", "acks")]
+            assert order == sorted(order), names
+            assert names.count("fsync") == 2
+            assert [ev[1] for ev in events[start:end]
+                    if ev[0] == "fsync"] == ["block", "kv"]
+            i_sync = names.index("before_data_sync")
+            assert set(names[1:i_sync]) <= {"pwritten"}
+            assert "pwritten" not in names[i_sync:]
+            written.update(ev[1] for ev in events[start:end]
+                           if ev[0] == "pwritten")
+            # the group is transactions first .. first + size - 1
+            for k in range(first, first + size):
+                owed.update(offsets_of[k])
+            assert not owed - written, (g, owed - written)
+            first += size
+        assert first == n_txn
+        c = s.commit_counters()
+        n_rec = sum(len(v) for v in offsets_of.values())
+        assert c["deferred_writes"] - base["deferred_writes"] == n_rec \
+            == sum(written.values())
+        assert c["writes_after_data_sync"] == 0
+        assert c["data_groups"] == c["data_fsyncs"]
+        assert not s._pending and not s._inflight
+        for oid, want in model[-1].items():
+            assert s.read(CID, oid) == want
+    finally:
+        s.umount()
+
+
+@pytest.mark.parametrize("point", COMMIT_POINTS)
+def test_write_behind_power_cut_keeps_every_acked_write(tmp_path, point):
+    """A power cut at each point of a group under a load of interleaved
+    overwrites: a fresh mount holds exactly what the acked transactions
+    wrote, every checksum good.  before_data_write leaves the group's
+    data in memory alone; before_data_sync and before_kv leave it in
+    the file, written and unreferenced."""
+    import threading
+    from ceph_tpu.store.blockstore import BlockStore, StoreError
+    path = str(tmp_path / "bs")
+    s = _write_behind_store(path)
+    com = s._committer
+    acked, model = [], []
+    _write_behind_load(s, 10, acked, model)
+    s.sync()
+    # one group still commits with the cut armed, the next is cut; the
+    # gate makes each burst one group, staged after the group before
+    # it was done
+    com.crash_at, com.crash_skip = point, 1
+    for n_done in (14, None):
+        com.gate = threading.Event()
+        written = s.commit_counters()["deferred_writes"]
+        staged = s._staged_n
+        _write_behind_load(s, 4, acked, model, seed=n_done or 5)
+        com.gate.set()
+        if n_done is not None:
+            s.sync()
+            assert acked == list(range(n_done))
+    with pytest.raises(StoreError):
+        s.sync()
+    assert com.dead
+    assert acked == list(range(14)) and len(model) == 18
+    n_acked = len(acked)
+    c = s.commit_counters()
+    assert c["writes_after_data_sync"] == 0
+    assert c["acks_before_commit"] == 0
+    n_cut = s._staged_n - staged
+    assert n_cut >= 8
+    if point == "before_data_write":    # never reached the file
+        assert len(s._pending) == len(s._inflight) == n_cut
+        assert c["deferred_writes"] == written
+    else:                               # in the file, and unreferenced
+        assert not s._pending and not s._inflight
+        assert c["deferred_writes"] == written + n_cut
+    # memory still serves what was applied, acked or not
+    for oid, want in model[-1].items():
+        assert s.read(CID, oid) == want
+    # the cut: no umount (it would flush); what the files hold
+    s2 = BlockStore(path)
+    s2.mount()
+    try:
+        for oid, want in model[n_acked - 1].items():
+            assert s2.read(CID, oid) == want, oid
+        assert {o.name for o in s2.collection_list(CID)} == {
+            o.name for o in model[n_acked - 1]}
+    finally:
+        s2.umount()
+
+
+@pytest.mark.parametrize("fault", [False, True],
+                         ids=["sound", "fsync_before_the_drain"])
+def test_writes_after_data_sync_counts_a_barrier_ahead_of_its_data(
+        tmp_path, fault):
+    """The control for the counter: a commit thread whose data barrier
+    runs BEFORE the staged records are written reads every record of
+    every group late; the sound one reads 0."""
+    s = _write_behind_store(str(tmp_path / "bs"))
+    com = s._committer
+    if fault:
+        write, sync = com.data_write, com.data_sync
+        # the fsync first (nothing drained: the count as it stood) ...
+        com.data_write = lambda: (sync(), s._written_n)[1]
+        com.data_sync = write       # ... and the write-out after it
+    acked, model = [], []
+    try:
+        base = s.commit_counters()["deferred_writes"]
+        _write_behind_load(s, 12, acked, model)
+        s.sync()
+        c = s.commit_counters()
+        assert acked == list(range(12))
+        late = c["writes_after_data_sync"]
+        if fault:
+            assert 0 < late <= c["deferred_writes"] - base
+        else:
+            assert late == 0
+        for oid, want in model[-1].items():
+            assert s.read(CID, oid) == want
+    finally:
+        s.umount()

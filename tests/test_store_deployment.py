@@ -319,13 +319,116 @@ def test_overwrite_at_an_extents_edges_keeps_the_bytes_around_it(
         store.umount()
 
 
+@pytest.mark.parametrize("compression", ["", "zlib"])
+def test_reads_see_writes_whose_data_is_still_staged(tmp_path, compression):
+    """With the kv-sync thread held at its gate nothing a transaction
+    wrote is in the file: a read, the read-modify-write of a cut
+    extent, a clone and a range clone are served from the in-flight
+    table, a failed batch leaves nothing behind, a caller's buffer is
+    the caller's again once the call has returned; and once the thread
+    has run, the table is empty and the file answers the same."""
+    from ceph_tpu.store.blockstore import MIN_ALLOC
+    from ceph_tpu.store.objectstore import OP_WRITE, TxOp
+    store = BlockStore(str(tmp_path / "s"), compression=compression)
+    store.mkfs()
+    store.mount()
+    com = store._committer
+    cid = CollectionId("e_head")
+    oid, twin, part, buf_oid = (ObjectId(n) for n in (
+        "o", "twin", "part", "buf"))
+    blk = MIN_ALLOC
+    rng = np.random.default_rng(3)
+
+    def payload(n_blocks):      # compressible, and no two alike
+        return np.repeat(rng.integers(0, 256, n_blocks * blk // 16,
+                                      np.uint8), 16).tobytes()
+
+    def hits():
+        return store.commit_counters()["inflight_read_hits"]
+    try:
+        store.apply_transaction(Transaction().create_collection(cid)
+                                .write(cid, oid, 0, payload(8)))
+        assert not store._pending and not store._inflight
+        assert hits() == 0
+        com.gate = threading.Event()            # the thread is held
+        want = {oid: payload(8)}
+        store.queue_transactions([Transaction().write(
+            cid, oid, 0, want[oid])])
+        staged = len(store._pending)
+        assert staged >= 1 and len(store._inflight) == staged
+        if compression:     # what is staged is what the file will hold
+            ext = store._get_onode(cid, oid).extents[0]
+            assert ext.alg == compression and ext.disk_len < ext.length
+        h = hits()
+        assert store.read(cid, oid) == want[oid]
+        assert hits() > h
+        # a cut extent: its surviving bytes come from the table
+        h, cut = hits(), payload(2)
+        store.queue_transactions([Transaction().write(
+            cid, oid, 3 * blk, cut)])
+        want[oid] = want[oid][:3 * blk] + cut + want[oid][5 * blk:]
+        assert hits() > h
+        assert store.read(cid, oid) == want[oid]
+        # clone and range clone, the second in the batch that wrote
+        # its source
+        h = hits()
+        store.queue_transactions([Transaction().clone(cid, oid, twin)])
+        want[twin] = want[oid]
+        fresh = payload(4)
+        store.queue_transactions([Transaction().write(
+            cid, buf_oid, 0, fresh).clone_range(
+                cid, buf_oid, part, blk, 2 * blk, 0)])
+        want[buf_oid], want[part] = fresh, fresh[blk:3 * blk]
+        assert hits() > h
+        # a batch that fails takes its records and its entries back
+        before = (len(store._pending), set(store._inflight))
+        with pytest.raises(StoreError):
+            store.queue_transactions([Transaction().write(
+                cid, oid, 0, payload(4)).write(
+                    CollectionId("missing"), oid, 0, b"x")])
+        assert (len(store._pending), set(store._inflight)) == before
+        # a buffer the caller may still change is copied, once
+        buf = bytearray(payload(4))
+        want[buf_oid] = bytes(buf)
+        txn = Transaction()
+        txn.ops.append(TxOp(OP_WRITE, cid, buf_oid, off=0,
+                            length=len(buf), data=buf))
+        store.queue_transactions([txn])
+        buf[:] = bytes(len(buf))
+        for name, data in want.items():
+            assert store.read(cid, name) == data, name
+        assert store.commit_counters()["deferred_writes"] == 1
+        com.gate.set()
+        com.gate = None
+        store.sync()
+        c = store.commit_counters()
+        assert not store._pending and not store._inflight
+        assert c["writes_after_data_sync"] == 0
+        assert c["deferred_bytes"] >= (1 if compression else 8 * blk)
+        h = hits()
+        for name, data in want.items():         # now from the file
+            assert store.read(cid, name) == data, name
+        assert hits() == h
+        store.umount()
+        store.mount()
+        for name, data in want.items():
+            assert store.read(cid, name) == data, name
+    finally:
+        store.umount()
+
+
 # ------------------------------------ (iii) the threaded group's spans
 class SlowBarrierStore(BlockStore):
-    """Barriers long enough that the gaps between spans do not count."""
+    """A write-out and barriers long enough that the gaps between spans
+    do not count."""
 
-    def _data_barrier(self):
+    def _write_pending(self):
+        time.sleep(0.002)
+        return super()._write_pending()
+
+    def _fsync_block(self):
         time.sleep(0.004)
-        super()._data_barrier()
+        super()._fsync_block()
 
 
 def _traced_store(path, op_tracing: bool):
@@ -356,8 +459,8 @@ def test_store_stages_tile_a_transactions_wait_for_durability(tmp_path):
     store.apply_transaction(Transaction().create_collection(cid))
     n_txn, rounds = 5, 3
     before = {s: _stage(ctx, s) for s in (
-        "store_commit_wait", "store_data_sync", "store_kv_sync",
-        "store_resume")}
+        "store_commit_wait", "store_data_write", "store_data_sync",
+        "store_kv_sync", "store_resume")}
     queue_s = 0.0
 
     async def one_round(r):
@@ -387,14 +490,17 @@ def test_store_stages_tile_a_transactions_wait_for_durability(tmp_path):
                  _stage(ctx, s)[1] - before[s][1]) for s in before}
         n = n_txn * rounds
         assert d["store_commit_wait"][0] == d["store_resume"][0] == n
-        assert d["store_data_sync"][0] == d["store_kv_sync"][0] == rounds
+        assert d["store_data_write"][0] == d["store_data_sync"][0] \
+            == d["store_kv_sync"][0] == rounds
         # per transaction: wait for the thread (the gate: no gather
-        # here, the group was whole when taken) + its group's two
+        # here, the group was whole when taken) + its group's three
         # sections + the wait for the loop
-        tiled = queue_s + n_txn * (d["store_data_sync"][1]
+        tiled = queue_s + n_txn * (d["store_data_write"][1]
+                                   + d["store_data_sync"][1]
                                    + d["store_kv_sync"][1]) \
             + d["store_resume"][1]
         whole = d["store_commit_wait"][1]
+        assert d["store_data_write"][1] >= rounds * 0.002
         assert d["store_data_sync"][1] >= rounds * 0.004
         assert d["store_kv_sync"][1] >= rounds * 0.003
         assert abs(tiled - whole) <= 0.05 * whole, (tiled, whole, d)
@@ -424,8 +530,43 @@ def test_with_tracing_off_the_committers_spans_read_no_clock(
     try:
         run(go())
         assert store._committer.tracer is ctx.tracer   # asked, and off
-        assert store.commit_counters()["kv_syncs"] >= 1
+        c = store.commit_counters()
+        assert c["kv_syncs"] >= 1
+        assert c["deferred_writes"] >= 1        # the write-out ran too
         assert ctx.tracer._hist is None                 # nothing recorded
+    finally:
+        store.umount()
+
+
+def test_the_write_out_is_an_annotation_on_the_kv_sync_thread(
+        tmp_path, monkeypatch):
+    """store_data_write is a section: the profiler's trace holds it by
+    name on the thread that ran it, the store's kv-sync thread, ahead
+    of the group's two barriers."""
+    from ceph_tpu.common import tracer as tracer_mod
+    seen = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append((self.name, threading.current_thread().name))
+
+        def __exit__(self, *exc):
+            return False
+    monkeypatch.setattr(tracer_mod, "_annotation_cls", Annotation)
+    ctx, store = _traced_store(tmp_path / "s", True)
+    try:
+        seen.clear()
+        store.apply_transaction(
+            Transaction().create_collection(CollectionId("t_head")).write(
+                CollectionId("t_head"), ObjectId("o"), 0, b"d" * 8192))
+        assert seen == [(name, "kv_sync_thread") for name in (
+            "store_data_write", "store_data_sync", "store_kv_sync")]
+        assert threading.current_thread().name != "kv_sync_thread"
+        assert "store_data_write" in tracer_mod.AUX_STAGES
+        assert _stage(ctx, "store_data_write")[0] >= 1
     finally:
         store.umount()
 
