@@ -28,7 +28,8 @@ import time
 from typing import Dict, List, Optional, Set
 
 from ceph_tpu.client import ObjectOperationError
-from ceph_tpu.qa.cluster import Cluster
+from ceph_tpu.mon.client import CommandError
+from ceph_tpu.qa.cluster import Cluster, make_ctx
 
 
 class ObjectModel:
@@ -136,26 +137,63 @@ class Thrasher:
             await self._heal()
 
 
+#: What one run may spend, phase by phase, in seconds.  The rounds stop
+#: as a liveness failure once ROUNDS_BUDGET_S is gone (the op then in
+#: flight may still wait out the client's own 30 s); the settle wait
+#: and the final verify each have ONE deadline.
+ROUNDS_BUDGET_S = 345.0
+WAIT_CLEAN_S = 60.0
+FINAL_VERIFY_S = 45.0
+
+
+def _ctx(name):
+    c = make_ctx(name)
+    # the checker's signal is CONSISTENCY under thrasher-driven
+    # kills, not heartbeat tuning: on a loaded box the fast-test
+    # grace (1.5s) false-positives into a mon-flap storm that
+    # wedges runs (seeds 406/422) — relax it; real kills still
+    # stop heartbeats entirely and get detected
+    c.config.set("osd_heartbeat_grace", 5.0)
+    return c
+
+
 async def run_model(seed: int, rounds: int = 80, n_osds: int = 5,
                     pool_kw: Optional[dict] = None,
                     n_oids: int = 24,
                     verbose: bool = False) -> dict:
-    """One seeded run: returns a result dict (ok, ops, ambiguities...)."""
+    """One seeded run: returns a result dict (ok, ops, ambiguities...).
+
+    What a run can cost: ROUNDS_BUDGET_S + 30 (the last op's own
+    timeout) + WAIT_CLEAN_S + FINAL_VERIFY_S = 480 s, plus what no
+    constant bounds: cluster start, the thrasher's heal (each of its
+    mon commands may wait out 30 s) and the stop.  Those are seconds in
+    a run that passes and were 90-120 s in the worst runs of the
+    census (ROADMAP D10), so a run reports by itself within 600 s; a
+    loop too busy to run its timers on time is ended from outside (the
+    tests' time_limit).  On every exit — pass, fail, exception or
+    cancellation — the thrasher is stopped and the cluster shut down."""
+    cl = Cluster(ctx_factory=_ctx)
+    try:
+        return await _run(cl, seed, rounds, n_osds, pool_kw, n_oids,
+                          verbose)
+    finally:
+        await cl.stop()
+
+
+async def _run(cl: Cluster, seed: int, rounds: int, n_osds: int,
+               pool_kw: Optional[dict], n_oids: int,
+               verbose: bool) -> dict:
     rng = random.Random(seed)
     events: List[str] = []
+    #: where the run's wall time went, phase by phase
+    seconds: Dict[str, float] = {}
+    mark = time.monotonic()
 
-    def _ctx(name):
-        from ceph_tpu.qa.cluster import make_ctx
-        c = make_ctx(name)
-        # the checker's signal is CONSISTENCY under thrasher-driven
-        # kills, not heartbeat tuning: on a loaded box the fast-test
-        # grace (1.5s) false-positives into a mon-flap storm that
-        # wedges runs (seeds 406/422) — relax it; real kills still
-        # stop heartbeats entirely and get detected
-        c.config.set("osd_heartbeat_grace", 5.0)
-        return c
-
-    cl = Cluster(ctx_factory=_ctx)
+    def _phase(name):
+        nonlocal mark
+        now = time.monotonic()
+        seconds[name] = round(now - mark, 1)
+        mark = now
     admin = await cl.start(n_osds)
     await admin.pool_create("model", pg_num=8,
                             **(pool_kw or {"size": 3}))
@@ -182,8 +220,17 @@ async def run_model(seed: int, rounds: int = 80, n_osds: int = 5,
                                sorted(snap_order, reverse=True))
         else:
             io.set_write_snapc(0, [])
+    _phase("start")
+    rounds_deadline = time.monotonic() + ROUNDS_BUDGET_S
     try:
         for r in range(rounds):
+            if time.monotonic() >= rounds_deadline:
+                # ops that each wait out their timeout: the run is
+                # wedged, and more rounds would only say so again
+                failures.append(
+                    f"rounds: budget of {ROUNDS_BUDGET_S:g}s spent "
+                    f"after {r} of {rounds} rounds")
+                break
             await asyncio.sleep(rng.uniform(0.0, 0.06))
             oid = rng.choice(oids)
             op = rng.choice(["write", "write", "write", "read", "read",
@@ -292,39 +339,59 @@ async def run_model(seed: int, rounds: int = 80, n_osds: int = 5,
                 stats["ambiguous"] += 1
                 events.append(f"round {r}: {op} {oid} ambiguous ({e!r})")
     finally:
-        await thrasher.stop()
-
-    # settle: all osds healed; wait for every pg clean, then final verify
-    await _wait_clean(cl, admin, events)
-    for oid in oids:
-        deadline = time.monotonic() + 45.0
-        while True:
-            try:
-                got = await io.read(oid, timeout=10.0)
-                break
-            except ObjectOperationError:
-                got = None
-                break
-            except asyncio.TimeoutError:
-                if time.monotonic() >= deadline:
-                    # prolonged unavailability after full heal is a
-                    # LIVENESS failure (wedged pg), distinct from loss
-                    failures.append(
-                        f"final read {oid} unavailable after 45s")
-                    got = "__unavailable__"
-                    break
-        if got == "__unavailable__":
-            events.extend(_forensics(cl, admin, "model", oid))
-            continue
-        stats["read_checks"] += 1
-        if not model.check(oid, got):
-            failures.append(
-                f"final: {oid} = {got if got is None else got[:16]!r} "
-                f"not acceptable")
-            events.extend(_forensics(cl, admin, "model", oid))
-    await cl.stop()
+        _phase("rounds")
+        try:
+            await thrasher.stop()
+        except CommandError as e:
+            # the mon did not answer the heal: the cluster is NOT
+            # whole, and the settle wait and the reads say where
+            failures.append(f"heal: {e}")
+    _phase("heal")
+    # settle: all osds healed; every pg must go clean, then every
+    # object must read back as one of its acceptable values
+    dirty = await _wait_clean(cl)
+    if dirty:
+        failures.append(f"wait_clean: {len(dirty)} pgs not clean after "
+                        f"{WAIT_CLEAN_S:g}s: " + "; ".join(dirty))
+    _phase("settle")
+    reads = {oid: asyncio.ensure_future(_read_until_answered(io, oid))
+             for oid in oids}
+    try:
+        await asyncio.wait(reads.values(), timeout=FINAL_VERIFY_S)
+        for oid in oids:
+            if not reads[oid].done():
+                # prolonged unavailability after full heal is a
+                # LIVENESS failure (wedged pg), distinct from loss
+                failures.append(f"final read {oid} unavailable after "
+                                f"{FINAL_VERIFY_S:g}s")
+                events.extend(_forensics(cl, admin, "model", oid))
+                continue
+            got = reads[oid].result()
+            stats["read_checks"] += 1
+            if not model.check(oid, got):
+                failures.append(
+                    f"final: {oid} = "
+                    f"{got if got is None else got[:16]!r} "
+                    f"not acceptable")
+                events.extend(_forensics(cl, admin, "model", oid))
+    finally:
+        for t in reads.values():
+            t.cancel()
+        await asyncio.gather(*reads.values(), return_exceptions=True)
+    _phase("verify")
+    epoch = admin.monc.osdmap.epoch
+    if failures:
+        # tells a wedge from a storm of epochs nobody asked for
+        events.append(
+            f"churn: reached osdmap epoch {epoch}; the thrasher made "
+            + ", ".join(f"{sum(e.startswith(p) for e in events)} {what}"
+                        for p, what in (("killed osd", "kills"),
+                                        ("out osd", "outs"),
+                                        ("false-down osd",
+                                         "false downs"))))
     result = {"seed": seed, "ok": not failures, "failures": failures,
-              **stats, "events": len(events)}
+              **stats, "events": len(events), "epoch": epoch,
+              "seconds": seconds}
     if verbose or failures:
         for e in events:
             print("  ", e, file=sys.stderr)
@@ -371,22 +438,40 @@ def _forensics(cl: Cluster, admin, pool: str, oid: str) -> List[str]:
     return out
 
 
-async def _wait_clean(cl: Cluster, admin, events: List[str],
-                      timeout: float = 60.0) -> None:
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        dirty = 0
+async def _read_until_answered(io, oid: str) -> Optional[bytes]:
+    """The final verify's read of one object: retried until the cluster
+    answers (None = ENOENT).  The caller holds the one deadline."""
+    while True:
+        try:
+            return await io.read(oid, timeout=10.0)
+        except ObjectOperationError:
+            return None
+        except asyncio.TimeoutError:
+            pass
+
+
+async def _wait_clean(cl: Cluster) -> List[str]:
+    """Wait up to WAIT_CLEAN_S for every pg to be active with nothing
+    to backfill or recover; returns what is still dirty then, one
+    description per pg in pgid order (empty = clean)."""
+    deadline = time.monotonic() + WAIT_CLEAN_S
+    while True:
+        dirty = []
         for osd in cl.osds.values():
             for pg in osd.pgs.values():
                 if not pg.is_primary():
                     continue
-                if pg.state != "active" or pg._backfilling or \
-                        any(pm.items for pm in pg.peer_missing.values()):
-                    dirty += 1
-        if dirty == 0:
-            return
+                missing = {p: len(pm.items)
+                           for p, pm in sorted(pg.peer_missing.items())
+                           if pm.items}
+                if pg.state != "active" or pg._backfilling or missing:
+                    dirty.append(
+                        f"{pg.pgid} state={pg.state} "
+                        f"backfilling={sorted(pg._backfilling)} "
+                        f"peer_missing={missing}")
+        if not dirty or time.monotonic() >= deadline:
+            return sorted(dirty)
         await asyncio.sleep(0.3)
-    events.append(f"wait_clean timed out with {dirty} dirty pgs")
 
 
 def main(argv=None) -> int:

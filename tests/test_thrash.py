@@ -3,264 +3,35 @@
 Runs ceph_tpu/qa/rados_model.py seeds in-process — randomized
 write/delete/read workloads raced against osd kills, restarts, out/in
 flaps and false down marks, with object-level verification against an
-in-memory model — plus a targeted crash-mid-backfill case proving the
-backfill_complete marker forces a resync retry (VERDICT r2 ask #8).
+in-memory model.  This file is the replicated sweep; the EC sweep is
+tests/test_thrash_ec.py and the targeted backfill / throttle cases are
+tests/test_thrash_targeted.py: one file each, because the driver's
+`--dist loadfile` gives a file to one worker.
 """
 
 import asyncio
 import os
-import sys
 
 import pytest
 
-sys.path.insert(0, __file__.rsplit("/", 1)[0])
-from test_osd import Cluster  # noqa: E402
-
-from ceph_tpu.qa.rados_model import run_model  # noqa: E402
+from ceph_tpu.qa.rados_model import run_model
 
 # the standalone runner covers many more: python -m ceph_tpu.qa.rados_model
 SEEDS = range(1, 1 + int(os.environ.get("THRASH_SEEDS", "6")))
 
 # seed 5's kill pattern replays ~48 s of recovery wall time and pins
 # no named regression (1-4, 6 keep the default-tier churn coverage);
-# it runs in the slow tier with the EC role-change seed below
+# it runs in the slow tier with the EC role-change seed
+# (tests/test_thrash_ec.py)
 _REP_SLOW = {5}
 SEEDS = [pytest.param(s, marks=pytest.mark.slow) if s in _REP_SLOW
          else s for s in SEEDS]
 
-# EC churn seeds.  101 drove six earlier fixes; 105 is the regression
-# seed for the role-change wedge (an EC shard moving osd slots, e.g.
-# s2 -> s0 on one osd, left a newborn primary starved of peering
-# replies behind its own old-shard stray) and for the backfill-cursor
-# read gate (a mid-backfill replica must serve versioned objects it
-# holds and answer EAGAIN — never ENOENT — for names past its cursor).
-# Widen locally with EC_SEEDS=10; the standalone runner covers more:
-# python -m ceph_tpu.qa.rados_model --ec --seeds 10
-_N_EC = int(os.environ.get("EC_SEEDS", "2"))
-EC_SEEDS = [101, 105] if _N_EC <= 2 else list(range(101, 101 + _N_EC))
 
-# Seed 105 replays the role-change wedge end to end (~150 s wall); it
-# stays required coverage but runs in the slow tier so the default
-# sweep fits its time budget.  python -m ceph_tpu.qa.rados_model --ec
-# still covers it, as does pytest without `-m 'not slow'`.
-_EC_SLOW = {105}
-EC_SEEDS = [
-    pytest.param(s, marks=pytest.mark.slow) if s in _EC_SLOW else s
-    for s in EC_SEEDS
-]
-
-
+# run_model's own worst case (its docstring) plus a margin: the limit
+# from outside (tests/conftest.py) only ends what the budgets missed
+@pytest.mark.time_limit(630)
 @pytest.mark.parametrize("seed", SEEDS)
 def test_model_checker_replicated(seed):
     res = asyncio.run(run_model(seed, rounds=60))
     assert res["ok"], res["failures"]
-
-
-@pytest.mark.parametrize("seed", EC_SEEDS)
-def test_model_checker_ec_pool(seed):
-    # required (no xfail) since the per-object backfill-cursor +
-    # shard-aware primariness work: the historical ~1/6-seed ENOENT
-    # window came from cursor-blind replicas serving holes as
-    # deletions and from role-changed primaries wedging mid-recovery
-    res = asyncio.run(run_model(
-        seed, rounds=50, n_osds=5,
-        pool_kw={"pool_type": "erasure", "k": 2, "m": 2}))
-    assert res["ok"], res["failures"]
-
-
-def test_crash_mid_backfill_forces_retry():
-    """Kill the backfill TARGET mid-resync: on restart its
-    backfill_complete=False marker must force a fresh full resync
-    instead of trusting the half-copied object set."""
-    from ceph_tpu.osd.pglog import PGLog
-
-    async def run():
-        old_max = PGLog.MAX_ENTRIES
-        PGLog.MAX_ENTRIES = 8     # shut the log window fast
-        try:
-            cl = Cluster()
-            admin = await cl.start(3)
-            await admin.pool_create("p", pg_num=1, size=3)
-            io = admin.open_ioctx("p")
-            for i in range(10):
-                await io.write_full(f"a{i}", bytes([i]) * 512)
-            # take osd.2 down; write far past the log window so catch-up
-            # requires a FULL resync, with many objects to copy
-            store2 = await cl.kill_osd(2)
-            await cl.mark_down_and_wait(admin, 2)
-            for i in range(40):
-                await io.write_full(f"b{i}", bytes([i]) * 2048)
-            # restart the stale osd; let backfill BEGIN and stamp a
-            # partial cursor, then crash it before it can finish
-            from ceph_tpu.osd.pglog import LB_MAX
-            osd2 = await cl.start_osd(2, store=store2)
-            deadline = asyncio.get_running_loop().time() + 20
-            started = False
-            while not started:
-                for pg in osd2.pgs.values():
-                    if not pg.info.backfill_complete \
-                            and pg.info.last_backfill \
-                            and pg.info.last_backfill != LB_MAX:
-                        started = True
-                assert asyncio.get_running_loop().time() < deadline, \
-                    "backfill never started"
-                await asyncio.sleep(0.002)
-            store2 = await cl.kill_osd(2)
-            await cl.mark_down_and_wait(admin, 2)
-            # the crashed copy must have persisted the incomplete marker
-            # (that is the crash-safety claim under test) — and its
-            # DURABLE last_backfill cursor, which the retry must resume
-            # FROM rather than restarting the copy from scratch
-            from ceph_tpu.osd.pg import PGInfo
-            killed_cursor = ""
-            # scan every collection's meta object for a pg info row
-            for cid in store2.list_collections():
-                for o in store2.collection_list(cid):
-                    try:
-                        _, omap = store2.omap_get(cid, o)
-                    except Exception:
-                        continue
-                    if b"info" in omap:
-                        info = PGInfo.from_bytes(omap[b"info"])
-                        killed_cursor = max(killed_cursor,
-                                            info.last_backfill)
-            assert killed_cursor and killed_cursor != LB_MAX, \
-                "no durable partial cursor found on the killed store"
-            # restart again: the marker forces a retry; eventually every
-            # object lands and the copy is trusted — and the cursor
-            # NEVER regresses below its killed-time durable value
-            osd2 = await cl.start_osd(2, store=store2)
-            deadline = asyncio.get_running_loop().time() + 40
-            while True:
-                for pg in osd2.pgs.values():
-                    if not pg.info.backfill_complete:
-                        lb = pg.info.last_backfill
-                        assert lb >= killed_cursor, \
-                            (f"resume regressed below the durable "
-                             f"cursor: {lb!r} < {killed_cursor!r}")
-                pgs = list(osd2.pgs.values())
-                if pgs and all(p.info.backfill_complete for p in pgs):
-                    names = {o.name
-                             for pg in pgs
-                             for o in osd2.store.collection_list(pg.cid)
-                             if o.name != pg.meta_oid.name}
-                    want = ({f"a{i}" for i in range(10)}
-                            | {f"b{i}" for i in range(40)})
-                    if want <= names:
-                        break
-                assert asyncio.get_running_loop().time() < deadline, \
-                    "resync never completed after mid-backfill crash"
-                await asyncio.sleep(0.2)
-            # and the data is right everywhere
-            for i in range(40):
-                assert await io.read(f"b{i}") == bytes([i]) * 2048
-            await cl.stop()
-        finally:
-            PGLog.MAX_ENTRIES = old_max
-    asyncio.run(run())
-
-
-def test_backfill_windowed_listing_and_cursor_resume():
-    """Large-PG backfill with a tiny scan window (osd_backfill_scan_max)
-    must page the listing in bounded messages, and a target killed
-    mid-backfill must RESUME from its persisted last_backfill cursor
-    rather than restarting from scratch (PG.h:1911)."""
-    from ceph_tpu.osd.pglog import LB_MAX, PGLog
-
-    async def run():
-        old_max = PGLog.MAX_ENTRIES
-        PGLog.MAX_ENTRIES = 8
-        try:
-            from ceph_tpu.qa.cluster import make_ctx
-
-            def ctx_f(name):
-                c = make_ctx(name)
-                c.config.set("osd_backfill_scan_max", 7)
-                return c
-            cl = Cluster(ctx_factory=ctx_f)
-            admin = await cl.start(3)
-            await admin.pool_create("p", pg_num=1, size=3)
-            io = admin.open_ioctx("p")
-            store2 = await cl.kill_osd(2)
-            await cl.mark_down_and_wait(admin, 2)
-            # 60 objects, far beyond the log window -> full backfill
-            # paged across ~9 windows of 7
-            for i in range(60):
-                await io.write_full(f"obj{i:03d}", bytes([i]) * 1024)
-            osd2 = await cl.start_osd(2, store=store2)
-            # catch it mid-backfill with a partial cursor, then kill
-            deadline = asyncio.get_running_loop().time() + 30
-            cursor = None
-            while cursor is None:
-                for pg in osd2.pgs.values():
-                    lb = pg.info.last_backfill
-                    if lb and lb != LB_MAX:
-                        cursor = lb
-                assert asyncio.get_running_loop().time() < deadline, \
-                    "no partial cursor observed"
-                await asyncio.sleep(0.002)
-            store2 = await cl.kill_osd(2)
-            await cl.mark_down_and_wait(admin, 2)
-            osd2 = await cl.start_osd(2, store=store2)
-            deadline = asyncio.get_running_loop().time() + 60
-            while True:
-                pgs = list(osd2.pgs.values())
-                if pgs and all(p.info.backfill_complete for p in pgs):
-                    break
-                assert asyncio.get_running_loop().time() < deadline, \
-                    "backfill never completed after resume"
-                await asyncio.sleep(0.05)
-            # every object must be present and correct on the resumed
-            # copy (read each back through the cluster)
-            for i in range(60):
-                got = await io.read(f"obj{i:03d}")
-                assert got == bytes([i]) * 1024, f"obj{i:03d} corrupt"
-            await cl.stop()
-        finally:
-            PGLog.MAX_ENTRIES = old_max
-    asyncio.run(run())
-
-
-def test_op_intake_throttle_bounds_memory():
-    """Flood one OSD with more write bytes than the intake cap: the
-    dispatch throttle must bound in-flight bytes (clients block on TCP
-    backpressure, ops still all complete) — VERDICT r3 weak #6."""
-    async def run():
-        from ceph_tpu.qa.cluster import make_ctx
-
-        def ctx_f(name):
-            c = make_ctx(name)
-            c.config.set("osd_client_message_size_cap", 262144)
-            return c
-        cl = Cluster(ctx_factory=ctx_f)
-        admin = await cl.start(1)
-        await admin.pool_create("p", pg_num=1, size=1)
-        io = admin.open_ioctx("p")
-        osd = next(iter(cl.osds.values()))
-        thr = osd.messenger.dispatch_throttle
-        assert thr is not None and thr.max == 262144
-        peak = 0
-
-        async def watch():
-            nonlocal peak
-            while True:
-                peak = max(peak, thr.cur)
-                await asyncio.sleep(0.001)
-        w = asyncio.get_running_loop().create_task(watch())
-        # 8 MiB of writes vs a 256 KiB budget
-        writes = [io.write_full(f"o{i}", bytes([i % 256]) * 65536)
-                  for i in range(128)]
-        await asyncio.gather(*writes)
-        w.cancel()
-        assert peak <= 262144, f"throttle exceeded: {peak}"
-        assert thr.waited > 0, "flood never hit the throttle"
-        # drained: nothing leaked budget
-        for _ in range(100):
-            if thr.cur == 0:
-                break
-            await asyncio.sleep(0.01)
-        assert thr.cur == 0, f"leaked {thr.cur} bytes of intake budget"
-        for i in range(0, 128, 17):
-            assert await io.read(f"o{i}") == bytes([i % 256]) * 65536
-        await cl.stop()
-    asyncio.run(run())
