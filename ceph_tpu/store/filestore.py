@@ -156,7 +156,7 @@ class FileStore(MemStore):
             for _ in range(nobj):
                 oid = dec.struct(ObjectId)
                 o = Obj()
-                o.data = bytearray(dec.bytes_())
+                o.data = dec.bytes_()
                 o.xattrs = {k.decode("utf-8"): v for k, v in dec.map_(
                     lambda d: d.bytes_(), lambda d: d.bytes_()).items()}
                 o.omap = dec.map_(lambda d: d.bytes_(), lambda d: d.bytes_())

@@ -4,6 +4,14 @@ Reference parity: os/memstore/MemStore.cc (RAM-backed fake store used to run
 OSD logic without disks).  Holds the canonical Transaction apply semantics
 that FileStore reuses.
 
+An object's data is held as the buffer it was written with.  A write of
+an immutable ``bytes`` that covers the whole object is ADOPTED by
+reference (an EC shard's full write: ``truncate(0)`` then ``write(0, ...)``)
+and a whole read hands that same ``bytes`` back; an op that changes part
+of an object first makes the buffer a ``bytearray`` (copy on write, once).
+Which of the two runs follows from the op alone: the type of its data, its
+offset, the object's current length.
+
 Apply is TOTAL: mutation ops never raise — destructive ops on missing
 targets are no-ops, constructive ops create their collection/object, and
 unknown op codes are skipped (forward compat, mirroring encoding's
@@ -33,14 +41,17 @@ class Obj:
     __slots__ = ("data", "xattrs", "omap", "omap_header")
 
     def __init__(self):
-        self.data = bytearray()
+        #: ``bytes``: adopted whole, shared freely (immutable);
+        #: ``bytearray``: the store's own, mutated in place
+        self.data = b""
         self.xattrs: Dict[str, bytes] = {}
         self.omap: Dict[bytes, bytes] = {}
         self.omap_header = b""
 
     def clone(self) -> "Obj":
         o = Obj()
-        o.data = bytearray(self.data)
+        o.data = (self.data if type(self.data) is bytes
+                  else bytearray(self.data))
         o.xattrs = dict(self.xattrs)
         o.omap = dict(self.omap)
         o.omap_header = self.omap_header
@@ -58,6 +69,12 @@ class MemStore(ObjectStore):
         self.colls: Dict[CollectionId, Dict[ObjectId, Obj]] = {}
         self.mounted = False
         self._committer = None
+        # how often data is kept by reference and how often copied
+        # (reported by commit_counters): plain counts, no clock read
+        self._data_counts = dict.fromkeys(
+            ("adopted_writes", "adopted_bytes", "copied_write_bytes",
+             "cow_copies", "cow_bytes", "reads_by_reference",
+             "read_copied_bytes"), 0)
 
     # --- lifecycle ---
     def mkfs(self) -> None:
@@ -108,7 +125,9 @@ class MemStore(ObjectStore):
             self._committer.flush()
 
     def commit_counters(self) -> Dict[str, float]:
-        return self._committer.counters() if self._committer else {}
+        c = self._committer.counters() if self._committer else {}
+        c.update(self._data_counts)
+        return c
 
     # read-path lookups (raise) -----------------------------------------
     def _coll(self, cid) -> Dict[ObjectId, Obj]:
@@ -139,12 +158,38 @@ class MemStore(ObjectStore):
         for op in txn.ops:
             self._apply_op(op)
 
-    @staticmethod
-    def _splice(o: Obj, off: int, data: bytes) -> None:
+    def _mutable(self, o: Obj) -> bytearray:
+        """The object's buffer as one the store may change in place:
+        an adopted ``bytes`` is copied, once (copy on write)."""
+        buf = o.data
+        if type(buf) is not bytearray:
+            if buf:
+                self._data_counts["cow_copies"] += 1
+                self._data_counts["cow_bytes"] += len(buf)
+            buf = o.data = bytearray(buf)
+        return buf
+
+    def _splice(self, o: Obj, off: int, data: bytes) -> None:
+        buf = self._mutable(o)
         end = off + len(data)
-        if len(o.data) < end:
-            o.data.extend(b"\x00" * (end - len(o.data)))
-        o.data[off:end] = data
+        if len(buf) < end:
+            buf.extend(b"\x00" * (end - len(buf)))
+        buf[off:end] = data
+
+    def _write(self, o: Obj, off: int, data) -> None:
+        n, counts = len(data), self._data_counts
+        if off != 0 or n < len(o.data):
+            self._splice(o, off, data)
+            counts["copied_write_bytes"] += n
+        elif type(data) is bytes:
+            # covers the whole object and nobody can change it: keep
+            # the caller's buffer itself (BlockStore._write_range's rule)
+            o.data = data
+            counts["adopted_writes"] += 1
+            counts["adopted_bytes"] += n
+        else:
+            o.data = bytes(data)
+            counts["copied_write_bytes"] += n
 
     def _apply_op(self, op: TxOp) -> None:
         code = op.op
@@ -160,7 +205,7 @@ class MemStore(ObjectStore):
             self._obj_w(op.cid, op.oid)
             return
         if code == OP_WRITE:
-            self._splice(self._obj_w(op.cid, op.oid), op.off, op.data)
+            self._write(self._obj_w(op.cid, op.oid), op.off, op.data)
             return
         if code == OP_ZERO:
             self._splice(self._obj_w(op.cid, op.oid), op.off,
@@ -169,10 +214,12 @@ class MemStore(ObjectStore):
         if code == OP_TRUNCATE:
             o = self._obj_w(op.cid, op.oid)
             size = op.off
-            if len(o.data) > size:
-                del o.data[size:]
-            else:
-                o.data.extend(b"\x00" * (size - len(o.data)))
+            if size == 0:
+                o.data = b""
+            elif len(o.data) > size:
+                del self._mutable(o)[size:]
+            elif len(o.data) < size:
+                self._mutable(o).extend(b"\x00" * (size - len(o.data)))
             return
         if code == OP_REMOVE:
             c = self.colls.get(op.cid)
@@ -200,7 +247,8 @@ class MemStore(ObjectStore):
         if code == OP_CLONERANGE2:
             src = self._obj_opt(op.cid, op.oid)
             if src is not None:
-                chunk = bytes(src.data[op.off:op.off + op.length])
+                chunk = bytes(
+                    memoryview(src.data)[op.off:op.off + op.length])
                 self._splice(self._obj_w(op.cid, op.oid2), op.dest_off,
                              chunk)
             return
@@ -246,10 +294,17 @@ class MemStore(ObjectStore):
 
     # --- read path (raises NoSuchCollection/NoSuchObject) ---
     def read(self, cid, oid, off: int = 0, length: int = -1) -> bytes:
-        o = self._obj(cid, oid)
-        if length < 0:
-            return bytes(o.data[off:])
-        return bytes(o.data[off:off + length])
+        buf = self._obj(cid, oid).data
+        if off == 0 and (length < 0 or length >= len(buf)) \
+                and type(buf) is bytes:
+            # the whole of an adopted buffer: immutable, so the caller
+            # cannot hurt the store through it
+            self._data_counts["reads_by_reference"] += 1
+            return buf
+        end = len(buf) if length < 0 else off + length
+        out = bytes(memoryview(buf)[off:end])
+        self._data_counts["read_copied_bytes"] += len(out)
+        return out
 
     def stat(self, cid, oid) -> Dict[str, int]:
         o = self._obj(cid, oid)

@@ -5,6 +5,7 @@ value-parameterized over backends) plus journal replay/crash tests
 (DeterministicOpSequence / run_seed_to.sh analog).
 """
 
+import array
 import collections
 import os
 
@@ -1205,3 +1206,367 @@ def test_writes_after_data_sync_counts_a_barrier_ahead_of_its_data(
             assert s.read(CID, oid) == want
     finally:
         s.umount()
+
+
+# ------------------------------------- data kept by reference (MemStore)
+# MemStore (and FileStore, which applies through it) holds an object's
+# data as the immutable buffer it was written with: adopted by
+# reference, handed back by reference, copied once when part of it is
+# about to change.  The comparisons of contents run on every store; the
+# identities and the counters on the two that adopt.
+
+@pytest.fixture(params=["memstore", "filestore"])
+def mstore(request, tmp_path):
+    s = ObjectStore.create(request.param, str(tmp_path / "store"))
+    s.mkfs()
+    s.mount()
+    yield s
+    s.umount()
+
+
+MIB = 1 << 20
+SNAP = OID.with_snap(7)
+OTHER = ObjectId("other", pool=1)
+
+
+def _payload(n, seed=0):
+    return np.random.default_rng(seed).integers(
+        0, 256, n, dtype=np.uint8).tobytes()
+
+
+def _raw_write(cid, oid, off, data):
+    """A write whose op carries `data` AS GIVEN (Transaction.write makes
+    it bytes first): what a store must never keep by reference."""
+    from ceph_tpu.store.objectstore import OP_WRITE, TxOp
+    t = Transaction()
+    t.ops.append(TxOp(OP_WRITE, cid, oid, off=off, length=len(data),
+                      data=data))
+    return t
+
+
+_CHANGEABLE = {
+    "bytearray": lambda raw: bytearray(raw),
+    "memoryview": lambda raw: memoryview(bytearray(raw)),
+    "numpy": lambda raw: np.frombuffer(raw, np.uint8).copy(),
+    "array": lambda raw: array.array("B", raw),
+}
+
+
+# (the WAL's encoder takes no ndarray, so none reaches a store in an op)
+@pytest.mark.parametrize("kind,via", [
+    (k, v) for k in sorted(_CHANGEABLE) for v in ("builder", "txop")
+    if (k, v) != ("numpy", "txop")])
+def test_store_copies_a_source_its_caller_can_still_change(
+        mstore, kind, via):
+    _mkcoll(mstore)
+    raw = _payload(4096, seed=3)
+    src = _CHANGEABLE[kind](raw)
+    if via == "builder":
+        t = Transaction().write(CID, OID, 0, src)
+    else:
+        t = _raw_write(CID, OID, 0, src)
+    mstore.queue_transactions([t])
+    for i in range(2048):               # the caller reuses its buffer
+        src[i] = 0
+    got = mstore.read(CID, OID)
+    assert type(got) is bytes and got == raw
+    assert mstore.read(CID, OID, 100, 50) == raw[100:150]
+
+
+def test_adopted_bytes_are_read_back_by_identity(mstore):
+    _mkcoll(mstore)
+    data = _payload(MIB)
+    mstore.queue_transactions([Transaction().truncate(CID, OID, 0)
+                               .write(CID, OID, 0, data)])
+    assert mstore.read(CID, OID) is data
+    assert mstore.read(CID, OID, 0, MIB) is data
+    assert mstore.read(CID, OID, 0, MIB + 4096) is data
+    for off, length in ((0, MIB - 1), (1, -1), (4096, 8192), (MIB, 10)):
+        part = mstore.read(CID, OID, off, length)
+        want = data[off:] if length < 0 else data[off:off + length]
+        assert type(part) is bytes and part == want and part is not data
+    assert mstore.stat(CID, OID)["size"] == MIB
+    # a clone shares the buffer; the next whole write replaces only
+    # the head's
+    data2 = _payload(MIB // 2, seed=1)
+    mstore.apply_transaction(Transaction().clone(CID, OID, SNAP)
+                             .truncate(CID, OID, 0)
+                             .write(CID, OID, 0, data2))
+    assert mstore.read(CID, SNAP) is data
+    assert mstore.read(CID, OID) is data2
+
+
+def _mut_partial_write(t, model):
+    t.write(CID, OID, 1000, b"\xa5" * 3000)
+    model[1000:4000] = b"\xa5" * 3000
+
+
+def _mut_write_past_end(t, model):
+    t.write(CID, OID, len(model) + 100, b"tail")
+    model.extend(bytes(100) + b"tail")
+
+
+def _mut_zero(t, model):
+    t.zero(CID, OID, 10, 5000)
+    model[10:5010] = bytes(5000)
+
+
+def _mut_truncate_down(t, model):
+    t.truncate(CID, OID, 777)
+    del model[777:]
+
+
+def _mut_truncate_up(t, model):
+    t.truncate(CID, OID, len(model) + 333)
+    model.extend(bytes(333))
+
+
+def _mut_clone_range_into(t, model):
+    t.write(CID, OTHER, 0, b"0123456789" * 100)
+    t.clone_range(CID, OTHER, OID, 5, 500, 2000)
+    model[2000:2500] = (b"0123456789" * 100)[5:505]
+
+
+def _mut_clone_range_onto_itself(t, model):
+    t.clone_range(CID, OID, OID, 0, 1024, 512)
+    model[512:1536] = bytes(model[0:1024])
+
+
+_MUTATIONS = [_mut_partial_write, _mut_write_past_end, _mut_zero,
+              _mut_truncate_down, _mut_truncate_up, _mut_clone_range_into,
+              _mut_clone_range_onto_itself]
+
+
+@pytest.mark.parametrize("mutate", _MUTATIONS,
+                         ids=[m.__name__[5:] for m in _MUTATIONS])
+def test_a_change_to_part_of_an_object_copies_on_write(store, mutate):
+    """Adopt, clone, then change part of the head: the head equals a
+    plain bytearray model, and neither the ORIGINAL bytes object nor
+    the clone taken before the change moved."""
+    _mkcoll(store)
+    data = _payload(8192, seed=5)
+    kept = bytes(bytearray(data))       # a copy nothing else refers to
+    store.apply_transaction(Transaction().write(CID, OID, 0, data)
+                            .clone(CID, OID, SNAP))
+    model = bytearray(kept)
+    t = Transaction()
+    mutate(t, model)
+    store.apply_transaction(t)
+    assert store.read(CID, OID) == bytes(model)
+    assert store.stat(CID, OID)["size"] == len(model)
+    assert data == kept
+    assert store.read(CID, SNAP) == kept
+    if isinstance(store, MemStore):
+        assert store.read(CID, SNAP) is data
+    # and once more on the now-mutable buffer
+    t = Transaction()
+    mutate(t, model)
+    store.apply_transaction(t)
+    assert store.read(CID, OID) == bytes(model)
+    assert store.read(CID, SNAP) == kept
+
+
+def test_truncate_zero_then_a_shorter_write_leaves_no_tail(store):
+    _mkcoll(store)
+    long_, short = _payload(6000, seed=1), _payload(2500, seed=2)
+    store.apply_transaction(Transaction().write(CID, OID, 0, long_))
+    store.apply_transaction(Transaction().truncate(CID, OID, 0)
+                            .write(CID, OID, 0, short))
+    assert store.read(CID, OID) == short
+    assert store.read(CID, OID, 2000, 4000) == short[2000:]
+    assert store.stat(CID, OID)["size"] == 2500
+    # the same without the truncate keeps the old tail, on every store
+    store.apply_transaction(Transaction().write(CID, OID, 0, long_))
+    store.apply_transaction(Transaction().write(CID, OID, 0, short))
+    assert store.read(CID, OID) == short + long_[2500:]
+
+
+_SEQ_NAMES = [ObjectId(f"seq{i}", pool=1) for i in range(3)]
+
+
+def _random_data_txn(rng, model, sources):
+    """1-4 random data ops on three objects, applied to `model`
+    ({oid: bytearray}, absent = no such object) as they are built."""
+    def splice(oid, off, chunk):
+        m = model.setdefault(oid, bytearray())
+        end = off + len(chunk)
+        if len(m) < end:
+            m.extend(bytes(end - len(m)))
+        m[off:end] = chunk
+
+    t = Transaction()
+    for _ in range(int(rng.integers(1, 5))):
+        oid = _SEQ_NAMES[int(rng.integers(0, 3))]
+        oid2 = _SEQ_NAMES[int(rng.integers(0, 3))]
+        kind = int(rng.integers(0, 9))
+        size = len(model.get(oid, b""))
+        if kind <= 2:
+            # a write: whole (with or without the truncate an EC full
+            # write sends), or anywhere; as bytes or as a buffer the
+            # caller goes on to change
+            chunk = _payload(int(rng.integers(0, 700)),
+                             seed=int(rng.integers(0, 2**31)))
+            off = 0 if kind < 2 else int(rng.integers(0, size + 50))
+            if kind == 0:
+                t.truncate(CID, oid, 0)
+                model[oid] = bytearray()
+            if rng.integers(0, 3) == 0:
+                src = bytearray(chunk)
+                t.ops.extend(_raw_write(CID, oid, off, src).ops)
+                sources.append((src, None))
+            else:
+                t.write(CID, oid, off, chunk)
+                sources.append((chunk, bytes(bytearray(chunk))))
+            splice(oid, off, chunk)
+        elif kind == 3:
+            off, n = int(rng.integers(0, size + 20)), int(rng.integers(0, 300))
+            t.zero(CID, oid, off, n)
+            splice(oid, off, bytes(n))
+        elif kind == 4:
+            n = int(rng.choice([0, size, int(rng.integers(0, size + 200))]))
+            t.truncate(CID, oid, n)
+            m = model.setdefault(oid, bytearray())
+            if n < len(m):
+                del m[n:]
+            else:
+                m.extend(bytes(n - len(m)))
+        elif kind == 5:
+            t.clone(CID, oid, oid2)
+            if oid in model:
+                model[oid2] = bytearray(model[oid])
+        elif kind in (6, 7):
+            off, n = int(rng.integers(0, size + 10)), int(rng.integers(0, 400))
+            dst = 0 if kind == 6 else int(rng.integers(0, 300))
+            t.clone_range(CID, oid, oid2, off, n, dst)
+            if oid in model:
+                splice(oid2, dst, bytes(model[oid][off:off + n]))
+        else:
+            t.remove(CID, oid)
+            model.pop(oid, None)
+    return t
+
+
+@pytest.mark.parametrize("block", range(20))
+def test_random_data_ops_match_a_bytearray_model(mstore, block):
+    """500 seeded op sequences (25 per case): after every transaction
+    each object reads as the plain bytearray model says, whole and in
+    part, and no bytes object handed to the store ever changed."""
+    _mkcoll(mstore)
+    for seed in range(block * 25, block * 25 + 25):
+        rng = np.random.default_rng(seed)
+        model, sources = {}, []
+        t = Transaction()
+        for oid in _SEQ_NAMES:
+            t.remove(CID, oid)
+        mstore.apply_transaction(t)
+        for _ in range(int(rng.integers(3, 8))):
+            mstore.queue_transactions(
+                [_random_data_txn(rng, model, sources)])
+            for src, _ in sources:
+                if type(src) is bytearray:
+                    src[:] = b"\xee" * len(src)     # the caller's reuse
+            for oid in _SEQ_NAMES:
+                if oid not in model:
+                    assert not mstore.exists(CID, oid), (seed, oid)
+                    continue
+                want = bytes(model[oid])
+                assert mstore.read(CID, oid) == want, (seed, oid)
+                assert mstore.stat(CID, oid)["size"] == len(want)
+                a = int(rng.integers(0, len(want) + 2))
+                n = int(rng.integers(0, len(want) + 2))
+                assert mstore.read(CID, oid, a, n) == want[a:a + n]
+        for src, kept in sources:
+            assert kept is None or src == kept, seed
+
+
+def test_filestore_replays_and_snapshots_adopted_objects(tmp_path):
+    """WAL replay, then the snapshot, give back the bytes of an adopted
+    object, of one copied on write and of a clone that shares a buffer;
+    and the snapshot of adopted objects is, byte for byte, the snapshot
+    of the same contents held as bytearrays."""
+    whole, part = _payload(65536, seed=8), _payload(5000, seed=9)
+    cowed = bytearray(whole)
+    cowed[100:5100] = part
+
+    def load(path, adopt):
+        s = FileStore(path)
+        s.mkfs()
+        s.mount()
+        _mkcoll(s)
+        if adopt:
+            s.apply_transaction(Transaction().write(CID, OID, 0, whole)
+                                .clone(CID, OID, SNAP))
+            s.apply_transaction(Transaction().write(CID, OTHER, 0, whole))
+        else:       # the same contents through partial writes
+            s.apply_transaction(
+                Transaction().write(CID, OID, 1000, whole[1000:])
+                .write(CID, OID, 0, whole[:1000])
+                .clone(CID, OID, SNAP))
+            s.apply_transaction(
+                Transaction().write(CID, OTHER, 1, whole[1:])
+                .write(CID, OTHER, 0, whole[:1]))
+        s.apply_transaction(Transaction().write(CID, OTHER, 100, part))
+        return s
+
+    def check(s):
+        assert s.read(CID, OID) == whole
+        assert s.read(CID, SNAP) == whole
+        assert s.read(CID, OTHER) == bytes(cowed)
+
+    a = load(str(tmp_path / "a"), adopt=True)
+    assert a.read(CID, OID) is whole and a.read(CID, SNAP) is whole
+    check(a)
+    a._wal.close()                      # crash: the WAL alone
+    a2 = FileStore(str(tmp_path / "a"))
+    a2.mount()
+    check(a2)
+    a2.umount()                         # clean: snapshot, empty WAL
+    a3 = FileStore(str(tmp_path / "a"))
+    a3.mount()
+    assert os.path.getsize(os.path.join(a3.path, "wal")) == 0
+    check(a3)
+    # what a mount decoded is held by reference too
+    assert a3.read(CID, OID) is a3.read(CID, OID)
+    a3.umount()
+
+    b = load(str(tmp_path / "b"), adopt=False)
+    assert b.commit_counters()["adopted_writes"] == 0
+    b.umount()
+    with open(os.path.join(a3.path, "checkpoint"), "rb") as fa, \
+            open(os.path.join(b.path, "checkpoint"), "rb") as fb:
+        assert fa.read() == fb.read()
+
+
+def test_store_counts_what_it_adopts_and_what_it_copies(mstore):
+    _mkcoll(mstore)
+    names = ("adopted_writes", "adopted_bytes", "copied_write_bytes",
+             "cow_copies", "cow_bytes", "reads_by_reference",
+             "read_copied_bytes")
+
+    def delta(fn):
+        base = mstore.commit_counters()
+        fn()
+        now = mstore.commit_counters()
+        return {k: now[k] - base[k] for k in names if now[k] != base[k]}
+
+    data = _payload(MIB)
+    assert delta(lambda: mstore.queue_transactions(
+        [Transaction().truncate(CID, OID, 0).write(CID, OID, 0, data)])) \
+        == {"adopted_writes": 1, "adopted_bytes": MIB}
+    assert delta(lambda: mstore.read(CID, OID)) == {"reads_by_reference": 1}
+    assert delta(lambda: mstore.read(CID, OID, 4096, 8192)) \
+        == {"read_copied_bytes": 8192}
+    assert delta(lambda: mstore.queue_transactions(
+        [Transaction().write(CID, OID, 4096, b"x" * 100)])) \
+        == {"cow_copies": 1, "cow_bytes": MIB, "copied_write_bytes": 100}
+    # the buffer is the store's own now: no second copy, and a whole
+    # read of it is one copy
+    assert delta(lambda: mstore.queue_transactions(
+        [Transaction().write(CID, OID, 0, b"y" * 100)])) \
+        == {"copied_write_bytes": 100}
+    assert delta(lambda: mstore.read(CID, OID)) == {"read_copied_bytes": MIB}
+    # a whole write from a buffer the caller can change: copied, once
+    assert delta(lambda: mstore.queue_transactions(
+        [_raw_write(CID, OTHER, 0, bytearray(1000))])) \
+        == {"copied_write_bytes": 1000}
+    assert delta(lambda: mstore.read(CID, OTHER)) == {"reads_by_reference": 1}

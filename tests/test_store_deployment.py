@@ -121,6 +121,47 @@ def test_cluster_defaults_to_memstore_and_acks_on_apply():
     run(go())
 
 
+def test_ec_full_write_is_adopted_by_every_shard_store():
+    """A 4 MiB `write_full` on k=4 m=2 over MemStore: each of the six
+    shard OSDs keeps its 1 MiB shard by reference (no copy on write),
+    and the read back is served from those same buffers."""
+    names = ("adopted_writes", "adopted_bytes", "cow_copies",
+             "reads_by_reference")
+
+    def counts(cl):
+        return {i: {k: osd.store.commit_counters()[k] for k in names}
+                for i, osd in cl.osds.items()}
+
+    async def go():
+        cl = Cluster(ctx_factory=ctx_factory(osd_op_num_shards=4))
+        admin = await cl.start(6)
+        try:
+            await admin.pool_create("ecpool", pg_num=4,
+                                    pool_type="erasure", k=4, m=2)
+            io = admin.open_ioctx("ecpool")
+            payload = np.random.default_rng(29).integers(
+                0, 256, 4 << 20, dtype=np.uint8).tobytes()
+            base = counts(cl)
+            await io.write_full("obj", payload)
+            wrote = counts(cl)
+            for i in cl.osds:
+                assert type(cl.osds[i].store) is MemStore
+                assert wrote[i]["adopted_writes"] > base[i]["adopted_writes"]
+                assert wrote[i]["adopted_bytes"] \
+                    - base[i]["adopted_bytes"] >= 1 << 20
+                assert wrote[i]["cow_copies"] == base[i]["cow_copies"]
+            assert await io.read("obj") == payload
+            read = counts(cl)
+            served = sum(read[i]["reads_by_reference"]
+                         - wrote[i]["reads_by_reference"] for i in cl.osds)
+            assert served >= 4          # k shards make the object
+            assert all(read[i]["cow_copies"] == base[i]["cow_copies"]
+                       for i in cl.osds)
+        finally:
+            await cl.stop()
+    run(go())
+
+
 def test_cluster_start_fails_loudly_without_a_directory():
     async def go():
         cl = Cluster(ctx_factory=ctx_factory(objectstore="blockstore"))
