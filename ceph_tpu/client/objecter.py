@@ -5,19 +5,34 @@ Reference parity: osdc/Objecter.cc — op_submit (:2167) → _calc_target
 the OSDs run) → _send_op; resend on map change (:1974 handle_osd_map
 scan) and on EAGAIN from an OSD that saw a stale mapping.  Linger
 (watch) ops are out of scope this round.
+
+The op budget (Objecter.cc _take_op_budget / _throttle_op /
+calc_op_budget): an op enters `_inflight` only while it holds one of
+`objecter_inflight_ops` ops and its cost of `objecter_inflight_op_bytes`
+bytes (a write's data, the length a read asks for), and gives both back
+when it leaves.  It takes the op budget first and the byte budget next;
+one that is used up is waited for in that AsyncThrottle's own FIFO line,
+joined in the step of the submit, and the grant's callback takes the
+rest and SENDS the op in the step of the grant: no later op can reach
+the wire first, and writes to one object keep their order through a
+wait.  An op larger than the whole budget passes when nothing else
+holds any (Throttle's own rule).
 """
 
 from __future__ import annotations
 
 import asyncio
 import errno
+from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 from ceph_tpu.common.qos import QOS_CLASS, QosFeedback
+from ceph_tpu.common.throttle import AsyncThrottle
 from ceph_tpu.msg.message import Message
 from ceph_tpu.msg.messenger import Dispatcher, Messenger
 from ceph_tpu.mon.client import MonClient
-from ceph_tpu.osd.messages import MOSDOp, MOSDOpBatch, MOSDOpReply, OSDOp
+from ceph_tpu.osd.messages import (MOSDOp, MOSDOpBatch, MOSDOpReply,
+                                   OP_READ, OSDOp)
 from ceph_tpu.osd.osdmap import OSDMap
 from ceph_tpu.osd.types import ObjectLocator, PGId
 
@@ -31,7 +46,7 @@ class ObjectOperationError(Exception):
 class _InFlight:
     __slots__ = ("tid", "oid", "loc", "ops", "fut", "attempts", "snapid",
                  "snapc", "span", "span_sent", "sent", "corked",
-                 "qos_class")
+                 "qos_class", "budget", "grants", "waited", "wait_t0")
 
     def __init__(self, tid, oid, loc, ops, fut, snapid=0, snapc=None):
         self.tid = tid
@@ -47,6 +62,13 @@ class _InFlight:
         self.sent = False       # first send left — resends skip the cork
         self.corked = False     # parked in a pending cork (no re-entry)
         self.qos_class = "client"   # dmClock class riding the envelope
+        # bytes of the byte budget this op costs (calc_op_budget)
+        self.budget = sum(len(o.data) or (o.length if o.op == OP_READ
+                                          else 0) for o in ops)
+        self.grants = []        # (throttle, cost, waiter or None): the
+        #                         budget it holds or stands in line for
+        self.waited = False     # found a budget used up
+        self.wait_t0 = 0.0      # tracer stamp taken on joining a line
 
 
 class Objecter(Dispatcher):
@@ -59,6 +81,14 @@ class Objecter(Dispatcher):
         monc.on_osdmap(self._on_osdmap)
         self._tid = 0
         self._inflight: Dict[int, _InFlight] = {}
+        # the op budget, taken in this order; each keeps its own line
+        self._op_budget = AsyncThrottle(
+            "objecter_ops", int(ctx.config["objecter_inflight_ops"]))
+        self._byte_budget = AsyncThrottle(
+            "objecter_bytes", int(ctx.config["objecter_inflight_op_bytes"]))
+        self.throttle_waits = 0     # ops that had to wait for budget
+        self.inflight_ops_peak = 0
+        self.inflight_bytes_peak = 0
         # corked op batching (sharded-data-plane client half): ops
         # submitted within one loop pass park UNTARGETED; the flush
         # batch-computes every corked op's placement in ONE kernel call
@@ -98,6 +128,7 @@ class Objecter(Dispatcher):
                     self._resend_later(op))
                 return True
             del self._inflight[m.tid]
+            self._put_budget(op)
             self._qos.note_done(op.qos_class, m.qos_phase)
             if op.span is not None and not op.span.finished:
                 # close the trace: the reply transit back is the last
@@ -296,13 +327,69 @@ class Objecter(Dispatcher):
         # gateway) > per-client config default > "client"
         op.qos_class = QOS_CLASS.get() or self._default_qos_class \
             or "client"
-        tr = self.ctx.tracer
-        if tr.enabled:
-            op.span = tr.start("osd_op")
-        self._inflight[tid] = op
-        self._send(op)
         try:
+            self._take_budget(op, 0)
+            # the deadline covers a wait for budget too
             reply = await asyncio.wait_for(fut, timeout)
         finally:
             self._inflight.pop(tid, None)
+            self._put_budget(op)
         return reply
+
+    # ------------------------------------------------------------- budget
+    def budget_stats(self) -> Dict[str, int]:
+        """The op budget's counters since this client started."""
+        return {"inflight_ops": self._op_budget.cur,
+                "inflight_bytes": self._byte_budget.cur,
+                "inflight_ops_peak": self.inflight_ops_peak,
+                "inflight_bytes_peak": self.inflight_bytes_peak,
+                "throttle_waits": self.throttle_waits}
+
+    def _take_budget(self, op: _InFlight, step: int) -> None:
+        """Take what `op` still lacks, from budget number `step` on;
+        with both held it is in flight, and sent, in this same step."""
+        budgets = ((self._op_budget, 1), (self._byte_budget, op.budget))
+        for thr, cost in budgets[step:]:
+            step += 1
+            if thr.get_or_fail(cost):
+                op.grants.append((thr, cost, None))
+                continue
+            if not op.waited:
+                op.waited = True
+                self.throttle_waits += 1
+                op.wait_t0 = self.ctx.tracer.stamp()
+            op.grants.append((thr, cost, thr.get_later(
+                cost, partial(self._granted, op, step))))
+            return
+        ops, nbytes = self._op_budget.cur, self._byte_budget.cur
+        if ops > self.inflight_ops_peak:
+            self.inflight_ops_peak = ops
+        if nbytes > self.inflight_bytes_peak:
+            self.inflight_bytes_peak = nbytes
+        tr = self.ctx.tracer
+        if op.waited:
+            tr.interval("client_throttle_wait", op.wait_t0)
+        if tr.enabled:
+            op.span = tr.start("osd_op")
+        self._inflight[op.tid] = op
+        self._send(op)
+
+    def _granted(self, op: _InFlight, step: int) -> None:
+        """A line's grant to `op`, inside the put() that made room."""
+        if op.fut.done():       # gave up (timeout, cancel) as it came:
+            return              # its op_submit gives the grant back
+        try:
+            self._take_budget(op, step)
+        except Exception as e:  # its own op_submit reports it
+            op.fut.set_exception(e)
+
+    def _put_budget(self, op: _InFlight) -> None:
+        """Give back what `op` holds, once, and leave the line it
+        stands in; the throttles admit whoever fits now."""
+        grants, op.grants = op.grants, []
+        for thr, cost, waiter in grants:
+            if waiter is None or (waiter.done()
+                                  and not waiter.cancelled()):
+                thr.put(cost)
+            else:
+                waiter.cancel()

@@ -395,8 +395,18 @@ DEFAULT_OPTIONS: List[Option] = [
            "crash injection countdown in queue_transactions batches: "
            "N>0 dies after the Nth batch journals, N<0 before "
            "(config_opts.h:1171)"),
-    Option("objecter_inflight_ops", "int", 1024, "client op throttle"),
-    Option("objecter_inflight_op_bytes", "size", "100m", ""),
+    Option("objecter_inflight_ops", "int", 1024,
+           "ops one Objecter keeps in flight; a further op_submit "
+           "waits, in submit order, until a reply (or an error, a "
+           "timeout, a cancel) gives one back; 0 = no limit "
+           "(config_opts.h objecter_inflight_ops; Objecter.cc "
+           "_take_op_budget / _throttle_op)"),
+    Option("objecter_inflight_op_bytes", "size", "100m",
+           "bytes of op data one Objecter keeps in flight: a write "
+           "costs its data, a read the length it asks for "
+           "(Objecter.cc calc_op_budget); an op larger than the whole "
+           "budget passes when nothing else is in flight; 0 = no limit "
+           "(config_opts.h objecter_inflight_op_bytes, 100 MB)"),
     Option("objecter_op_batching", "bool", True,
            "cork client ops per target OSD within one loop pass: N "
            "MOSDOps coalesce into ONE wire frame / ONE local-delivery "

@@ -69,7 +69,7 @@ class AsyncThrottle:
         self.cur = 0
         self.waited = 0               # times a get had to block
         from collections import deque
-        self._waiters: "deque" = deque()   # (future, cost)
+        self._waiters: "deque" = deque()   # (future, cost, on_grant)
 
     def _room(self, c: int) -> bool:
         return self.cur + c <= self.max or self.cur == 0
@@ -82,7 +82,7 @@ class AsyncThrottle:
             return
         self.waited += 1
         fut = asyncio.get_running_loop().create_future()
-        self._waiters.append((fut, c))
+        self._waiters.append((fut, c, None))
         try:
             await fut
         except asyncio.CancelledError:
@@ -91,7 +91,7 @@ class AsyncThrottle:
                 self.put(c)
             else:
                 try:
-                    self._waiters.remove((fut, c))
+                    self._waiters.remove((fut, c, None))
                 except ValueError:
                     pass
             raise
@@ -104,24 +104,37 @@ class AsyncThrottle:
         self.cur += c
         return True
 
-    def get_later(self, c: int = 1) -> "asyncio.Future":
+    def get_later(self, c: int = 1, on_grant=None) -> "asyncio.Future":
         """SYNCHRONOUSLY join the queue: the returned future resolves
         once the budget is granted (FIFO with get()).  Lets a caller
         that must park work reserve its place in line before yielding
         the loop — otherwise a later get_or_fail could overtake it
         (the batch-unpack ordering hazard).  The budget is already
         charged when the future resolves; a caller abandoning the
-        wait must put() it back if the future completed."""
+        wait must put() it back if the future completed, and cancels
+        the future otherwise.
+
+        `on_grant()` runs IN THE STEP of the grant (here, or inside the
+        put() that makes room), before any later arrival can find room:
+        a caller whose granted work must reach its next stage in line
+        order does that work there instead of awaiting the future.  It
+        must not raise."""
         loop = asyncio.get_running_loop()
         fut = loop.create_future()
         if self.max <= 0 or (not self._waiters and self._room(c)):
             if self.max > 0:
                 self.cur += c
-            fut.set_result(None)
+            self._grant(fut, on_grant)
             return fut
         self.waited += 1
-        self._waiters.append((fut, c))
+        self._waiters.append((fut, c, on_grant))
         return fut
+
+    @staticmethod
+    def _grant(fut, on_grant) -> None:
+        fut.set_result(None)
+        if on_grant is not None:
+            on_grant()
 
     def put(self, c: int = 1) -> None:
         if self.max <= 0:
@@ -129,7 +142,7 @@ class AsyncThrottle:
         self.cur -= c
         assert self.cur >= 0
         while self._waiters:
-            fut, cost = self._waiters[0]
+            fut, cost, on_grant = self._waiters[0]
             if fut.done():            # cancelled waiter
                 self._waiters.popleft()
                 continue
@@ -137,7 +150,7 @@ class AsyncThrottle:
                 break
             self._waiters.popleft()
             self.cur += cost
-            fut.set_result(None)
+            self._grant(fut, on_grant)
 
     def open_wide(self) -> None:
         """Disable the limit and admit every parked waiter — teardown
@@ -145,6 +158,6 @@ class AsyncThrottle:
         budget nobody will release)."""
         self.max = 0
         while self._waiters:
-            fut, _ = self._waiters.popleft()
+            fut, _, on_grant = self._waiters.popleft()
             if not fut.done():
-                fut.set_result(None)
+                self._grant(fut, on_grant)

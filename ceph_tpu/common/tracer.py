@@ -169,9 +169,15 @@ STORE_STAGES = (
 #: memory extent pool, osd/extents.py): they are exactly the bytes
 #: REMOVED from lane_codec, so the pair next to a flat lane_codec is
 #: the evidence the copy moved rather than vanished.
+#: client_throttle_wait is the objecter's wait for its op budget
+#: (objecter_inflight_ops / objecter_inflight_op_bytes): an interval
+#: from op_submit's entry to the budget held, recorded only for an op
+#: that had to wait.  It lies IN FRONT of the chain: the op's span
+#: starts when the budget is held, so client_submit and op_total do
+#: not hold it.
 AUX_STAGES = ("op_total", "repl_apply", "repl_commit",
               "recovery_pull", "decode_rebuild",
-              "extent_write", "extent_read") \
+              "extent_write", "extent_read", "client_throttle_wait") \
     + SEAM_STAGES + LOOP_STAGES + STORE_STAGES
 
 STAGE_GROUP = "op_stages"
