@@ -41,6 +41,14 @@ def crc32c(data, crc: int = 0) -> int:
     return crc32c_python(data, crc)
 
 
+def crc32c_many(blobs) -> list:
+    """crc32c of each of `blobs` (`bytes` objects).  On the native
+    kernel one call digests them all, so the GIL changes hands once."""
+    if native.available():
+        return native.crc32c_many(blobs)
+    return [crc32c_python(b) for b in blobs]
+
+
 def crc32c_python(data, crc: int = 0) -> int:
     t = _table()
     c = crc ^ 0xFFFFFFFF

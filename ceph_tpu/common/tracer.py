@@ -118,7 +118,7 @@ QUEUE_WAIT_CAUSES = (
 #: One device request's trip through the seam (osd/ec_queue.py), below
 #: the chain's ``ec_encode`` / the aux ``decode_rebuild``.  Intervals
 #: on the loop's side, sections on the ec-device executor thread:
-#: seam_pending + [seam_fold .. seam_split] + seam_resume ~ seam_apply.
+#: seam_pending + [seam_fold .. seam_finish] + seam_resume ~ seam_apply.
 SEAM_STAGES = (
     "seam_apply",       # interval: the whole apply() await
     "seam_pending",     # interval: apply() entry -> executor takes the group
@@ -126,7 +126,8 @@ SEAM_STAGES = (
     "seam_h2d",         # section: jax.device_put of the folded batch
     "seam_launch",      # section: slice / pad / device_call / concatenate
     "seam_d2h",         # section: np.asarray of the device result
-    "seam_split",       # section: per-request result copies
+    "seam_split",       # section: result copies (plain apply() requests)
+    "seam_finish",      # section: the requests' continuations (apply_then)
     "seam_resume",      # interval: executor done -> awaiter runs again
 )
 
@@ -137,7 +138,10 @@ LOOP_STAGES = (
     "loop_client",        # objecter: placement + message build of a cork
     "loop_dispatch",      # OSD: delivered client op / sub-op ack -> its PG
     "loop_prepare",       # EC write: cls, cow, per-shard txns before encode
-    "loop_ec_host",       # EC: split, tobytes/crc/txn build, decode glue
+    "loop_ec_host",       # EC: a full write's shard txn build (its split,
+                          # tobytes and crc only where they run inline: a
+                          # padded payload, a continuation on the host
+                          # path), decode glue
     "loop_store_apply",   # store apply at the primary and the sub-op handler
     "loop_store_commit",  # store: one inline (ack-on-apply) commit group
     "loop_submit",        # payload seal + fan-out in the submit regions

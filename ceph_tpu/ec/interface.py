@@ -121,10 +121,17 @@ class ErasureCode(ABC):
         """Pad+split an object into its [k, chunk] data chunks — the ONE
         place the stripe geometry is computed (reference
         ErasureCode::encode padding; also used by the OSD device batch
-        queue so both encode paths pad identically)."""
+        queue so both encode paths pad identically).  A payload that
+        is a whole stripe already (no padding) is VIEWED, not copied:
+        the chunks are then read-only and share the payload's memory;
+        no encode path writes to its data chunks."""
         chunk = self.get_chunk_size(len(data))
+        flat = np.frombuffer(data, np.uint8)
+        if flat.size == chunk * self.k:
+            flat.flags.writeable = False    # a bytearray's view is not
+            return flat.reshape(self.k, chunk)
         padded = np.zeros(chunk * self.k, np.uint8)
-        padded[:len(data)] = np.frombuffer(data, np.uint8)
+        padded[:flat.size] = flat
         return padded.reshape(self.k, chunk)
 
     def encode(self, want_to_encode: Set[int],

@@ -7,7 +7,8 @@
 //     instruction, three interleaved streams joined by zero-shift tables
 //     (the crc32c_intel_fast role); ceph_crc32c_table is the slicing-by-8
 //     software path (sctp_crc32 role), always compiled: the fallback and
-//     what the tests compare against.  ceph_crc32c_impl names the choice.
+//     what the tests compare against.  ceph_crc32c_impl names the choice;
+//     ceph_crc32c_many digests several buffers in one call.
 //   * rjenkins hash batch — reference src/crush/hash.c:12-90, used to
 //     accelerate host-side placement fallback paths
 //   * GF(2^8) region encode (poly 0x11d, log/exp tables) — the scalar CPU
@@ -155,6 +156,14 @@ uint32_t ceph_crc32c(uint32_t crc, const uint8_t* data, uint64_t len) {
 
 const char* ceph_crc32c_impl() { return "table"; }
 #endif
+
+// Several buffers in ONE call: a caller that holds Python's GIL gives
+// it up once for all of them (a full EC write's six shards), not once
+// and back again for each.
+void ceph_crc32c_many(const uint8_t* const* bufs, const uint64_t* lens,
+                      uint64_t n, uint32_t* out) {
+  for (uint64_t i = 0; i < n; i++) out[i] = ceph_crc32c(0, bufs[i], lens[i]);
+}
 
 // ------------------------------------------------------------- rjenkins --
 #define crush_hashmix(a, b, c) do {            \

@@ -36,6 +36,7 @@ from ceph_tpu.osd.messages import (
     OP_SETXATTR, OP_STAT, OP_TRUNCATE, OP_WATCH, OP_WRITE, OP_WRITEFULL,
     OP_ZERO,
 )
+from ceph_tpu.common.crc import crc32c, crc32c_many
 from ceph_tpu.crush.constants import CRUSH_ITEM_NONE
 from ceph_tpu.msg.payload import LazyPayload
 from ceph_tpu.osd import extents
@@ -611,7 +612,6 @@ class ReplicatedBackend(PGBackend):
         # object digest (data_digest role): full-object writes record the
         # crc scrub verifies against; partial mutations invalidate it
         # (empty marker) exactly like the reference drops data_digest
-        from ceph_tpu.common.crc import crc32c
         from ceph_tpu.osd.scrub import CRC_XATTR
         digest_ops = {OP_WRITEFULL: None, OP_WRITE: b"", OP_APPEND: b"",
                       OP_TRUNCATE: b"", OP_ZERO: b""}
@@ -813,6 +813,19 @@ class ReplicatedBackend(PGBackend):
 
 # ================================================================= erasure
 
+def _shard_blobs(chunks, parity) -> List[Tuple[bytes, int]]:
+    """A full write's shard preparation: for each row of the k data
+    chunks and then of the parity, the `bytes` a shard stores and the
+    crc32c of that very object.  Runs as the EC queue's continuation
+    (`ECBatchQueue.apply_then`), on the ec-device thread for a
+    device group: the rows may be views (of the payload, of the
+    group's fetched batch), the copies made here are the only ones,
+    and ONE digest call for all of them releases the GIL beside the
+    loop (on a thread every release is a wait to win it back)."""
+    blobs = [row.tobytes() for rows in (chunks, parity) for row in rows]
+    return list(zip(blobs, crc32c_many(blobs)))
+
+
 class ECBackend(PGBackend):
     """Erasure-coded strategy (osd/ECBackend.cc) with one-shot TPU encode.
 
@@ -850,13 +863,20 @@ class ECBackend(PGBackend):
         # oid -> (interval_epoch, raw snapset) from _authoritative_ss
         self._ss_cache: Dict[str, Tuple[int, bytes]] = {}
 
-    async def _encode_object(self, data: bytes) -> Dict[int, np.ndarray]:
-        """Full-object encode, batched across PGs on the device queue
-        when the codec exposes a plain generator matrix (rs/jerasure/isa
-        family); codec host path otherwise (lrc/shec layering).  In
-        mesh mode the encode runs as ONE sharded device program where
-        each mesh device computes its own shard (all_gather over the
-        shard axis = the fan-out hop)."""
+    async def _encode_object(self, data: bytes
+                             ) -> List[Tuple[bytes, int]]:
+        """Full-object encode: per shard, in shard order, the `bytes`
+        to store and their crc32c.  Batched across PGs on the device
+        queue when the codec exposes a plain generator matrix
+        (rs/jerasure/isa family); codec host path otherwise (lrc/shec
+        layering).  In mesh mode the encode runs as ONE sharded device
+        program where each mesh device computes its own shard
+        (all_gather over the shard axis = the fan-out hop).
+
+        The copies and digests (`_shard_blobs`) ride the queue's
+        request as its continuation, so for a device group they run on
+        the ec-device thread that fetched the parity, not on the loop;
+        the other paths run them inline."""
         gen = getattr(self.codec, "generator", None)
         ex = getattr(self.osd, "mesh_exec", None)
         # per-loop collector: under threaded shards the daemon-wide
@@ -864,23 +884,24 @@ class ECBackend(PGBackend):
         q = self.osd.ec_batch_queue() \
             if hasattr(self.osd, "ec_batch_queue") \
             else getattr(self.osd, "ec_queue", None)
+        tr = self.osd.ctx.tracer
+        coded = None
         if ex is not None and gen is not None:
             try:
-                return await ex.encode_object(self.codec, data)
+                coded = await ex.encode_object(self.codec, data)
             except Exception as e:
                 q.note_fallback("mesh encode", e)
-        if gen is None or q is None:
-            return self.codec.encode(set(range(self.n)), data)
-        with self.osd.ctx.tracer.section("loop_ec_host"):
+        if coded is None and (gen is None or q is None):
+            coded = self.codec.encode(set(range(self.n)), data)
+        if coded is not None:
+            with tr.section("loop_ec_host"):
+                return _shard_blobs([coded[i] for i in range(self.n)], ())
+        with tr.section("loop_ec_host"):
             chunks = self.codec.split_data(data)
         # device-candidate:ec-encode@landed the live kernel call site: awaits
         # the cross-PG collector (LANE_BUCKETS-bucketed, executor
         # dispatch) — the loop never blocks on the device
-        parity = await q.apply(gen[self.k:], chunks)
-        out = {i: chunks[i] for i in range(self.k)}
-        out.update({self.k + i: parity[i]
-                    for i in range(self.n - self.k)})
-        return out
+        return await q.apply_then(gen[self.k:], chunks, _shard_blobs)
 
     async def _decode_shards(self, want, streams: Dict[int, np.ndarray]
                              ) -> Dict[int, np.ndarray]:
@@ -1024,25 +1045,23 @@ class ECBackend(PGBackend):
             th = tr.hist if span is not None else None
             if span is not None:
                 span.cut("prepare", th)
-            from ceph_tpu.common.crc import crc32c
             from ceph_tpu.osd.scrub import CRC_XATTR
             empty_crc = str(crc32c(b"")).encode()
         for op in writes:
             if op.op == OP_WRITEFULL:
-                chunks = await self._encode_object(op.data)
+                blobs = await self._encode_object(op.data)
                 with tr.section("loop_ec_host"):
-                    for i in range(self.n):
+                    size = str(len(op.data)).encode()
+                    for i, (chunk_bytes, crc) in enumerate(blobs):
                         t = shard_txns[i]
-                        chunk_bytes = chunks[i].tobytes()
                         t.truncate(cids[i], soid, 0)
                         t.write(cids[i], soid, 0, chunk_bytes)
-                        t.setattr(cids[i], soid, SIZE_XATTR,
-                                  str(len(op.data)).encode())
+                        t.setattr(cids[i], soid, SIZE_XATTR, size)
                         # per-shard digest (hinfo role,
-                        # ECBackend.cc:1695): scrub verifies stored
-                        # bytes against this
+                        # ECBackend.cc:1695) over the very bytes
+                        # object stored: scrub verifies against it
                         t.setattr(cids[i], soid, CRC_XATTR,
-                                  str(crc32c(chunk_bytes)).encode())
+                                  str(crc).encode())
             elif op.op == OP_CREATE:
                 for i, t in shard_txns.items():
                     t.touch(cids[i], soid)
@@ -1614,7 +1633,6 @@ class ECBackend(PGBackend):
         claim would make the receiver's apply_push wipe clones we
         cannot replace."""
         pg = self.pg
-        from ceph_tpu.common.crc import crc32c
         from ceph_tpu.osd.scrub import CRC_XATTR
         from ceph_tpu.osd.snaps import load_snapset
         ss = load_snapset(self.osd.store, pg.cid, pg.meta_oid, oid)
@@ -1677,7 +1695,6 @@ class ECBackend(PGBackend):
         rebuilt = (await self._decode_shards([target], streams))[target]
         # the digest xattr is PER SHARD: the rebuilt chunk gets its own,
         # never a copy of ours (scrub would flag it forever)
-        from ceph_tpu.common.crc import crc32c
         from ceph_tpu.osd.scrub import CRC_XATTR
         attrs = dict(attrs)
         attrs[CRC_XATTR] = str(crc32c(rebuilt.tobytes())).encode()
@@ -1730,7 +1747,6 @@ class ECBackend(PGBackend):
         streams, attrs = got
         rebuilt = (await self._decode_shards([my], streams))[my]
         blob = rebuilt.tobytes()
-        from ceph_tpu.common.crc import crc32c
         from ceph_tpu.osd.scrub import CRC_XATTR
         attrs = dict(attrs)
         attrs[CRC_XATTR] = str(crc32c(blob)).encode()

@@ -22,6 +22,12 @@ Design:
     instead: a window plus a device dispatch costs more latency than
     encoding 64 KiB on the CPU.  Everything is counted in perf
     counters so `perf dump` proves where bytes went.
+  * A request may carry a continuation (`apply_then`): what the
+    caller would do first with the rows, `finish(chunks, rows)`, runs
+    where the rows are made — on the ec-device thread for a device
+    group, so that work is off the event loop too.  The rows still
+    come back through `apply()`, carrying what the continuation made
+    of them.
   * Whether the queue launches on the device at all is decided ONCE,
     by `resolve_backend()`, before the OSD takes ops: "on" without an
     accelerator fails the start, and every later device->host reroute
@@ -32,7 +38,8 @@ from __future__ import annotations
 
 import asyncio
 import concurrent.futures
-from typing import Dict, List, Optional, Tuple
+import contextvars
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -49,17 +56,34 @@ def _bucket(n: int) -> int:
 
 
 class _Req:
-    __slots__ = ("key", "mat", "chunks", "fut", "t_apply", "t_done")
+    __slots__ = ("key", "mat", "chunks", "finish", "fut", "t_apply",
+                 "t_done")
 
-    def __init__(self, key, mat, chunks, fut, t_apply):
+    def __init__(self, key, mat, chunks, finish, fut, t_apply):
         self.key = key
         self.mat = mat
         self.chunks = chunks        # [k, L] uint8
+        self.finish = finish        # continuation or None
         self.fut = fut
         # op tracing (tracer stamps; 0.0 while tracing is off):
         # apply() entry, and the executor's last instant on the group
         self.t_apply = t_apply
         self.t_done = 0.0
+
+
+#: the continuation of the apply() this task is making: apply_then()
+#: sets it around its call, apply() hands it to the request
+_FINISH: contextvars.ContextVar = contextvars.ContextVar(
+    "ec_apply_finish", default=None)
+
+_NOT_MADE = object()
+
+
+class _Rows(np.ndarray):
+    """A request's result rows that carry what its continuation made of
+    them.  Only the very object the seam returned does: a copy or a
+    view of it is an array without one again."""
+    finished = _NOT_MADE
 
 
 class ECBatchQueue:
@@ -89,7 +113,10 @@ class ECBatchQueue:
             max_workers=1, thread_name_prefix="ec-device")
         self.perf = ctx.perf.create("ec_batch_queue")
         for key in ("device_launches", "device_requests", "device_bytes",
-                    "host_requests", "host_bytes", "device_fallbacks"):
+                    "host_requests", "host_bytes", "device_fallbacks",
+                    # continuations run on the ec-device thread / on
+                    # the caller's thread (host kernel, fallback)
+                    "finish_thread", "finish_inline"):
             self.perf.add_u64(key)
         self.perf.add_avg("batch_fill")    # requests per device launch
         # concurrent encodes parked in the collector at each arrival:
@@ -178,8 +205,8 @@ class ECBatchQueue:
         await self._pending_throttle.get(nbytes)
         fut = loop.create_future()
         req = _Req((mat.shape, mat.tobytes()),
-                   np.ascontiguousarray(mat, np.uint8), chunks, fut,
-                   t_apply)
+                   np.ascontiguousarray(mat, np.uint8), chunks,
+                   _FINISH.get(), fut, t_apply)
         self._pending.append(req)
         self._pending_bytes += nbytes
         self.perf.tinc("pending_depth", len(self._pending))
@@ -206,6 +233,33 @@ class ECBatchQueue:
             return native.gf_matrix_apply(mat, chunks)
         from ceph_tpu.ec import gf256
         return gf256.host_apply(mat, chunks)
+
+    async def apply_then(self, mat: np.ndarray, chunks: np.ndarray,
+                         finish: Callable):
+        """`finish(chunks, rows)` of one apply(): what the caller would
+        do first with the rows, made where the rows are made.  For a
+        device group that is the ec-device thread (section
+        `seam_finish`): `rows` is then a view of the group's whole
+        fetched batch, so `finish` copies what it keeps, writes to
+        neither argument, and what it raises fails this request alone.
+
+        The rows come back through apply() all the same, so whatever
+        stands in front of it sees them, and only the very rows the
+        seam returned vouch for what was made of them: rows made on
+        this thread (the host kernel; the reroute after a device
+        failure) or replaced on their way here get `finish` inline,
+        which on a loop is EC host work and is named so."""
+        token = _FINISH.set(finish)
+        try:
+            rows = await self.apply(mat, chunks)
+        finally:
+            _FINISH.reset(token)
+        done = getattr(rows, "finished", _NOT_MADE)
+        if done is _NOT_MADE:
+            self.perf.inc("finish_inline")
+            with self.ctx.tracer.section("loop_ec_host"):
+                done = finish(chunks, rows)
+        return done
 
     async def stop(self) -> None:
         if self._task is not None:
@@ -255,7 +309,12 @@ class ECBatchQueue:
                     outs = await loop.run_in_executor(
                         self._pool, self._run_group, reqs)
                     for r, out in zip(reqs, outs):
-                        if not r.fut.done():
+                        if r.fut.done():
+                            continue
+                        if isinstance(out, Exception):
+                            # what its continuation raised: its alone
+                            r.fut.set_exception(out)
+                        else:
                             r.fut.set_result(out)
                 except Exception as e:     # device failure: host fallback
                     self.note_fallback("device batch", e)
@@ -268,7 +327,7 @@ class ECBatchQueue:
                             except Exception as e2:
                                 r.fut.set_exception(e2)
 
-    def _run_group(self, reqs: List[_Req]) -> List[np.ndarray]:
+    def _run_group(self, reqs: List[_Req]) -> list:
         """Executor thread: device launches for all requests sharing a
         generator matrix, folded along the lane axis.  Batches beyond
         the largest lane bucket split into bucket-sized windows, so
@@ -336,12 +395,33 @@ class ECBatchQueue:
         from ceph_tpu.common import devstats
         devstats.note_bytes("ec_apply", k * total, device=True)
         self.perf.tinc("batch_fill", len(reqs))
-        with tr.section("seam_split"):
-            res = []
-            off = 0
-            for ln in lens:
-                res.append(np.ascontiguousarray(out[:, off:off + ln]))
-                off += ln
+        rows = []
+        off = 0
+        for ln in lens:
+            rows.append(out[:, off:off + ln])
+            off += ln
+        res: list = [None] * len(reqs)
+        todo = [i for i, r in enumerate(reqs) if r.finish is not None]
+        if len(todo) < len(reqs):
+            with tr.section("seam_split"):
+                for i, r in enumerate(reqs):
+                    if r.finish is None:
+                        res[i] = np.ascontiguousarray(rows[i])
+        if todo:
+            # a continuation takes its rows as a view of the fetched
+            # batch (what it keeps it copies itself: the only copy),
+            # and so they go back, with what it made of them; what it
+            # raises is its own request's error, never a device failure
+            with tr.section("seam_finish"):
+                for i in todo:
+                    done = rows[i].view(_Rows)
+                    try:
+                        done.finished = reqs[i].finish(reqs[i].chunks,
+                                                       rows[i])
+                    except Exception as e:
+                        done = e
+                    res[i] = done
+            self.perf.inc("finish_thread", len(todo))
         # the results are finished; from here they wait for the loop
         # (the collector's resume, then each awaiter's)
         t_done = tr.stamp()

@@ -175,6 +175,72 @@ def test_chunk_size_alignment():
     assert ec.get_data_chunk_count() == 3
 
 
+def _split_by_copy(ec, data):
+    """split_data as it was before it learned to view: always the
+    zero-filled copy."""
+    chunk = ec.get_chunk_size(len(data))
+    padded = np.zeros(chunk * ec.k, np.uint8)
+    padded[:len(data)] = np.frombuffer(data, np.uint8)
+    return padded.reshape(ec.k, chunk)
+
+
+@pytest.mark.parametrize("kind", [bytes, bytearray, memoryview])
+@pytest.mark.parametrize("length", [1, 4 * 256 - 1, 4 * 256, 4 * 256 + 1],
+                         ids=["one", "stripe_less_1", "stripe",
+                              "stripe_plus_1"])
+def test_split_data_views_a_whole_stripe_and_copies_the_rest(length, kind):
+    """A payload of exactly k x chunk bytes is viewed: no allocation, no
+    copy, read-only (a bytearray's too).  Anything else is the
+    zero-padded copy.  Either way the chunks equal the old result."""
+    ec = factory("rs", {"k": "4", "m": "2"})
+    data = kind(rand_bytes(length, seed=length))
+    chunks = ec.split_data(data)
+    assert chunks.dtype == np.uint8 and chunks.flags.c_contiguous
+    assert np.array_equal(chunks, _split_by_copy(ec, data))
+    whole = length == 4 * 256
+    assert np.shares_memory(chunks, np.frombuffer(data, np.uint8)) == whole
+    assert chunks.flags.writeable == (not whole)
+    if whole:
+        with pytest.raises(ValueError):
+            chunks[0, 0] = 1
+
+
+HOST_CODECS = PROFILES + [
+    ("lrc", {"k": "4", "m": "2", "l": "3"}),
+    ("shec", {"k": "4", "m": "3", "c": "2"}),
+    ("jerasure", {"k": "5", "m": "2", "technique": "liberation",
+                  "w": "7", "packetsize": "8"}),
+    ("jerasure", {"k": "6", "m": "2", "technique": "blaum_roth",
+                  "w": "6", "packetsize": "8"}),
+]
+
+
+@pytest.mark.parametrize("plugin,profile", HOST_CODECS)
+def test_host_codecs_encode_a_whole_stripe_without_writing_to_it(
+        plugin, profile):
+    """Every registered plugin encodes a whole-stripe payload, whose
+    data chunks are a read-only view of it: a codec that wrote into its
+    input would raise here.  Same chunks as from a private copy."""
+    assert plugin in plugin_names()
+    ec = factory(plugin, dict(profile, backend="host"))
+    n = ec.get_chunk_count()
+    size = ec.k * ec.get_chunk_size(ec.k * 1000)
+    assert ec.get_chunk_size(size) * ec.k == size     # no padding
+    data = rand_bytes(size, seed=size)
+    for payload in (data, bytearray(data)):
+        assert not ec.split_data(payload).flags.writeable
+        got = ec.encode(set(range(n)), payload)
+        assert bytes(payload) == data
+        coded = ec.encode_chunks(_split_by_copy(ec, data))
+        for i in range(ec.k):
+            assert got[i].tobytes() == data[i * size // ec.k:
+                                            (i + 1) * size // ec.k]
+        for j in range(n - ec.k):
+            assert np.array_equal(got[ec.k + j], coded[j]), (plugin, j)
+    # and whatever the layout, the stripe decodes back
+    assert ec.decode_concat(got)[:size] == data
+
+
 def test_minimum_to_decode_greedy():
     ec = factory("rs", {"k": "4", "m": "2"})
     # all wanted available -> wanted

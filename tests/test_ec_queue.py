@@ -195,10 +195,206 @@ def test_device_failure_falls_back_to_host(monkeypatch):
     asyncio.run(run())
 
 
+# ------------------------------------------------------ continuations
+
+def _digest_on(seen):
+    """A continuation that says where and how often it ran, keeps a
+    copy of its rows and returns something that is not the rows."""
+    import threading
+
+    def finish(chunks, rows):
+        seen.append(threading.current_thread().name)
+        return ("done", chunks.shape[1], rows.tobytes())
+    return finish
+
+
+def test_continuation_runs_on_the_device_thread_once_per_request():
+    """mode=force: each request's continuation runs on the ec-device
+    thread, once, with the request's own chunks and its own rows of
+    the group's result, and the await resolves to what it returned."""
+    async def run():
+        q = make_queue(min_device_bytes=256)
+        mat = gen_mat()
+        rng = np.random.default_rng(21)
+        ins = [rng.integers(0, 256, (4, 2048 + 128 * i), dtype=np.uint8)
+               for i in range(5)]
+        seen = []
+        outs = await asyncio.gather(
+            *[q.apply_then(mat, c, _digest_on(seen)) for c in ins])
+        for c, o in zip(ins, outs):
+            assert o == ("done", c.shape[1],
+                         gf256.host_apply(mat, c).tobytes())
+        assert len(seen) == 5
+        assert all(name.startswith("ec-device") for name in seen), seen
+        d = q.perf.dump()
+        assert d["device_requests"] == 5 and d["device_launches"] == 1
+        assert d["finish_thread"] == 5 and d["finish_inline"] == 0
+        assert d["host_requests"] == 0 and d["device_fallbacks"] == 0
+        await q.stop()
+    asyncio.run(run())
+
+
+def test_requests_without_a_continuation_are_as_before_beside_one():
+    """One group, requests with and without `finish`: the plain ones
+    resolve to their own contiguous copy of the rows, byte for byte the
+    host kernel's; only the others are counted."""
+    async def run():
+        q = make_queue(min_device_bytes=256)
+        mat = gen_mat()
+        rng = np.random.default_rng(22)
+        ins = [rng.integers(0, 256, (4, 4096), dtype=np.uint8)
+               for _ in range(6)]
+        seen = []
+        outs = await asyncio.gather(
+            *[q.apply_then(mat, c, _digest_on(seen)) if i % 2
+              else q.apply(mat, c) for i, c in enumerate(ins)])
+        for i, (c, o) in enumerate(zip(ins, outs)):
+            want = gf256.host_apply(mat, c)
+            if i % 2:
+                assert o == ("done", 4096, want.tobytes())
+            else:
+                assert isinstance(o, np.ndarray) and o.dtype == np.uint8
+                assert o.flags.c_contiguous and np.array_equal(o, want)
+        d = q.perf.dump()
+        assert d["device_launches"] == 1 and d["device_requests"] == 6
+        assert d["finish_thread"] == 3 and d["finish_inline"] == 0
+        await q.stop()
+    asyncio.run(run())
+
+
+@pytest.mark.parametrize("path", ["mode_off", "auto_on_cpu", "small_lone"])
+def test_continuation_runs_inline_on_the_host_kernel_path(path):
+    """Where the result is made on the caller's thread (the host
+    kernel), the continuation runs there, in the caller's step."""
+    import threading
+
+    async def run():
+        q = make_queue(mode={"mode_off": "off", "auto_on_cpu": "auto",
+                             "small_lone": "force"}[path],
+                       min_device_bytes=1 << 20 if path == "small_lone"
+                       else 256)
+        mat = gen_mat()
+        c = np.arange(4 * 4096, dtype=np.uint32).astype(np.uint8) \
+            .reshape(4, -1)
+        seen = []
+        out = await q.apply_then(mat, c, _digest_on(seen))
+        assert out == ("done", 4096, gf256.host_apply(mat, c).tobytes())
+        assert seen == [threading.current_thread().name]
+        d = q.perf.dump()
+        assert d["host_requests"] == 1 and d["device_requests"] == 0
+        assert d["finish_inline"] == 1 and d["finish_thread"] == 0
+        assert d["device_fallbacks"] == 0
+        await q.stop()
+    asyncio.run(run())
+
+
+def test_continuation_runs_inline_after_a_device_failure(monkeypatch):
+    """The device-failure fallback makes the result on the loop, so the
+    continuation runs there too; the only fallback counted is the
+    injected failure."""
+    import threading
+
+    async def run():
+        q = make_queue(min_device_bytes=256)
+
+        def boom(reqs):
+            raise RuntimeError("device gone")
+        monkeypatch.setattr(q, "_run_group", boom)
+        mat = gen_mat()
+        rng = np.random.default_rng(23)
+        ins = [rng.integers(0, 256, (4, 1 << 15), dtype=np.uint8)
+               for _ in range(3)]
+        seen = []
+        outs = await asyncio.gather(
+            *[q.apply_then(mat, c, _digest_on(seen)) for c in ins])
+        for c, o in zip(ins, outs):
+            assert o == ("done", 1 << 15,
+                         gf256.host_apply(mat, c).tobytes())
+        assert seen == [threading.current_thread().name] * 3
+        d = q.perf.dump()
+        assert d["device_fallbacks"] == 1 and d["device_bytes"] == 0
+        assert d["host_requests"] == 3
+        assert d["finish_inline"] == 3 and d["finish_thread"] == 0
+        await q.stop()
+    asyncio.run(run())
+
+
+@pytest.mark.parametrize("path", ["device_thread", "host_kernel"])
+def test_raising_continuation_fails_its_own_request_only(path):
+    """What a continuation raises is its request's error: the rest of
+    the group completes, nothing is booked as a device fallback and
+    nothing is run again on the host."""
+    class Boom(Exception):
+        pass
+
+    async def run():
+        q = make_queue(mode="force" if path == "device_thread" else "off",
+                       min_device_bytes=256)
+        mat = gen_mat()
+        rng = np.random.default_rng(24)
+        ins = [rng.integers(0, 256, (4, 4096), dtype=np.uint8)
+               for _ in range(4)]
+        seen = []
+
+        def bad(chunks, rows):
+            raise Boom("mine alone")
+
+        outs = await asyncio.gather(
+            *[q.apply_then(mat, c, bad if i == 1 else _digest_on(seen))
+              for i, c in enumerate(ins)], return_exceptions=True)
+        assert isinstance(outs[1], Boom)
+        for i in (0, 2, 3):
+            assert outs[i] == ("done", 4096,
+                               gf256.host_apply(mat, ins[i]).tobytes())
+        assert len(seen) == 3
+        d = q.perf.dump()
+        assert d["device_fallbacks"] == 0
+        if path == "device_thread":
+            assert d["device_requests"] == 4 and d["host_requests"] == 0
+            assert d["finish_thread"] == 4
+        else:
+            assert d["host_requests"] == 4 and d["finish_inline"] == 4
+        # the queue is whole: the next request goes through
+        c = ins[0]
+        assert np.array_equal(await q.apply(mat, c),
+                              gf256.host_apply(mat, c))
+        await q.stop()
+    asyncio.run(run())
+
+
+def test_rows_replaced_in_front_of_apply_lose_what_was_made_of_them():
+    """The rows come back through apply(), so a wrapper around it (the
+    benchmark's `seam_corrupt` control is one) sees them and may hand
+    on others.  Only the very rows the seam returned carry the
+    continuation's product: replaced rows get the continuation again,
+    inline, so what the caller stores is made of what came back."""
+    async def run():
+        q = make_queue(min_device_bytes=256)
+        real = q.apply
+
+        async def corrupting(mat, chunks):
+            out = (await real(mat, chunks)).copy()
+            out[0, 0] ^= 0x01
+            return out
+        q.apply = corrupting
+        mat = gen_mat()
+        c = np.random.default_rng(25).integers(0, 256, (4, 8192),
+                                               dtype=np.uint8)
+        seen = []
+        out = await q.apply_then(mat, c, _digest_on(seen))
+        want = gf256.host_apply(mat, c)
+        want[0, 0] ^= 0x01
+        assert out == ("done", 8192, want.tobytes())
+        assert len(seen) == 2 and seen[0].startswith("ec-device")
+        d = q.perf.dump()
+        assert d["finish_thread"] == 1 and d["finish_inline"] == 1
+        await q.stop()
+    asyncio.run(run())
+
+
 # -------------------------------------------- op tracing at the seam
 
-EXEC_SECTIONS = ("seam_fold", "seam_h2d", "seam_launch", "seam_d2h",
-                 "seam_split")
+EXEC_SECTIONS = ("seam_fold", "seam_h2d", "seam_launch", "seam_d2h")
 
 
 def _seam_sums(q):
@@ -207,16 +403,25 @@ def _seam_sums(q):
             if name.startswith("seam_")}
 
 
+@pytest.mark.parametrize("last", ["seam_split", "seam_finish"])
 @pytest.mark.parametrize("grouping", ["own_groups", "one_group"])
-def test_seam_stages_tile_the_apply_await(grouping):
+def test_seam_stages_tile_the_apply_await(grouping, last):
     """With op_tracing on, a device request's trip is tiled by
     seam_pending (enqueue -> the executor takes its group), the five
     sections on the ec-device thread, and seam_resume (the executor's
     last instant -> the awaiter runs again): together within 10% of
     seam_apply.  Sections are per GROUP: when n requests share one
     launch each of them waits through the same sections, so against the
-    per-request intervals they weigh n."""
+    per-request intervals they weigh n.  The fifth section is
+    seam_split (the result copies) for requests without a continuation
+    and seam_finish (the continuations, which take their rows as views)
+    for requests with one: a group records the one it has work for."""
     n = 6
+    sections = EXEC_SECTIONS + (last,)
+    other = "seam_finish" if last == "seam_split" else "seam_split"
+
+    def finish(chunks, rows):
+        return np.ascontiguousarray(rows)
 
     async def run():
         q = make_queue(min_device_bytes=256, window_ms=2.0)
@@ -234,7 +439,8 @@ def test_seam_stages_tile_the_apply_await(grouping):
 
         async def burst():
             outs = await asyncio.gather(
-                *[q.apply(m, c) for m, c in zip(mats, ins)])
+                *[q.apply_then(m, c, finish) if last == "seam_finish"
+                  else q.apply(m, c) for m, c in zip(mats, ins)])
             for m, c, o in zip(mats, ins, outs):
                 assert np.array_equal(o, gf256.host_apply(m, c))
 
@@ -249,11 +455,12 @@ def test_seam_stages_tile_the_apply_await(grouping):
         assert groups == (n if grouping == "own_groups" else 1)
         for name in ("seam_apply", "seam_pending", "seam_resume"):
             assert d[name][0] == n, (name, d[name])
-        for name in EXEC_SECTIONS:
+        for name in sections:
             assert d[name][0] == groups, (name, d[name])
+        assert other not in d, d
         weight = n // groups
         tiled = d["seam_pending"][1] + d["seam_resume"][1] \
-            + weight * sum(d[name][1] for name in EXEC_SECTIONS)
+            + weight * sum(d[name][1] for name in sections)
         total = d["seam_apply"][1]
         assert abs(tiled - total) <= 0.10 * total, (tiled, total, d)
         await q.stop()
@@ -271,6 +478,21 @@ def test_lone_small_request_on_the_host_kernel_records_seam_apply_only():
         assert q.perf.dump()["host_requests"] == 1
         sums = _seam_sums(q)
         assert set(sums) == {"seam_apply"} and sums["seam_apply"][0] == 1
+        await q.stop()
+    asyncio.run(run())
+
+
+def test_inline_continuation_is_loop_time_with_a_name():
+    """On the host-kernel path the continuation is EC host work on the
+    caller's loop: one `loop_ec_host` section, no `seam_finish`."""
+    async def run():
+        q = make_queue(mode="off")
+        q.ctx.config.set("op_tracing", True)
+        c = np.arange(4 * 512, dtype=np.uint8).reshape(4, 512)
+        out = await q.apply_then(gen_mat(), c, lambda ch, rows: 7)
+        assert out == 7
+        assert set(_seam_sums(q)) == {"seam_apply"}
+        assert q.ctx.tracer.hist.histograms()["loop_ec_host"].count == 1
         await q.stop()
     asyncio.run(run())
 

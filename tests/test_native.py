@@ -66,6 +66,22 @@ def test_crc32c_three_paths_agree(length):
     assert crc32c(memoryview(v)) == want
 
 
+@pytest.mark.parametrize("lengths", [
+    (), (0,), (1, 0, 9), (1 << 20,) * 6,
+    (3 * CRC_LONG + CRC_SHORT + 1, 7, 3 * CRC_SHORT + 3, 70)],
+    ids=["none", "empty", "short", "six_shards", "mixed"])
+def test_crc32c_many_is_each_buffers_own_digest(lengths):
+    """One call for several `bytes` (a full EC write's six shards): each
+    digest is that buffer's own, NUL bytes and empty buffers included,
+    and the python fallback of common.crc agrees."""
+    from ceph_tpu.common import crc as crc_mod
+    blobs = [bytes(_view(n, off=i % 8)) for i, n in enumerate(lengths)]
+    want = [native.crc32c_table(b) for b in blobs]
+    assert native.crc32c_many(blobs) == want
+    assert crc_mod.crc32c_many(blobs) == want
+    assert [crc32c_python(b) for b in blobs[:3]] == want[:3]
+
+
 @pytest.mark.parametrize("off", range(9))
 @pytest.mark.parametrize(
     "length", [0, 7, 70, 3 * CRC_SHORT + 3, 3 * CRC_LONG + CRC_SHORT + 1])

@@ -189,6 +189,61 @@ def test_ec_write_records_each_shard_digest(size):
     asyncio.run(run())
 
 
+def test_ec_write_digests_come_from_the_device_threads_continuation():
+    """The same guarantee on the path the chip runs: with the EC queue
+    in `force` mode a full write's shard bytes and digests are made by
+    the request's continuation on the ec-device thread (`finish_thread`
+    counts every write, `finish_inline` none).  Each of the six shards
+    of a whole-stripe payload (`split_data` views it) and of a padded
+    one carries the `_crc` of exactly the bytes stored, the bytes are
+    the host codec's encode, and deep scrub agrees."""
+    from test_osd import FAST_CFG
+    from ceph_tpu.ec.registry import factory
+    saved = dict(FAST_CFG)
+    FAST_CFG["osd_ec_batch_device"] = "force"
+    FAST_CFG["osd_ec_batch_min_bytes"] = 1024
+    sizes = {"aligned": 131072, "padded": 131072 + 4099,
+             "aligned_small": 4 * 1024}
+
+    async def run():
+        cl = Cluster()
+        admin = await cl.start(6)
+        await admin.pool_create("ecpool", pg_num=4, pool_type="erasure",
+                                k=4, m=2)
+        io = admin.open_ioctx("ecpool")
+        payloads = {name: np.random.default_rng(size).integers(
+            0, 256, size, dtype=np.uint8).tobytes()
+            for name, size in sizes.items()}
+        await asyncio.gather(*[io.write_full(n, p)
+                               for n, p in payloads.items()])
+        seam = [osd.ec_queue.perf.dump() for osd in cl.osds.values()]
+        assert sum(d["device_requests"] for d in seam) == len(sizes)
+        assert sum(d["finish_thread"] for d in seam) == len(sizes)
+        assert sum(d["finish_inline"] for d in seam) == 0
+        assert sum(d["device_fallbacks"] for d in seam) == 0
+        codec = factory("rs", {"k": "4", "m": "2", "backend": "host"})
+        for name, payload in payloads.items():
+            want = codec.encode(set(range(6)), payload)
+            copies = find_copies(cl, name)
+            assert len(copies) == 6
+            for osd, cid, soid in copies:
+                stored = osd.store.read(cid, soid)
+                shard = int(cid.name[:-len("_head")].rsplit("s", 1)[1])
+                assert stored == want[shard].tobytes(), (name, cid)
+                assert int(osd.store.getattr(cid, soid, CRC_XATTR)) == \
+                    crc32c_python(stored)
+            pg, _ = primary_pg(cl, "ecpool", name)
+            res = await run_scrub(pg, deep=True)
+            assert res["errors"] == 0 and res["repaired"] == 0
+            assert await io.read(name) == payload
+        await cl.stop()
+    try:
+        asyncio.run(run())
+    finally:
+        FAST_CFG.clear()
+        FAST_CFG.update(saved)
+
+
 def test_deep_scrub_rebuilds_primary_own_ec_shard():
     async def run():
         cl = Cluster()

@@ -480,8 +480,10 @@ def test_loop_sections_never_nest_and_seam_sections_run_off_the_loop(
     """The rule the readers lean on, on a live EC mini-cluster through
     the device seam: `loop_*` sections run on the loop thread and never
     inside one another (their sum is loop time with a name, counted
-    once); the `seam_*` sections run on the ec-device thread; and the
-    loop sampler's two stages are there beside them."""
+    once); the `seam_*` sections run on the ec-device thread, a full
+    write's continuation (`seam_finish`: its shards' bytes and digests)
+    among them and never on the loop; and the loop sampler's two
+    stages are there beside them."""
     import threading
     from ceph_tpu.qa.cluster import Cluster, make_ctx
 
@@ -536,23 +538,32 @@ def test_loop_sections_never_nest_and_seam_sections_run_off_the_loop(
             assert await io.read(k) == v
         await asyncio.sleep(2.5 * tracer_mod.LOOP_SAMPLE_PERIOD)
         merged = cl.stage_histograms()
+        seam = [osd.ec_queue.perf.dump() for osd in cl.osds.values()]
         await cl.stop()
-        return merged
+        return merged, seam
 
-    merged = asyncio.run(run())
+    merged, seam = asyncio.run(run())
+    # every EC write went to the device thread, its continuation too
+    assert sum(d["device_requests"] for d in seam) == 12
+    assert sum(d["finish_thread"] for d in seam) == 12
+    assert sum(d["finish_inline"] for d in seam) == 0
     assert not faults, faults[:5]
     assert not open_loop
     for name in ("loop_client", "loop_dispatch", "loop_prepare",
                  "loop_ec_host", "loop_store_apply", "loop_store_commit",
                  "loop_submit", "loop_reply"):
         assert seen[name] == {True}, (name, seen[name])
+    # (no seam_split: that is the result copy of a request WITHOUT a
+    # continuation, and every request here is a full write's)
     for name in ("seam_fold", "seam_h2d", "seam_launch", "seam_d2h",
-                 "seam_split"):
+                 "seam_finish"):
         assert seen[name] == {False}, (name, seen[name])
+    assert "seam_split" not in seen
     for name in list(seen) + ["seam_apply", "seam_pending",
                               "seam_resume", "loop_wall", "loop_cpu"]:
         assert merged[name].count > 0, name
-    # 12 EC writes: one split and one shard-txn build each, and one
+    # 12 EC writes: one split and one shard-txn build each (the
+    # shards' tobytes and digests are the continuation's), and one
     # assembly per read; every write of both pools applies at its
     # primary and at each replica / shard (a traced sub-op's apply
     # records repl_apply)
