@@ -129,7 +129,13 @@ class OSD(Dispatcher):
         # — bench ec_e2e and test_perf_smoke read it
         self.perf_window = ctx.perf.create("osd_op_window")
         for key in ("ops_admitted", "window_drains",
-                    "max_inflight_depth"):
+                    "max_inflight_depth",
+                    # what skew does to it (osd/sequencer.py): ops
+                    # admitted behind an in-flight write of their own
+                    # object, admissions that found a PG's window
+                    # full, the most ops one object had in it at once
+                    "same_object_waits", "window_full_waits",
+                    "chain_peak"):
             self.perf_window.add_u64(key)
         self.perf_window.add_avg("inflight_depth")
         self._scrub_task: Optional[asyncio.Task] = None

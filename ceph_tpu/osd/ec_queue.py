@@ -21,7 +21,14 @@ Design:
   * Small lone requests take the native host kernel (GFNI/AVX-512)
     instead: a window plus a device dispatch costs more latency than
     encoding 64 KiB on the CPU.  Everything is counted in perf
-    counters so `perf dump` proves where bytes went.
+    counters so `perf dump` proves where bytes went.  What that
+    threshold (osd_ec_batch_min_bytes) SHOULD be is measured in the
+    benchmark's cell `ycsb_a_1k_zipf` (1,000-byte records, the
+    threshold stated as 0 so every encode takes the device):
+    `device_lanes_launched` beside `device_bytes` says how much of
+    each launch was padding up to its lane bucket (pad share =
+    1 - (device_bytes / k) / device_lanes_launched; PERF.md
+    sections 5 and 7).
   * A request may carry a continuation (`apply_then`): what the
     caller would do first with the rows, `finish(chunks, rows)`, runs
     where the rows are made — on the ec-device thread for a device
@@ -113,6 +120,9 @@ class ECBatchQueue:
             max_workers=1, thread_name_prefix="ec-device")
         self.perf = ctx.perf.create("ec_batch_queue")
         for key in ("device_launches", "device_requests", "device_bytes",
+                    # the bucketed lanes of every device_call: what
+                    # was launched, against device_bytes / k asked for
+                    "device_lanes_launched",
                     "host_requests", "host_bytes", "device_fallbacks",
                     # continuations run on the ec-device thread / on
                     # the caller's thread (host kernel, fallback)
@@ -378,6 +388,7 @@ class ECBatchQueue:
                 parts.append(
                     ap.device_call(seg)[:, :min(cap, total - w0)])
                 self.perf.inc("device_launches")
+                self.perf.inc("device_lanes_launched", seg.shape[1])
             out_dev = parts[0] if len(parts) == 1 \
                 else jnp.concatenate(parts, axis=1)
         # device-sync:begin group result fetch: one d2h for the whole

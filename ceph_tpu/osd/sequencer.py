@@ -39,11 +39,12 @@ class _ObjGate:
     """Per-object dependency tail: the last admitted writer's done
     future plus every reader admitted since it."""
 
-    __slots__ = ("write_tail", "readers")
+    __slots__ = ("write_tail", "readers", "inflight")
 
     def __init__(self):
         self.write_tail: Optional[asyncio.Future] = None
         self.readers: List[asyncio.Future] = []
+        self.inflight = 0          # admitted on this object, not released
 
 
 class OpSlot:
@@ -79,6 +80,7 @@ class OpSequencer:
         self.max_inflight = max(1, int(max_inflight))
         self.active = 0            # admitted, not yet released
         self.max_depth = 0         # high-water mark (counter)
+        self.chain_peak = 0        # most ops ONE object had in it at once
         self._gates: Dict[str, _ObjGate] = {}
         self._slot_free = asyncio.Event()
         self._slot_free.set()
@@ -97,6 +99,8 @@ class OpSequencer:
         entry and `admit_wait` (a full window's slot wait) on exit."""
         if span is not None and self.tracer is not None:
             span.cut("queue_wait_pump", self.tracer.hist)
+        if self.perf is not None and self.active >= self.max_inflight:
+            self.perf.inc("window_full_waits")
         while self.active >= self.max_inflight:
             self._slot_free.clear()
             await self._slot_free.wait()
@@ -117,6 +121,16 @@ class OpSequencer:
         if gate is None:
             gate = self._gates[oid] = _ObjGate()
         waits: List[asyncio.Future] = []
+        # what skew does to the window: the most ops one object ever
+        # had in it at once (this one included), and ops queued behind
+        # a write of their own object still in flight
+        gate.inflight += 1
+        if gate.inflight > self.chain_peak:
+            self.chain_peak = gate.inflight
+            if self.perf is not None:
+                self.perf.set_max("chain_peak", self.chain_peak)
+        if self.perf is not None and gate.write_tail is not None:
+            self.perf.inc("same_object_waits")
         if write:
             # exclusive: behind the last writer AND every reader since
             if gate.write_tail is not None:
@@ -156,6 +170,7 @@ class OpSequencer:
             slot.done.set_result(None)
         gate = self._gates.get(slot.oid)
         if gate is not None:
+            gate.inflight -= 1
             if gate.write_tail is slot.done:
                 gate.write_tail = None
             else:

@@ -95,6 +95,49 @@ def test_oversize_batch_splits_into_bucket_windows():
     asyncio.run(run())
 
 
+def test_device_lanes_launched_counts_the_bucket_of_every_launch():
+    """What was LAUNCHED against what was asked for: each device_call
+    runs a whole lane bucket, and `device_bytes` / k are the lanes the
+    requests brought; the difference is padding."""
+    from ceph_tpu.osd import ec_queue as eq
+
+    async def run():
+        q = make_queue(min_device_bytes=0)
+        mat = gen_mat(2, 1)
+        rng = np.random.default_rng(3)
+        small = [rng.integers(0, 256, (2, 512), dtype=np.uint8)
+                 for _ in range(5)]
+        # a lone record-sized request: 512 lanes asked, a bucket launched
+        out = await q.apply(mat, small[0])
+        assert np.array_equal(out, gf256.host_apply(mat, small[0]))
+        d = q.perf.dump()
+        assert d["host_requests"] == 0 and d["device_launches"] == 1
+        assert d["device_lanes_launched"] == eq.LANE_BUCKETS[0] == 16384
+        assert d["device_bytes"] // 2 == 512
+        # five folded into one launch still launch ONE smallest bucket
+        await asyncio.gather(*[q.apply(mat, c) for c in small])
+        d = q.perf.dump()
+        assert d["device_launches"] == 2
+        assert d["device_lanes_launched"] == 2 * 16384
+        assert d["device_bytes"] // 2 == 6 * 512
+        pad = 1 - (d["device_bytes"] / 2) / d["device_lanes_launched"]
+        assert pad == pytest.approx(1 - 3072 / 32768)
+        # one lane over a bucket launches the next; beyond the largest
+        # it splits into two windows, each its own bucket
+        await q.apply(mat, rng.integers(0, 256, (2, 16385), dtype=np.uint8))
+        assert q.perf.dump()["device_lanes_launched"] == \
+            2 * 16384 + eq.LANE_BUCKETS[1]
+        before = q.perf.dump()["device_lanes_launched"]
+        cap = eq.LANE_BUCKETS[-1]
+        await q.apply(mat, rng.integers(0, 256, (2, cap + 100),
+                                        dtype=np.uint8))
+        d = q.perf.dump()
+        assert d["device_lanes_launched"] - before == cap + 16384
+        assert d["host_bytes"] == 0
+        await q.stop()
+    asyncio.run(run())
+
+
 def test_mode_on_without_accelerator_fails_the_start():
     """mode=on REQUIRES a real accelerator: the backend is resolved
     once, before the queue takes requests, and on the CPU jax backend

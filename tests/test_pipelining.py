@@ -102,6 +102,68 @@ def test_sequencer_readers_share_writers_exclude():
     asyncio.run(run())
 
 
+def test_sequencer_counts_what_a_hand_made_admission_order_says():
+    """The three counters of what skew does to a PG's window: ops
+    admitted behind an in-flight WRITE of their own object, admissions
+    that found the window full, and the most ops one object ever had
+    in the window at once."""
+    from ceph_tpu.common.context import Context
+
+    async def run():
+        perf = Context("osd.0").perf.create("osd_op_window")
+        for key in ("ops_admitted", "max_inflight_depth",
+                    "same_object_waits", "window_full_waits",
+                    "chain_peak"):
+            perf.add_u64(key)
+        perf.add_avg("inflight_depth")
+        seq = OpSequencer(4, perf=perf)
+
+        def counts():
+            d = perf.dump()
+            return (d["same_object_waits"], d["window_full_waits"],
+                    d["chain_peak"])
+
+        # reads of one object share: nobody is behind a write
+        r1 = seq.admit("hot", False)
+        r2 = seq.admit("hot", False)
+        assert counts() == (0, 0, 2)
+        # a write behind readers waits, but not behind a WRITE
+        w1 = seq.admit("hot", True)
+        assert counts() == (0, 0, 3)
+        # a read and a write behind that write: two same-object waits
+        r3 = seq.admit("hot", False)
+        assert counts() == (1, 0, 4)
+        # the window (4) is full: the admitter waits once, and counts
+        # once however long it waits
+        waiter = asyncio.ensure_future(seq.wait_slot())
+        await asyncio.sleep(0)
+        assert not waiter.done() and counts() == (1, 1, 4)
+        for s in (r1, r2):
+            seq.release(s)
+        await asyncio.wait_for(waiter, 1.0)
+        w2 = seq.admit("hot", True)
+        assert counts() == (2, 1, 4)        # hot holds w1, r3, w2: three
+        # another object is a chain of its own; a free slot is no wait
+        await asyncio.wait_for(seq.wait_slot(), 1.0)
+        c1 = seq.admit("cold", True)
+        assert counts() == (2, 1, 4)
+        for s in (w1, r3, w2, c1):
+            seq.release(s)
+        assert seq.balanced()
+        # the chain is counted per object and starts again from empty
+        seq.admit("hot", True)
+        assert counts() == (2, 1, 4)
+        # without a perf group nothing is counted and nothing breaks
+        bare = OpSequencer(1)
+        slot = bare.admit("o", True)
+        blocked = asyncio.ensure_future(bare.wait_slot())
+        await asyncio.sleep(0)
+        bare.release(slot)
+        await asyncio.wait_for(blocked, 1.0)
+
+    asyncio.run(run())
+
+
 def test_sequencer_failed_op_never_wedges_successors():
     async def run():
         seq = OpSequencer(16)
