@@ -40,10 +40,12 @@ from ceph_tpu.common.crc import crc32c, crc32c_many
 from ceph_tpu.crush.constants import CRUSH_ITEM_NONE
 from ceph_tpu.msg.payload import LazyPayload
 from ceph_tpu.osd import extents
-from ceph_tpu.osd.pglog import LOG_DELETE, LOG_MODIFY, LogEntry
+from ceph_tpu.osd.pglog import (LOG_DELETE, LOG_MODIFY, LOG_ROLLBACK,
+                                LogEntry)
 from ceph_tpu.store.objectstore import (
     NoSuchCollection, NoSuchObject, Transaction,
 )
+from ceph_tpu.store.types import ObjectId
 
 SIZE_XATTR = "_size"       # EC: original object length (hinfo role)
 VERSION_XATTR = "_ver"     # log version of the stored object state:
@@ -693,6 +695,7 @@ class ReplicatedBackend(PGBackend):
                 self.osd.send_osd(p, rep)
         if span is not None:
             span.cut("submit", th)
+        pg.op_submitted(m)
         # awaitfree:end replicated-submit
         if not await self._await_acks(fut):
             self._inflight.pop(tid, None)
@@ -862,6 +865,27 @@ class ECBackend(PGBackend):
         self.n = self.codec.get_chunk_count()
         # oid -> (interval_epoch, raw snapset) from _authoritative_ss
         self._ss_cache: Dict[str, Tuple[int, bytes]] = {}
+        # per object, the versions this primary submitted that not
+        # every shard has acked yet: a write that replaces one of
+        # THOSE keeps it (_keep_prior).  A version leaves when it or
+        # a later one of its object is acked by all (shards apply one
+        # object's writes in order), never because its own write gave
+        # up, and all go with the interval
+        self._unacked: Dict[str, List[EVersion]] = {}
+        # the rollback generations those writes left on the shards:
+        # by the write's version while it is unacked; once every
+        # shard has acked, in _gen_trim until the next write's
+        # transactions remove them everywhere
+        self._kept: Dict[EVersion, ObjectId] = {}
+        self._gen_trim: List[ObjectId] = []
+        self._trim_timer: Optional[asyncio.TimerHandle] = None
+
+    def on_interval_change(self) -> None:
+        super().on_interval_change()
+        # what an aborted write kept is for the next peering to use
+        # (plan_rollbacks) and for its activation to sweep
+        self._kept.clear()
+        self._unacked.clear()
 
     async def _encode_object(self, data: bytes
                              ) -> List[Tuple[bytes, int]]:
@@ -902,6 +926,28 @@ class ECBackend(PGBackend):
         # the cross-PG collector (LANE_BUCKETS-bucketed, executor
         # dispatch) — the loop never blocks on the device
         return await q.apply_then(gen[self.k:], chunks, _shard_blobs)
+
+    def start_early_encode(self, m: MOSDOp) -> Optional[asyncio.Task]:
+        """The encode of a full write is a pure function of its
+        payload, so an op that waits in its object's chain need not
+        keep it waiting too: for an op holding ONE OP_WRITEFULL start
+        `_encode_object` now (the one entry: mesh mode, a per-loop
+        collector and a codec without a plain generator keep their
+        paths) and leave (sub-op, task) on the op, where
+        submit_client_write takes it in place of its own call.  An op
+        refused before that leaves it to PG._run_windowed, which
+        cancels it; an error nobody took is retrieved here."""
+        fulls = [op for op in m.ops if op.op == OP_WRITEFULL]
+        if len(fulls) != 1:
+            return None
+        _ops_materialize(fulls)
+        task = asyncio.get_running_loop().create_task(
+            self._encode_object(fulls[0].data))
+        task.add_done_callback(
+            lambda t: t.cancelled() or t.exception())
+        m._early_encode = (fulls[0], task)
+        self.pg.op_window.count("early_encodes")
+        return task
 
     async def _decode_shards(self, want, streams: Dict[int, np.ndarray]
                              ) -> Dict[int, np.ndarray]:
@@ -966,6 +1012,355 @@ class ECBackend(PGBackend):
     def my_shard(self) -> int:
         return self.pg.pgid.shard
 
+    # ------------------------------------------- rollback generations
+    def _keep_prior(self, soid: ObjectId, writes: List[OSDOp],
+                    shard_txns: Dict[int, Transaction], cids
+                    ) -> Tuple[Optional[EVersion], Optional[ObjectId]]:
+        """An EC write changes its shards IN PLACE, and an interval
+        change can leave it on some of them only (a shard that already
+        lives in the next interval drops the sub-write: rule EPOCH10).
+        One unacked write of an object leaves two versions out, and of
+        those the older is on every shard that lacks the newer.  But
+        writes to one object pipeline (osd/sequencer.py), and a write
+        that replaces a version NOT YET ON EVERY SHARD would take from
+        the shards the only version some of them share with the rest:
+        so every shard keeps that version, as the rollback generation
+        named after it, in the write's own transaction, until all
+        shards have acked the write (ECBackend.cc's rollback info per
+        log entry; ROADMAP Invariants).  Peering can then put the
+        object back to the newest version k shards still hold
+        (plan_rollbacks).  A write that replaces the whole object
+        MOVES it aside and carries its xattrs over (they are the same
+        on every shard; size, digest and version are set anew behind
+        this); any other clones it.
+
+        Returns (the version replaced: zero when there is no object,
+        None when it carries no version; the generation kept)."""
+        pg = self.pg
+        try:
+            attrs = self.osd.store.getattrs(pg.cid, soid)
+        except (NoSuchObject, NoSuchCollection):
+            return EVersion.zero(), None
+        raw = attrs.get(VERSION_XATTR)
+        if not raw:
+            return None, None
+        prior = EVersion.from_bytes(raw)
+        gen = soid.with_generation(prior.version)
+        replaced = any(
+            op.op in (OP_WRITEFULL, OP_DELETE, OP_ROLLBACK)
+            or (op.op == OP_TRUNCATE and op.offset == 0)
+            for op in writes)
+        for i, t in shard_txns.items():
+            if replaced:
+                t.try_rename(cids[i], soid, gen)
+                t.touch(cids[i], soid)
+                t.setattrs(cids[i], soid, attrs)
+            else:
+                t.clone(cids[i], soid, gen)
+        return prior, gen
+
+    def sweep_generations(self) -> None:
+        """A new interval is active and its rollbacks are decided:
+        whatever generation an entry of the log may have left on a
+        shard (its write aborted, or its primary went before the trim
+        was sent) goes with the next write's transactions."""
+        pg = self.pg
+        seen = {(g.name, g.generation) for g in self._gen_trim}
+        for e in pg.log.entries:
+            g = e.kept_generation()
+            if g and (e.oid, g) not in seen:
+                seen.add((e.oid, g))
+                self._gen_trim.append(
+                    pg.object_id(e.oid).with_generation(g))
+        self._trim_later()
+
+    #: how long a generation to trim waits for a write to ride on
+    TRIM_DELAY = 0.25
+
+    def _trim_later(self) -> None:
+        if self._trim_timer is None and self._gen_trim:
+            self._trim_timer = asyncio.get_running_loop().call_later(
+                self.TRIM_DELAY, self._flush_trim)
+
+    def _flush_trim(self) -> None:
+        """No write came by to carry the removes (a busy PG's next
+        submit section takes them long before this): send them alone,
+        as a sub-write that repeats the log's newest entry, which
+        every shard already has and appends nowhere."""
+        self._trim_timer = None
+        pg = self.pg
+        if not self._gen_trim or not pg.log.entries \
+                or pg._worker_task is None or not pg.is_primary() \
+                or pg.state != "active":
+            return      # the next activation sweeps
+        from ceph_tpu.store.types import CollectionId
+        gens, self._gen_trim = self._gen_trim, []
+        entry = pg.log.entries[-1]
+        log_payload = LazyPayload.seal(entry)
+        tid = self.osd.next_tid()
+        for i, osd_id in enumerate(pg.acting):
+            txn = Transaction()
+            cid = CollectionId.pg(pg.pool_id, pg.pgid.seed, i)
+            for g in gens:
+                txn.remove(cid, g)
+            targets = {osd_id}
+            if i < len(pg.up):
+                targets.add(pg.up[i])
+            for t_osd in targets:
+                if t_osd == self.osd.whoami:
+                    self.osd.store.queue_transactions([txn])
+                elif t_osd >= 0 and t_osd != CRUSH_ITEM_NONE:
+                    self.osd.send_osd(t_osd, MOSDECSubOpWrite(
+                        pg.pgid.with_shard(i), tid,
+                        LazyPayload.seal(txn), log_payload,
+                        entry.version, self.osd.osdmap.epoch))
+
+    def _versions_held(self, oid: str, gens: List[int]) -> List[bytes]:
+        """The raw version of our object and of each of its rollback
+        generations asked after (b"": not there)."""
+        pg = self.pg
+        soid = pg.object_id(oid)
+        out = []
+        for g in [0] + list(gens):
+            try:
+                out.append(self.osd.store.getattr(
+                    pg.cid, soid.with_generation(g), VERSION_XATTR))
+            except (NoSuchObject, NoSuchCollection):
+                out.append(b"")
+        return out
+
+    async def plan_rollbacks(self) -> Dict[str, tuple]:
+        """Peering, after the missing sets are known and before any
+        peer is activated: the objects whose newest logged version
+        FEWER THAN k in-sync shards hold can never be reconstructed
+        (recovery and reads wait for that version for ever).  For each
+        of them ask the shards which versions they still have, as the
+        object or as a generation kept behind an unacked overwrite,
+        and choose the newest one k of them hold whose successors a
+        shard at hand shows were never acked (_never_acked); an acked
+        version is on every shard, so the choice is never older than
+        the last ack.  Returns oid -> (version to restore, or zero:
+        the object did not exist; per acting position what that shard
+        showed), for execute_rollbacks.  An object with no such
+        version is left as it is (and logged): it waits for its
+        shards as it did before."""
+        pg = self.pg
+        me = self.osd.whoami
+        in_sync = {}          # acting position -> osd
+        for i, o in enumerate(pg.acting):
+            if o == me:
+                in_sync[i] = o
+            elif o >= 0 and o != CRUSH_ITEM_NONE \
+                    and self.osd.osdmap.is_up(o):
+                pi = pg.peer_info.get(o)
+                if pi is not None and pg._peer_in_sync(pi):
+                    in_sync[i] = o
+        at_risk = set(pg.missing.items)
+        for o in in_sync.values():
+            pm = pg.peer_missing.get(o)
+            if pm is not None:
+                at_risk.update(pm.items)
+        ask: Dict[str, List[EVersion]] = {}
+        for oid in sorted(at_risk):
+            holders = sum(
+                1 for o in in_sync.values()
+                if oid not in (pg.missing.items if o == me else getattr(
+                    pg.peer_missing.get(o), "items", ())))
+            if holders >= self.k:
+                continue      # the common case: recovery rebuilds it
+            latest = pg.log.latest_entry_for(oid)
+            if latest is None or latest.is_delete():
+                continue
+            vs = set()
+            for e in pg.log.entries:
+                if e.oid == oid:
+                    vs.add(e.version)
+                    if e.kept_generation():
+                        vs.add(e.prior_version)
+            ask[oid] = sorted(vs, reverse=True)[:64]
+        if not ask:
+            return {}
+        oids = list(ask)
+        gens = [[v.version for v in ask[o]] for o in oids]
+
+        async def survey(i: int, osd_id: int):
+            if osd_id == me:
+                return i, [self._versions_held(o, g)
+                           for o, g in zip(oids, gens)]
+            tid = self.osd.next_tid()
+            fut = asyncio.get_running_loop().create_future()
+            self._inflight[tid] = ({osd_id}, fut)
+            msg = MOSDECSubOpRead(pg.pgid.with_shard(i), tid,
+                                  [(o, 0, 0) for o in oids])
+            msg.gens = gens
+            self.osd.send_osd(osd_id, msg)
+            try:
+                reply = await asyncio.wait_for(fut, 15.0)
+            except asyncio.TimeoutError:
+                self._inflight.pop(tid, None)
+                raise RuntimeError(
+                    f"{pg.pgid}: no version survey from osd.{osd_id}")
+            flat, out, at = reply.data, [], 0
+            for g in gens:
+                out.append(flat[at:at + 1 + len(g)])
+                at += 1 + len(g)
+            return i, out
+
+        have: Dict[str, Dict[int, Dict[EVersion, str]]] = {
+            o: {} for o in oids}
+        for i, per_oid in await asyncio.gather(
+                *[survey(i, o) for i, o in in_sync.items()]):
+            for oid, raws in zip(oids, per_oid):
+                held = have[oid][i] = {}
+                for n, raw in enumerate(raws):
+                    if raw:
+                        held.setdefault(EVersion.from_bytes(raw),
+                                        "gen" if n else "head")
+        plans: Dict[str, tuple] = {}
+        for oid in oids:
+            shown = have[oid]
+            latest = pg.log.latest_entry_for(oid).version
+            if sum(1 for h in shown.values()
+                   if h.get(latest) == "head") >= self.k:
+                continue      # the logs lag the stores: recoverable
+            mine = [e for e in pg.log.entries if e.oid == oid]
+            seen = set(ask[oid]).union(*shown.values())
+            tos = [v for v in sorted(seen, reverse=True)
+                   if v < latest and sum(
+                       1 for h in shown.values() if v in h) >= self.k]
+            if mine[0].op == LOG_MODIFY \
+                    and mine[0].prior_version == EVersion.zero():
+                tos.append(EVersion.zero())     # made inside the log
+            to = next((v for v in tos if self._never_acked(
+                [e.version for e in mine if v < e.version], v, shown,
+                in_sync)), None)
+            if to is None:
+                self.log_.warning(
+                    f"{pg.pgid}: {oid} at {latest} is on fewer than "
+                    f"{self.k} shards and nothing shows that it was "
+                    f"never acked: {shown}")
+                continue
+            plans[oid] = (to, shown)
+        return plans
+
+    def _never_acked(self, undone: List[EVersion], to: EVersion,
+                     shown: Dict[int, Dict[EVersion, str]],
+                     in_sync: Dict[int, int]) -> bool:
+        """Shards that are GONE prove nothing: an acked version can be
+        on fewer than k of the shards at hand because the others died,
+        and then the PG has to wait for them, as ever.  `undone` may
+        be rolled back only where a shard at hand shows that none of
+        it was ever acked: a witness that stood at its position of the
+        acting set in the interval of every one of those writes (the
+        primary sent them to it, and an ack needs every shard) and
+        never applied the oldest of them — its object IS the version
+        to go back to or, where that is not to exist, it has none
+        though its own log had begun (a store made anew has neither
+        and is no witness).  Shards apply one object's writes in
+        order, so it applied none of the later ones either."""
+        pg = self.pg
+        for i, held in shown.items():
+            lu = pg.lu_at_peering if in_sync[i] == self.osd.whoami \
+                else pg.peer_info[in_sync[i]].last_update
+            if to == EVersion.zero():
+                if held or not EVersion.zero() < lu < min(undone):
+                    continue
+            elif held.get(to) != "head":
+                continue
+            if all(any(iv.first <= w.epoch <= iv.last
+                       and i < len(iv.acting)
+                       and iv.acting[i] == in_sync[i]
+                       for iv in pg.past_intervals) for w in undone):
+                return True
+        return False
+
+    async def execute_rollbacks(self, plans: Dict[str, tuple]) -> None:
+        """The peers hold the log now (MPGLog activate went first on
+        each connection): one logged write per planned object, its
+        entry a LOG_ROLLBACK that makes the entries it undoes void
+        (PGLog.void_reqids), its transaction made PER SHARD from what
+        that shard showed: restamp the object where it is the version
+        to restore, move the generation back where it was kept, and
+        nothing where the shard has neither (it is then missing the
+        object and recovery rebuilds it from the k that have it)."""
+        from ceph_tpu.osd.pglog import MissingSet
+        from ceph_tpu.store.types import CollectionId
+        pg = self.pg
+        me = self.osd.whoami
+        waits = []
+        for oid, (to, shown) in sorted(plans.items()):
+            soid = pg.object_id(oid)
+            version = pg.next_version()
+            entry = LogEntry(LOG_ROLLBACK, oid, version, to, "")
+            stamp = version.to_bytes()
+            txns: Dict[int, Transaction] = {}
+            for i in range(self.n):
+                cid = CollectionId.pg(pg.pool_id, pg.pgid.seed, i)
+                t = txns[i] = Transaction()
+                held = shown.get(i, {})
+                where = held.get(to)
+                if to == EVersion.zero():
+                    t.remove(cid, soid)
+                elif where == "head":
+                    t.setattr(cid, soid, VERSION_XATTR, stamp)
+                elif where == "gen":
+                    t.remove(cid, soid)
+                    t.try_rename(cid, soid.with_generation(to.version),
+                                 soid)
+                    t.setattr(cid, soid, VERSION_XATTR, stamp)
+                for v, w in held.items():
+                    if w == "gen" and v != to:
+                        t.remove(cid, soid.with_generation(v.version))
+            self.log_.warning(
+                f"{pg.pgid}: {oid} rolled back to "
+                f"{to if to != EVersion.zero() else 'not existing'} "
+                f"as {version}: {shown}")
+            perf = getattr(self.osd, "perf_recovery", None)
+            if perf is not None:
+                perf.inc("objects_rolled_back")
+            self._ss_cache.pop(oid, None)
+            restored = {i for i, h in shown.items()
+                        if to == EVersion.zero() or to in h}
+            local = txns[self.my_shard]
+            if self.my_shard in restored:
+                pg.missing.items.pop(oid, None)
+            else:
+                pg.missing.add(oid, version)
+            pg.append_log(local, entry)
+            if pg.missing:
+                pg.save_meta(local)
+            commit = self._queue_txn(
+                local, on_commit=lambda v=version: pg.complete_to(v))
+            log_payload = LazyPayload.seal(entry)
+            tid = self.osd.next_tid()
+            peers = set()
+            for i, osd_id in enumerate(pg.acting):
+                targets = {osd_id}
+                if to == EVersion.zero() and i < len(pg.up):
+                    targets.add(pg.up[i])     # a remove harms nobody
+                for t_osd in targets:
+                    if t_osd == me or t_osd < 0 \
+                            or t_osd == CRUSH_ITEM_NONE \
+                            or not self.osd.osdmap.is_up(t_osd):
+                        continue
+                    pm = pg.peer_missing.setdefault(t_osd, MissingSet())
+                    if i in restored:
+                        pm.items.pop(oid, None)
+                    else:
+                        pm.add(oid, version)
+                    peers.add(t_osd)
+                    self.osd.send_osd(t_osd, MOSDECSubOpWrite(
+                        pg.pgid.with_shard(i), tid,
+                        LazyPayload.seal(txns[i]), log_payload,
+                        version, self.osd.osdmap.epoch))
+            waits.append((self._ack_init(tid, peers), commit, tid))
+        for fut, commit, tid in waits:
+            if not await self._await_acks(fut) \
+                    or not await self._await_commit(commit):
+                self._inflight.pop(tid, None)
+                raise PGIntervalChanged(
+                    f"pg {pg.pgid}: rollback not acked")
+
     # ------------------------------------------------------------- writes
     async def submit_client_write(self, m: MOSDOp) -> int:
         pg = self.pg
@@ -1028,6 +1423,15 @@ class ECBackend(PGBackend):
             # the write may have advanced the snapset: the survey cache
             # must not serve the pre-COW row to a later read-at-snap
             self._ss_cache.pop(m.oid, None)
+            if m.oid in self._unacked:
+                prior, kept = self._keep_prior(soid, writes, shard_txns,
+                                               cids)
+            else:
+                # what it replaces is on every shard: nothing to keep
+                # (None: there is an object; zero: there is none)
+                kept = None
+                prior = None if self.osd.store.exists(pg.cid, soid) \
+                    else EVersion.zero()
             for op in [o for o in writes if o.op == OP_ROLLBACK]:
                 try:
                     src = snaps_mod.rollback_targets(pg, m.oid, soid,
@@ -1049,7 +1453,13 @@ class ECBackend(PGBackend):
             empty_crc = str(crc32c(b"")).encode()
         for op in writes:
             if op.op == OP_WRITEFULL:
-                blobs = await self._encode_object(op.data)
+                early = m._early_encode
+                if early is not None and early[0] is op:
+                    # started at admission (start_early_encode)
+                    m._early_encode = None
+                    blobs = await early[1]
+                else:
+                    blobs = await self._encode_object(op.data)
                 with tr.section("loop_ec_host"):
                     size = str(len(op.data)).encode()
                     for i, (chunk_bytes, crc) in enumerate(blobs):
@@ -1098,12 +1508,23 @@ class ECBackend(PGBackend):
         with tr.section("loop_store_apply"):
             version = pg.next_version()
             entry = LogEntry(LOG_DELETE if deletes else LOG_MODIFY,
-                             m.oid, version, pg.info.last_update,
+                             m.oid, version,
+                             version if prior is None else prior,
                              m.reqid)
             if not deletes:
                 for i, t in shard_txns.items():
                     t.setattr(cids[i], soid, VERSION_XATTR,
                               version.to_bytes())
+            # generations whose overwrite every shard has acked go
+            # with this write's transactions; the one it keeps itself
+            # follows when its own acks are in
+            for gen in self._gen_trim:
+                for i, t in shard_txns.items():
+                    t.remove(cids[i], gen)
+            self._gen_trim = []
+            if kept is not None:
+                self._kept[version] = kept
+            self._unacked.setdefault(m.oid, []).append(version)
             # local shard applies in memory now; its durability
             # overlaps the sub-op fan-out (commit pipelining), and
             # pglog last_complete advances from the commit callback
@@ -1163,10 +1584,23 @@ class ECBackend(PGBackend):
                 self.osd.send_osd(osd_id, msg)
         if span is not None:
             span.cut("submit", th)
+        pg.op_submitted(m)
         # awaitfree:end ec-submit
         if not await self._await_acks(fut):
             self._inflight.pop(tid, None)
             return -errno.EAGAIN
+        out = self._unacked.get(m.oid)
+        if out is not None:
+            # on every shard now, and with it every write of the
+            # object before it
+            out[:] = [v for v in out if version < v]
+            if not out:
+                del self._unacked[m.oid]
+        kept = self._kept.pop(version, None)
+        if kept is not None:
+            # what it replaced can never be wanted again
+            self._gen_trim.append(kept)
+            self._trim_later()
         if span is not None:
             span.cut("replica_rtt", th)
         if not await self._await_commit(commit_fut):
@@ -1809,6 +2243,11 @@ class ECBackend(PGBackend):
             txn = m.txn()
             entry = m.log_entry()
             advance = None
+            if entry.op == LOG_ROLLBACK and not entry.is_delete() \
+                    and txn.empty():
+                # we showed neither the version restored nor its
+                # generation: the object is owed to us
+                pg.missing.add(entry.oid, entry.version)
             if pg.log.head < entry.version:
                 pg.log.append(entry)
                 pg.note_reqid(entry)
@@ -1817,7 +2256,10 @@ class ECBackend(PGBackend):
                     # a copy still owed recovery pushes must keep its
                     # honest last_complete cursor, or the gap hides
                     advance = entry.version
-            pg.save_meta_log(txn, entry)
+            if entry.op == LOG_ROLLBACK and pg.missing:
+                pg.save_meta(txn)      # the missing set goes with it
+            else:
+                pg.save_meta_log(txn, entry)
             src = int(m.src_name.id)
             reply = MOSDECSubOpWriteReply(pg.pgid, m.tid, 0,
                                           self.my_shard, self.osd.whoami)
@@ -1841,6 +2283,14 @@ class ECBackend(PGBackend):
     def _handle_ec_sub_read(self, m) -> None:
         from ceph_tpu.osd.pglog import LB_MAX
         pg = self.pg
+        if m.gens:
+            # a survey of versions (plan_rollbacks): no bytes
+            flat: List[bytes] = []
+            for (oid, _off, _ln), gens in zip(m.reads, m.gens):
+                flat.extend(self._versions_held(oid, gens))
+            self.osd.send_osd(int(m.src_name.id), MOSDECSubOpReadReply(
+                pg.pgid, m.tid, self.my_shard, 0, flat, {}))
+            return
         data, attrs = [], {}
         result = 0
         for oid, off, ln in m.reads:

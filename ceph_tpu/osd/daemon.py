@@ -135,7 +135,15 @@ class OSD(Dispatcher):
                     # object, admissions that found a PG's window
                     # full, the most ops one object had in it at once
                     "same_object_waits", "window_full_waits",
-                    "chain_peak"):
+                    "chain_peak",
+                    # writes of one object pipeline (PR 33): writes
+                    # whose submit section ran while an earlier write
+                    # of their object was still in the window; full
+                    # writes whose encode started at admission, and
+                    # those of them whose op was refused before it
+                    # took the result
+                    "writes_pipelined", "early_encodes",
+                    "early_encodes_dropped"):
             self.perf_window.add_u64(key)
         self.perf_window.add_avg("inflight_depth")
         self._scrub_task: Optional[asyncio.Task] = None
@@ -164,7 +172,10 @@ class OSD(Dispatcher):
         for key in ("objects_pushed", "objects_pulled",
                     "push_bytes", "pull_bytes", "active_pulls",
                     "backoff_retries", "backoff_give_ups",
-                    "cursor_lag"):
+                    "cursor_lag",
+                    # EC objects peering put back to the newest
+                    # version k shards still held (plan_rollbacks)
+                    "objects_rolled_back"):
             self.perf_recovery.add_u64(key)
         # per-PG backfill shortfall feeding the cursor_lag gauge; each
         # PG reports ONLY itself from its home shard (SHARD11: no

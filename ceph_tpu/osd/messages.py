@@ -171,6 +171,11 @@ class MOSDOp(Message):
     TYPE = 200
     STRUCT_V = 4
     THROTTLE_DISPATCH = True     # client data ops bound OSD intake
+    # the executing PG's per-op state, set at window admission: the
+    # op's slot (osd/sequencer.py) and a full write's encode started
+    # ahead of the op, as (the OP_WRITEFULL sub-op, its task)
+    _slot = None
+    _early_encode = None
 
     def __init__(self, pgid: Optional[PGId] = None, oid: str = "",
                  loc: Optional[ObjectLocator] = None,
@@ -443,9 +448,12 @@ class MOSDECSubOpRead(Message):
     snap each read targets (clone chunk reads for snapshot decode);
     v3 adds want_ss — the reply carries the shard's SnapSet row so a
     primary whose own meta missed the row (adopted the pg mid-churn)
-    can resolve reads-at-snap authoritatively."""
+    can resolve reads-at-snap authoritatively; v4 adds gens — a survey
+    of VERSIONS, no bytes: per read the rollback generations asked
+    after, and the reply's data holds per read the version of the
+    shard's object and of each of those it keeps (b"": not there)."""
     TYPE = 206
-    STRUCT_V = 3
+    STRUCT_V = 4
     PRIORITY = PRIO_HIGH
 
     def __init__(self, pgid: Optional[PGId] = None, tid: int = 0,
@@ -457,6 +465,7 @@ class MOSDECSubOpRead(Message):
         self.reads = reads or []
         self.snap = snap              # 0 = head
         self.want_ss = False
+        self.gens: List[List[int]] = []    # v4: one list per read
 
     def encode_payload(self, enc: Encoder) -> None:
         enc.struct(self.pgid).u64(self.tid)
@@ -464,6 +473,8 @@ class MOSDECSubOpRead(Message):
                                             e.s64(r[2])))
         enc.u64(self.snap)
         enc.boolean(self.want_ss)
+        enc.list_(self.gens,
+                  lambda e, gs: e.list_(gs, lambda e2, g: e2.u64(g)))
 
     @classmethod
     def decode_payload(cls, dec: Decoder, struct_v: int):
@@ -473,6 +484,9 @@ class MOSDECSubOpRead(Message):
             m.snap = dec.u64()
         if struct_v >= 3:
             m.want_ss = dec.boolean()
+        if struct_v >= 4:
+            m.gens = dec.list_(
+                lambda d: d.list_(lambda d2: d2.u64()))
         return m
 
 

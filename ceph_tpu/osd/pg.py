@@ -38,10 +38,10 @@ from typing import Dict, List, Optional, Set, Tuple
 from ceph_tpu.crush.constants import CRUSH_ITEM_NONE
 from ceph_tpu.osd.messages import (
     EVersion, MOSDOp, MOSDOpReply, MPGLog, MPGLogRequest, MPGNotify,
-    MPGObjectList, MPGPush, MPGPushReply, MPGQuery,
+    MPGObjectList, MPGPush, MPGPushReply, MPGQuery, WRITE_OPS,
 )
-from ceph_tpu.osd.pglog import (LB_MAX, LogEntry, MissingSet, PastInterval,
-                                PGInfo, PGLog)
+from ceph_tpu.osd.pglog import (LB_MAX, LOG_ROLLBACK, LogEntry,
+                                MissingSet, PastInterval, PGInfo, PGLog)
 from ceph_tpu.osd.types import NO_SHARD, PGId, PGPool
 from ceph_tpu.store.objectstore import Transaction
 from ceph_tpu.store.types import CollectionId, ObjectId
@@ -145,6 +145,11 @@ class PG:
         # OSD-wide accounting (dispatch throttle, OpTracker) even when
         # the cancelled task never reached _do_client_op's finally
         self._window_tasks: Dict[asyncio.Task, MOSDOp] = {}
+        # EC peering: objects of ours no k shards could rebuild, and
+        # why (_heal_missing); _activate rolls them back or fails
+        self._heal_deferred: Dict[str, Exception] = {}
+        # where our own log stood before this peering merged another
+        self.lu_at_peering = EVersion()
         # request/reply matching for peering + recovery
         self._notify_waiters: Dict[int, asyncio.Future] = {}
         self._log_waiters: Dict[int, asyncio.Future] = {}
@@ -371,7 +376,8 @@ class PG:
         if self.info.last_complete < self.info.last_update \
                 and self.log.can_catch_up_from(self.info.last_complete):
             stored = {s.name
-                      for s in self.osd.store.collection_list(self.cid)}
+                      for s in self.osd.store.collection_list(self.cid)
+                      if not s.generation}
             for oid, e in self.log.objects_since(
                     self.info.last_complete).items():
                 if not e.is_delete() and oid not in stored \
@@ -595,6 +601,8 @@ class PG:
         # members that may hold newer writes (PG.h GetInfo state)
         self.peer_info.clear()
         self.peer_missing.clear()
+        self._heal_deferred = {}
+        self.lu_at_peering = self.info.last_update
         probe, blocked = self._build_prior_set()
         self.peering_blocked_by = blocked
         if blocked:
@@ -717,7 +725,7 @@ class PG:
             heal_src = best_osd if best_osd != self.osd.whoami else next(
                 iter(sorted(self.peer_info)), -1)
             if heal_src >= 0:
-                await self._heal_missing(heal_src, epoch)
+                await self._heal_missing(heal_src, epoch, peering=True)
                 txn = Transaction()
                 self.save_meta(txn)
                 self.osd.store.apply_transaction(txn)
@@ -770,24 +778,36 @@ class PG:
             self.missing.add(e.oid, e.version)
         self.reqids = self.log.reqids()
         self.info.last_update = self.log.head
-        await self._heal_missing(peer, epoch)
-        self.info.last_complete = self.info.last_update
+        await self._heal_missing(peer, epoch, peering=True)
+        if not self.missing:
+            self.info.last_complete = self.info.last_update
         txn = Transaction()
         self.save_meta(txn)
         self.osd.store.apply_transaction(txn)
 
-    async def _heal_missing(self, peer: int, epoch: int) -> None:
+    async def _heal_missing(self, peer: int, epoch: int,
+                            peering: bool = False) -> None:
         """Drain the primary's own missing set: deletions apply
         directly, the rest are pulled (replicated: whole-object push
         from the auth peer; EC: reconstruct OUR shard from k peers — a
-        foreign shard's bytes must never be installed as ours)."""
+        foreign shard's bytes must never be installed as ours).
+        `peering`, on an EC pool: an object that cannot be rebuilt
+        stays missing and is noted for _activate, which fails as this
+        would have unless the object can be rolled back
+        (ECBackend.plan_rollbacks) and then heals it."""
         for oid in list(self.missing.items):
             latest = self.log.latest_entry_for(oid)
             if latest is not None and latest.is_delete():
                 t = Transaction().remove(self.cid, self.object_id(oid))
                 self.osd.store.apply_transaction(t)
             else:
-                await self.backend.pull_object(peer, oid, epoch)
+                try:
+                    await self.backend.pull_object(peer, oid, epoch)
+                except RuntimeError as e:
+                    if not (peering and self.pool.is_erasure()):
+                        raise
+                    self._heal_deferred[oid] = e
+                    continue
                 if not self.osd.store.exists(self.cid,
                                              self.object_id(oid)):
                     # the donor couldn't provide it (it may be missing
@@ -850,7 +870,8 @@ class PG:
         # the reference never ships a whole PG listing in one message)
         local = sorted(s.name for s in
                        self.osd.store.collection_list(self.cid)
-                       if s.name != self.meta_oid.name)
+                       if s.name != self.meta_oid.name
+                       and not s.generation)
         window = max(8, int(self.osd.cfg["osd_backfill_scan_max"]))
         after = ""
         pulled = total = misplaced = 0
@@ -999,6 +1020,7 @@ class PG:
                 self._peering_task = \
                     asyncio.get_running_loop().create_task(self._peer())
             return
+        activations = []
         for p, pi in self.peer_info.items():
             if p not in self.acting and p not in self.up:
                 continue
@@ -1044,7 +1066,8 @@ class PG:
                             pm.add(oid, e.version)
                 for soid in self.osd.store.collection_list(self.cid):
                     if soid.name != self.meta_oid.name \
-                            and soid.name > backfill_from:
+                            and soid.name > backfill_from \
+                            and not soid.generation:
                         pm.add(soid.name, self.info.last_update)
                 self._backfilling.add(p)
                 # OUR view of the target's cursor is the cursor we just
@@ -1056,6 +1079,18 @@ class PG:
                 # ENOENT-for-a-backfill-hole window the cursor closes
                 pi.last_backfill = backfill_from
             self.peer_missing[p] = pm
+            activations.append((p, full_resync, backfill_from))
+        plans = {}
+        if self.pool.is_erasure():
+            # a version that fewer than k shards hold would be waited
+            # for for ever: find what it can be rolled back to
+            plans = await self.backend.plan_rollbacks()
+            if epoch != self.interval_epoch:
+                return
+            for oid, err in self._heal_deferred.items():
+                if oid in self.missing and oid not in plans:
+                    raise err    # as _heal_missing would have
+        for p, full_resync, backfill_from in activations:
             msg = MPGLog(
                 self.pgid.with_shard(self.shard_of(p)), epoch,
                 self.info, self.log, me,
@@ -1075,6 +1110,17 @@ class PG:
                 self._peering_task = asyncio.get_running_loop().create_task(
                     self._peer())
             return
+        if plans:
+            await self.backend.execute_rollbacks(plans)
+            if epoch != self.interval_epoch:
+                return
+        if self.missing:
+            # what waited for a rollback, and what one made us owe
+            await self._heal_missing(
+                next(iter(sorted(self.peer_info)), -1), epoch)
+            self.info.last_complete = self.info.last_update
+        if self.pool.is_erasure():
+            self.backend.sweep_generations()
         self.info.last_epoch_started = epoch
         self.state = STATE_ACTIVE
         self._active_event.set()
@@ -1279,7 +1325,7 @@ class PG:
                 soid.name
                 for soid in self.osd.store.collection_list(self.cid)
                 if soid.name != self.meta_oid.name
-                and soid.name > m.list_after)
+                and soid.name > m.list_after and not soid.generation)
             limit = m.list_max or len(names)
             truncated = len(names) > limit
             self.osd.send_osd(m.from_osd, MPGObjectList(
@@ -1557,6 +1603,38 @@ class PG:
         methods stage onto their own object only in this codebase)."""
         return not m.oid
 
+    def _admission_class(self, m: MOSDOp) -> Tuple[bool, bool]:
+        """How a client op enters its object's chain in the window
+        (osd/sequencer.py): (exclusive, early_link).  THE rule, in
+        this one place:
+
+          * shared — a pure read: behind the release of every write in
+            flight on its object, beside other reads;
+          * exclusive — anything that may change the object, and every
+            op on a writeback tier, reads too (a cache miss promotes:
+            an internal WRITE of the object — two shared readers of one
+            cold object would otherwise race duplicate promotes outside
+            the chain);
+          * early link — an exclusive op that is NOTHING BUT plain
+            mutations (every sub-op one of WRITE_OPS) on a pool that
+            is no cache tier: all it reads before its submit section
+            (cow, rollback source, append offset) is the primary's
+            LOCAL state, which the write before it applied inside ITS
+            submit section, so it may follow that write from the end
+            of that section on, not from its ack.  An op that carries
+            a read or a guard (`_read_op` on an EC pool gathers from
+            the shards, which have not applied an unacked write) or a
+            cls call (it stages ops from what it reads), and every op
+            of a tier (promote and flush are round trips of their own)
+            keep the whole exclusion: they wait for the ack of what is
+            before them and are waited for by theirs."""
+        tier = self.pool.is_tier()
+        exclusive = any(o.is_write() for o in m.ops) or (
+            tier and self.pool.cache_mode == "writeback")
+        early_link = exclusive and not tier and all(
+            o.op in WRITE_OPS for o in m.ops)
+        return exclusive, early_link
+
     async def _worker(self) -> None:
         """The single ADMITTER (ShardedOpWQ role): dequeues in FIFO
         order and feeds the dependency-tracked window (osd/sequencer.py)
@@ -1599,20 +1677,20 @@ class PG:
                         # machine-checked by devtools rule AF01
                         # awaitfree:begin window-admission
                         m._windowed = True
-                        # writeback-tier reads are admitted EXCLUSIVE:
-                        # a cache miss promotes (an internal WRITE of
-                        # the object) — two shared readers of the same
-                        # cold object would otherwise race duplicate
-                        # promotes outside the per-object chain
-                        write = any(o.is_write() for o in m.ops) or (
-                            self.pool.is_tier()
-                            and self.pool.cache_mode == "writeback")
-                        slot = seq.admit(m.oid, write)
-                        task = asyncio.get_running_loop().create_task(
-                            self._run_windowed(m, slot))
-                        self._window_tasks[task] = m
-                        task.add_done_callback(
-                            lambda t: self._window_tasks.pop(t, None))
+                        slot = m._slot = seq.admit(
+                            m.oid, *self._admission_class(m))
+                        self._track_window_task(
+                            m, asyncio.get_running_loop().create_task(
+                                self._run_windowed(m, slot)))
+                        early = getattr(self.backend,
+                                        "start_early_encode", None)
+                        if early is not None and slot.must_wait() \
+                                and not self._resend_in_window(m):
+                            # an EC pool's full write: its encode is a
+                            # pure function of its payload and need
+                            # not stand in the chain with the op
+                            # (None: no such op)
+                            self._track_window_task(m, early(m))
                         # awaitfree:end window-admission
                 elif isinstance(m, MPGScrub):
                     # scrub drains the window: no client op can
@@ -1672,7 +1750,51 @@ class PG:
         except Exception:
             self.log_.exception(f"{self.pgid} op failed: {m}")
         finally:
+            early = m._early_encode
+            if early is not None:
+                # refused, failed or cancelled before its encode was
+                # taken: nothing is owed for it
+                m._early_encode = None
+                early[1].cancel()
+                self.op_window.count("early_encodes_dropped")
             self.op_window.release(slot)
+
+    def op_submitted(self, m: MOSDOp) -> None:
+        """Called by a backend at the END of its await-free submit
+        section: writes queued behind this one on its object may now
+        enter theirs (osd/sequencer.py; nothing outside a window)."""
+        if m._slot is not None:
+            m._slot.mark_submitted()
+
+    def _resend_in_window(self, m: MOSDOp) -> bool:
+        """`m` is a resend of a write that is logged or in the window
+        now (the objecter resends what is in flight on every new map):
+        it follows its original down the chain into the duplicate
+        short-cut and executes nothing."""
+        return bool(m.reqid) and (m.reqid in self.reqids or any(
+            o.reqid == m.reqid and o is not m
+            for o in self._window_tasks.values()))
+
+    def _track_window_task(self, m: MOSDOp,
+                           task: Optional[asyncio.Task]) -> None:
+        """stop()'s sweep cancels what the window started for `m`."""
+        if task is not None:
+            self._window_tasks[task] = m
+            task.add_done_callback(
+                lambda t: self._window_tasks.pop(t, None))
+
+    async def _reply_in_order(self, m: MOSDOp) -> None:
+        """An early-link write answers only after every write admitted
+        before it on its object was released: acks per object leave in
+        submit order, and a duplicate that followed its original down
+        the chain (it finds the reqid in the log from the original's
+        SUBMIT on) cannot ack a write no shard has acked yet."""
+        slot = m._slot
+        if slot is None or not slot.reply_waits:
+            return
+        if await slot.wait_reply() and m._span is not None:
+            # the same wait for its own object's chain, at its far end
+            m._span.cut("dep_wait", self.osd.ctx.tracer.hist)
 
     def _finish_client_op(self, m: MOSDOp) -> None:
         """Release one client op's OSD-wide accounting — OpTracker
@@ -1746,8 +1868,16 @@ class PG:
         if has_write and m.reqid and m.reqid in self.reqids:
             # duplicate of an already-applied write (client resend after a
             # map change / lost reply): ack success without re-executing
+            epoch = self.interval_epoch
+            await self._reply_in_order(m)
+            # the wait may have spanned an interval change that failed
+            # the original: its log entry is then for the new
+            # interval's peering to judge, not for this op to vouch for
+            still = epoch == self.interval_epoch and self.is_primary() \
+                and self.state == STATE_ACTIVE and m.reqid in self.reqids
             self.osd.reply_to(m, MOSDOpReply(
-                m.tid, 0, m.ops, self.osd.osdmap.epoch))
+                m.tid, 0 if still else -errno.EAGAIN, m.ops,
+                self.osd.osdmap.epoch))
             return
         from ceph_tpu.osd.backend import PGIntervalChanged
         try:
@@ -1796,6 +1926,7 @@ class PG:
                     m._span.cut("op_exec", self.osd.ctx.tracer.hist)
         except PGIntervalChanged:
             result = -errno.EAGAIN
+        await self._reply_in_order(m)
         with self.osd.ctx.tracer.section("loop_reply"):
             reply = MOSDOpReply(m.tid, result, m.ops,
                                 self.osd.osdmap.epoch)
@@ -1914,6 +2045,9 @@ class PG:
             self.info.last_complete = version
 
     def note_reqid(self, entry: LogEntry) -> None:
+        if entry.op == LOG_ROLLBACK:
+            # the writes it undid are no duplicates any more
+            self.log.void_reqids(entry, self.reqids)
         if entry.reqid:
             self.reqids[entry.reqid] = entry.version
             if len(self.reqids) > 2 * PGLog.MAX_ENTRIES:

@@ -17,6 +17,11 @@ from ceph_tpu.osd.types import PGId
 
 LOG_MODIFY = 1
 LOG_DELETE = 2
+#: peering put the object back to `prior_version` (zero: to not being
+#: there), the newest version k shards of an EC pool still held: the
+#: writes in between reached fewer than k and are void, their reqids
+#: no duplicates (ECBackend.plan_rollbacks)
+LOG_ROLLBACK = 3
 
 
 class LogEntry(Encodable):
@@ -25,7 +30,14 @@ class LogEntry(Encodable):
 
     Entries are immutable once constructed, so their framed encoding is
     cached (_enc): the pg log is re-persisted on EVERY write and
-    re-encoding the whole window per op dominated the OSD profile."""
+    re-encoding the whole window per op dominated the OSD profile.
+
+    `prior_version` on an EC pool is the version of the OBJECT this
+    entry replaced, which every shard keeps as a rollback generation
+    (ObjectId.with_generation) until the write is on all of them: zero
+    when there was no object, the entry's own version when it is not
+    known.  A replicated pool carries the PG's head before the entry
+    there and reads it nowhere."""
 
     __slots__ = ("op", "oid", "version", "prior_version", "reqid",
                  "_enc")
@@ -48,7 +60,15 @@ class LogEntry(Encodable):
         return self._enc
 
     def is_delete(self) -> bool:
-        return self.op == LOG_DELETE
+        return self.op == LOG_DELETE or (
+            self.op == LOG_ROLLBACK
+            and self.prior_version == EVersion.zero())
+
+    def kept_generation(self) -> int:
+        """The rollback generation this entry left on the shards
+        (0: none)."""
+        p = self.prior_version
+        return p.version if EVersion.zero() < p < self.version else 0
 
     def encode_payload(self, enc: Encoder) -> None:
         enc.u8(self.op).string(self.oid)
@@ -250,8 +270,29 @@ class PGLog(Encodable):
         return None
 
     def reqids(self) -> Dict[str, EVersion]:
-        """reqid -> version for duplicate-op detection (PGLog dup index)."""
-        return {e.reqid: e.version for e in self.entries if e.reqid}
+        """reqid -> version for duplicate-op detection (PGLog dup
+        index); what a rollback entry made void is no duplicate."""
+        out: Dict[str, EVersion] = {}
+        for e in self.entries:
+            if e.reqid:
+                out[e.reqid] = e.version
+            elif e.op == LOG_ROLLBACK:
+                self.void_reqids(e, out)
+        return out
+
+    def void_reqids(self, rollback: LogEntry,
+                    reqids: Dict[str, EVersion]) -> None:
+        """Forget the reqids of the writes `rollback` undid: its
+        object's entries newer than the version it restored.  A resend
+        of one of them is then a write like any other, not a duplicate
+        to ack unapplied."""
+        for e in reversed(self.entries):
+            if not rollback.prior_version < e.version:
+                break
+            if e.oid == rollback.oid and e.reqid \
+                    and e.version < rollback.version \
+                    and reqids.get(e.reqid) == e.version:
+                del reqids[e.reqid]
 
     def mutable_copy(self) -> "PGLog":
         """Cheap snapshot (msg/payload.py copy discipline): the entry

@@ -52,8 +52,8 @@ def build_scrub_map(pg, deep: bool) -> Dict[str, ScrubEntry]:
     except NoSuchCollection:
         return out
     for soid in soids:
-        if soid.name == pg.meta_oid.name:
-            continue
+        if soid.name == pg.meta_oid.name or soid.generation:
+            continue      # a rollback generation is no object of the PG
         if not soid.is_head():
             # clones scrub like heads, keyed by name\x00snapid; their
             # CRC_XATTR (per-object for replicated, per-shard for EC)

@@ -64,8 +64,17 @@ class ObjectId(Encodable):
         return ObjectId(self.name, self.key, self.namespace, self.pool,
                         snap, self.shard, self.generation)
 
+    def with_generation(self, generation: int) -> "ObjectId":
+        """The rollback generation of this object that holds the
+        version `generation` (an EC shard keeps what an overwrite
+        replaced until every shard has the overwrite; 0 = the object
+        itself)."""
+        return ObjectId(self.name, self.key, self.namespace, self.pool,
+                        self.snap, self.shard, generation)
+
     def is_head(self) -> bool:
-        return self.snap == SNAP_HEAD
+        # a rollback generation is never the object clients see
+        return self.snap == SNAP_HEAD and not self.generation
 
     def encode_payload(self, enc: Encoder) -> None:
         enc.string(self.name).string(self.key).string(self.namespace)

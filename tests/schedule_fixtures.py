@@ -22,6 +22,13 @@ bug is a no-op with good marketing):
 
 Both patch at class level and restore on exit; apply them INSIDE the
 test, around the run_ec_mini/explore call.
+
+And one WORKLOAD the mini-workload lacks (its writes go to distinct
+names): ``run_two_writes_and_a_read`` puts two writes and a read of
+ONE object into the window at once, so every explored schedule is an
+interleaving of a pipelined pair of writes (ISSUE 33: the second
+follows the first from the end of its submit section) with the read
+behind them, which waits for both acks, and with the shards' acks.
 """
 
 from __future__ import annotations
@@ -159,3 +166,133 @@ def boolean_backfill_marker():
     finally:
         ECBackend._handle_ec_sub_read = orig_read
         ECBackend._stale_shards = orig_stale
+
+
+def copies_not_holding(cl, pool_id: int, name: str, payload: bytes):
+    """The stored copies of `name` that differ from what `payload`
+    makes them: the payload itself on a replicated pool, on an EC pool
+    the shard the codec encodes for that position.  Returns
+    (copies seen, [description of each that differs])."""
+    seen, bad = 0, []
+    for osd in cl.osds.values():
+        for pg in osd.pgs.values():
+            if pg.pool_id != pool_id:
+                continue
+            want = payload
+            if pg.pool.is_erasure():
+                want = bytes(pg.backend.codec.encode(
+                    set(range(pg.backend.n)), payload)[pg.pgid.shard])
+            seen += 1
+            if bytes(osd.store.read(pg.cid, pg.object_id(name))) != want:
+                bad.append(f"osd.{osd.whoami} {pg.pgid}")
+    return seen, bad
+
+
+def run_two_writes_and_a_read(seed: int, pool_type: str = "erasure"):
+    """(ScheduleReport, writes_pipelined) of one schedule of: write A,
+    write B, read of ONE object, submitted in that order by one client
+    without waiting for an answer, on a sim cluster (EC k=2 m=1 or
+    replicated, one PG).  Findings: a reply out of the
+    per-object order (B acked before A; the read answered before
+    either, or with other bytes than B's), a final state other than B
+    on the served path or on any stored copy, and everything
+    check_cluster_invariants holds a quiesced cluster to (window slots
+    balanced, pglog dense and in order, no leaked accounting)."""
+    from ceph_tpu.devtools import schedule as sched
+    from ceph_tpu.msg import payload as payload_mod
+    from ceph_tpu.qa.cluster import Cluster, make_sim_ctx
+
+    report = sched.ScheduleReport(seed=seed)
+    findings = report.findings
+    a, b = b"A" * 1000, b"B" * 1500
+
+    async def main():
+        with sched.commit_observation() as obs, \
+                sched.watch_last_complete(findings):
+            pipelined = await body()
+            findings.extend(obs.findings)
+        return pipelined
+
+    async def body():
+        encode_base = payload_mod.counters()["msg_encode_calls"]
+        cl = Cluster(ctx_factory=make_sim_ctx)
+        admin = await cl.start(3)
+        kw = dict(pool_type="erasure", k=2, m=1) \
+            if pool_type == "erasure" else {}
+        await admin.pool_create("one", pg_num=1, **kw)
+        io = admin.open_ioctx("one")
+        await io.write_full("obj", b"seed")
+        # answers in the order they REACH the client (its tasks wake
+        # in whatever order the scheduler picks)
+        answered = []
+        dispatch = io.objecter.ms_dispatch
+
+        def noting(msg):
+            if getattr(msg, "tid", 0) > tid0 \
+                    and getattr(msg, "result", -1) == 0:
+                answered.append(msg.tid - tid0)
+            return dispatch(msg)
+
+        tid0 = io.objecter._tid
+        io.objecter.ms_dispatch = noting
+
+        async def write(data):
+            await asyncio.wait_for(io.write_full("obj", data), 45.0)
+
+        async def read():
+            got = await asyncio.wait_for(io.read("obj"), 45.0)
+            if got != b:
+                findings.append(
+                    f"read behind the writes returned {got[:1]!r} x "
+                    f"{len(got)}, not the write submitted before it")
+
+        async def submitted(coro):
+            """Start `coro` and return once its op is submitted (the
+            objecter gave it a tid): the scheduler permutes which
+            ready task runs next, the ORDER of the three submits is
+            the thing under test and must not be its to choose."""
+            tid = io.objecter._tid
+            task = asyncio.ensure_future(coro)
+            while io.objecter._tid == tid and not task.done():
+                await asyncio.sleep(0)
+            return task
+
+        await asyncio.gather(await submitted(write(a)),
+                             await submitted(write(b)),
+                             await submitted(read()))
+        io.objecter.ms_dispatch = dispatch
+        if answered != [1, 2, 3]:
+            findings.append(
+                f"answers out of submit order: {answered}")
+        if await io.read("obj") != b:
+            findings.append("final read is not the last write")
+        await sched._quiesce(cl)
+        for where in copies_not_holding(cl, io.pool_id, "obj", b)[1]:
+            findings.append(f"{where} does not hold the last write")
+        for osd in cl.osds.values():
+            for pg in osd.pgs.values():
+                if pg.pool_id != io.pool_id:
+                    continue
+                mine = [e.version.version for e in pg.log.entries
+                        if e.oid == "obj"]
+                if len(mine) != 3 or mine != sorted(mine):
+                    findings.append(
+                        f"osd.{osd.whoami} {pg.pgid} logged {mine} "
+                        f"for three writes of the object")
+        sched.check_cluster_invariants(cl, encode_base=encode_base,
+                                       findings=findings)
+        pipelined = sum(int(o.perf_window.dump()["writes_pipelined"])
+                        for o in cl.osds.values())
+        await cl.stop()
+        return pipelined
+
+    pipelined = 0
+    try:
+        pipelined, loop = sched.run_deterministic(main, seed=seed)
+        report.trace_hash = loop.trace_hash()
+        report.steps = loop.steps
+        report.trace_tail = list(loop.trace_tail)
+    except (Exception, asyncio.CancelledError) as e:
+        findings.append(
+            f"schedule did not complete: {type(e).__name__}: {e}")
+    return report, pipelined
