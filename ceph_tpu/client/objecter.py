@@ -115,41 +115,50 @@ class Objecter(Dispatcher):
     # ------------------------------------------------------------ dispatch
     def ms_dispatch(self, m: Message) -> bool:
         if isinstance(m, MOSDOpReply):
-            op = self._inflight.get(m.tid)
-            if op is None:
-                return True
-            if m.result == -errno.EAGAIN:
-                # osd saw a stale/foreign mapping: refresh map + resend
-                self.monc.sub_want("osdmap",
-                                   max(m.map_epoch,
-                                       self.osdmap.epoch if self.osdmap
-                                       else 0))
-                asyncio.get_running_loop().create_task(
-                    self._resend_later(op))
-                return True
-            del self._inflight[m.tid]
-            self._put_budget(op)
-            self._qos.note_done(op.qos_class, m.qos_phase)
-            if op.span is not None and not op.span.finished:
-                # close the trace: the reply transit back is the last
-                # chain segment, then op_total (t0 -> now) lands as the
-                # aux e2e the coverage guard measures the chain against.
-                # A reply that crossed a process-lane ring carries the
-                # lane's send stamp (converted to this clock by the
-                # parent): rebase the cursor onto it so ack_delivery
-                # covers only the reply leg — the skipped window is the
-                # lane worker's service time, recorded by the lane's
-                # own continuation span (merging would double count)
-                tr = self.ctx.tracer
-                anchor = getattr(m, "_lane_sent_mono", 0.0)
-                if anchor:
-                    op.span.rebase(anchor)
-                op.span.cut("ack_delivery", tr.hist)
-                tr.finish(op.span)
-            if not op.fut.done():
-                op.fut.set_result(m)
-            return True
+            tr = self.ctx.tracer
+            if tr.enabled:
+                with tr.section("loop_client_reply"):
+                    return self._handle_reply(m)
+            return self._handle_reply(m)
         return False
+
+    def _handle_reply(self, m: MOSDOpReply) -> bool:
+        """One MOSDOpReply: the ack_delivery cut, the budget's return,
+        the future's result."""
+        op = self._inflight.get(m.tid)
+        if op is None:
+            return True
+        if m.result == -errno.EAGAIN:
+            # osd saw a stale/foreign mapping: refresh map + resend
+            self.monc.sub_want("osdmap",
+                               max(m.map_epoch,
+                                   self.osdmap.epoch if self.osdmap
+                                   else 0))
+            asyncio.get_running_loop().create_task(
+                self._resend_later(op))
+            return True
+        del self._inflight[m.tid]
+        self._put_budget(op)
+        self._qos.note_done(op.qos_class, m.qos_phase)
+        if op.span is not None and not op.span.finished:
+            # close the trace: the reply transit back is the last
+            # chain segment, then op_total (t0 -> now) lands as the
+            # aux e2e the coverage guard measures the chain against.
+            # A reply that crossed a process-lane ring carries the
+            # lane's send stamp (converted to this clock by the
+            # parent): rebase the cursor onto it so ack_delivery
+            # covers only the reply leg — the skipped window is the
+            # lane worker's service time, recorded by the lane's
+            # own continuation span (merging would double count)
+            tr = self.ctx.tracer
+            anchor = getattr(m, "_lane_sent_mono", 0.0)
+            if anchor:
+                op.span.rebase(anchor)
+            op.span.cut("ack_delivery", tr.hist)
+            tr.finish(op.span)
+        if not op.fut.done():
+            op.fut.set_result(m)
+        return True
 
     async def _resend_later(self, op: _InFlight) -> None:
         op.attempts += 1
@@ -259,8 +268,8 @@ class Objecter(Dispatcher):
         if not pend:
             return
         # op tracing: placement + message build of the cork as one loop
-        # section; the sends below are not in it (a local send can run
-        # the OSD's intake, which has sections of its own)
+        # section; the sends below are the messenger's (loop_msg), and
+        # a local send can run the OSD's intake, with sections of its own
         with self.ctx.tracer.section("loop_client"):
             m = self.osdmap
             if m is not None and len(pend) > 1:
@@ -328,7 +337,14 @@ class Objecter(Dispatcher):
         op.qos_class = QOS_CLASS.get() or self._default_qos_class \
             or "client"
         try:
-            self._take_budget(op, 0)
+            # op tracing: the budget, the op's span and its place in
+            # the cork (the cork's flush is a section of its own)
+            tr = self.ctx.tracer
+            if tr.enabled:
+                with tr.section("loop_client"):
+                    self._take_budget(op, 0)
+            else:
+                self._take_budget(op, 0)
             # the deadline covers a wait for budget too
             reply = await asyncio.wait_for(fut, timeout)
         finally:

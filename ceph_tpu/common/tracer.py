@@ -35,10 +35,13 @@ Design:
     thread that ran it: a ``with`` block that never awaits, timed into
     the same histogram group under ``name`` and entered as a
     ``jax.profiler.TraceAnnotation`` of the same name, so the block
-    lies in the profiler's trace on the profiler's clock.  ``loop_*``
-    sections run on the event-loop thread and never nest (their sum
-    is loop time with a name); ``seam_*`` sections run on the EC
-    queue's device thread, ``store_*`` sections on a store's kv-sync
+    lies in the profiler's trace on the profiler's clock.  Sections
+    may NEST (a send inside ``loop_submit`` runs the messenger's
+    ``loop_msg``): each records its SELF time, its wall minus the wall
+    of the sections it enclosed, so the sum over names counts no
+    instant twice.  ``loop_*`` sections run on the event-loop thread
+    (their sum is loop time with a name); ``seam_*`` sections run on
+    the EC queue's device thread, ``store_*`` sections on a store's kv-sync
     thread (``store_data_write``: a write-behind store's staged data
     written out; then the group's two barriers, ``store_data_sync``
     and ``store_kv_sync``).  INTERVALS (``Tracer.interval``) are the
@@ -47,13 +50,26 @@ Design:
   * While tracing is on, one sampler per event loop records the loop
     thread's wall and CPU time (``loop_wall`` / ``loop_cpu``) every
     100 ms: their ratio is the share of the one Python loop that is
-    burning CPU, the rest is the loop waiting.
+    burning CPU.  The same sampler times the loop's selector for as
+    long as it lives (``evloop_idle``: a ``select`` that was asked to
+    block, the loop asleep with nothing ready; ``evloop_poll``: a
+    ``select(0)`` between ready callbacks, which does no waiting of
+    its own, so it reads the syscall plus the wait to win the GIL
+    back), which closes the account: loop_wall = evloop_idle +
+    evloop_poll + the callbacks' wall, and loop_wall - evloop_idle -
+    loop_cpu is the time the loop was runnable and off its core (the
+    GIL, or the kernel's scheduler).
 
   * Fully off-path when disabled (``op_tracing=false``, the default):
     no span allocation, no clock reads — every call site guards on
     ``tracer.enabled`` / ``span is not None``, and the tracer caches
     the config flag with an observer so the check is one attribute
-    load per op.  ``section()`` then returns one shared no-op object.
+    load per op.  ``section()`` then returns one shared no-op object;
+    a bare ``with tracer.section(..)`` still costs three Python calls
+    (``section``, the no-op's ``__enter__`` and ``__exit__``), which a
+    64 KiB read of some 1,200 calls feels at twenty sites, so a site
+    whose body is ONE call guards on ``tracer.enabled`` instead
+    (``if tr.enabled: with tr.section(..): f() else: f()``).
 """
 
 from __future__ import annotations
@@ -100,6 +116,7 @@ CHAIN_STAGES = (
     "replica_rtt",      # all replica/shard acks gathered
     "commit_wait",      # residual local group-commit wait (post-acks)
     "op_exec",          # read-class execution (reads only)
+    "reply_wait",       # a pipelined write's wait to REPLY in order
     "ack_delivery",     # reply transit back to the client dispatch
 )
 
@@ -132,11 +149,20 @@ SEAM_STAGES = (
 )
 
 #: Synchronous work on the event-loop thread, by section, plus the
-#: loop sampler's two per-tick stages.  Sections never nest, so their
-#: sum over loop_cpu is the share of the loop's CPU that has a name.
+#: loop sampler's two per-tick stages.  A section records its SELF
+#: time (nested sections take theirs out), so the sum over loop_cpu is
+#: the share of the loop's CPU that has a name.
 LOOP_STAGES = (
     "loop_client",        # objecter: placement + message build of a cork
+    "loop_client_reply",  # objecter: an MOSDOpReply -> its op's future
+    "loop_msg",           # messenger: the local hand-over, both sides
+    "loop_pump",          # OSD: one item of a shard's ring (its own
+                          # sections inside take their time out)
     "loop_dispatch",      # OSD: delivered client op / sub-op ack -> its PG
+    "loop_admit",         # PG: queue_op, the window admission
+    "loop_read",          # read at the primary: local shard, fan-out,
+                          # the sub-reads' sends, the replies' streams
+    "loop_sub_read",      # read at a shard: the whole sub-read handler
     "loop_prepare",       # EC write: cls, cow, per-shard txns before encode
     "loop_ec_host",       # EC: a full write's shard txn build (its split,
                           # tobytes and crc only where they run inline: a
@@ -162,6 +188,16 @@ STORE_STAGES = (
     "store_resume",       # interval: barriers done -> completion record runs
 )
 
+#: The loop sampler's timing of the loop's selector, per call of
+#: ``select(timeout)``; histogram only (no annotation: the loop's sleep
+#: overlaps the other threads' sections) and NOT named ``loop_*``:
+#: they are not work.  loop_wall = evloop_idle + evloop_poll + the
+#: callbacks' wall.
+EVLOOP_STAGES = (
+    "evloop_idle",        # select asked to block: asleep, nothing ready
+    "evloop_poll",        # select(0): the syscall + the GIL won back
+)
+
 #: Auxiliary (non-chain) stages, for dump annotation.  recovery_pull
 #: (one recovered object: gather -> decode -> push ack) and
 #: decode_rebuild (the decode slice alone, batched through the EC
@@ -179,10 +215,15 @@ STORE_STAGES = (
 #: that had to wait.  It lies IN FRONT of the chain: the op's span
 #: starts when the budget is held, so client_submit and op_total do
 #: not hold it.
+#: read_gather is the read's twin of replica_rtt, seen from the
+#: primary: the first sub-read's send -> k shard streams in hand (the
+#: sub-read's trip through the shard OSD's messenger, PG queue and
+#: worker), once per gather that asked a remote shard.
 AUX_STAGES = ("op_total", "repl_apply", "repl_commit",
               "recovery_pull", "decode_rebuild",
-              "extent_write", "extent_read", "client_throttle_wait") \
-    + SEAM_STAGES + LOOP_STAGES + STORE_STAGES
+              "extent_write", "extent_read", "client_throttle_wait",
+              "read_gather") \
+    + SEAM_STAGES + LOOP_STAGES + STORE_STAGES + EVLOOP_STAGES
 
 STAGE_GROUP = "op_stages"
 
@@ -299,11 +340,18 @@ def _annotation(name: str):
     return _annotation_cls(name)
 
 
+#: thread id -> the innermost section open on that thread (each links
+#: to the one around it): how a section finds its parent
+_open_section: Dict[int, "_Section"] = {}
+
+
 class _Section:
     """One timed synchronous block: histogram + profiler annotation
-    under one name."""
+    under one name.  Records its SELF time: on exit its wall minus the
+    wall of the sections it enclosed, and its whole wall goes to the
+    enclosed sum of the section around it."""
 
-    __slots__ = ("hist", "name", "ann", "t0")
+    __slots__ = ("hist", "name", "ann", "t0", "parent", "enclosed")
 
     def __init__(self, hist, name: str):
         self.hist = hist
@@ -313,14 +361,25 @@ class _Section:
         self.ann = _annotation(self.name)
         if self.ann is not None:
             self.ann.__enter__()
+        tid = threading.get_ident()
+        self.parent = _open_section.get(tid)
+        _open_section[tid] = self
+        self.enclosed = 0.0
         self.t0 = time.monotonic()
 
     def __exit__(self, *exc):
         dt = time.monotonic() - self.t0
         if self.ann is not None:
             self.ann.__exit__(*exc)
-        self.hist.hinc(self.name, dt)
-        _last_stage[threading.get_ident()] = self.name
+        tid = threading.get_ident()
+        parent = self.parent
+        if parent is None:
+            _open_section.pop(tid, None)
+        else:
+            _open_section[tid] = parent
+            parent.enclosed += dt
+        self.hist.hinc(self.name, dt - self.enclosed)
+        _last_stage[tid] = self.name
         return False
 
 
@@ -331,26 +390,75 @@ LOOP_SAMPLE_PERIOD = 0.1
 _sampled_loops: "weakref.WeakSet" = weakref.WeakSet()
 
 
-class _LoopSampler:
-    """Records the loop thread's wall and CPU time per tick into the
-    histograms of the tracer that started it.  A timer chain, not a
-    task: nothing to cancel when the loop closes.  Ends when its tracer
-    is switched off or collected; the next enabled tracer on that loop
-    starts a new one."""
+class _TimedSelector:
+    """The loop's selector with a clock around ``select``: what the
+    loop sampler puts in ``loop._selector`` for as long as it lives
+    (the seam devtools/schedule.py's virtual selector sits in).  Two
+    clock reads and one histogram record per call; everything else is
+    the selector's own."""
 
-    __slots__ = ("loop", "tracer", "wall", "cpu")
+    __slots__ = ("_inner", "_hist")
+
+    def __init__(self, inner, hist):
+        self._inner = inner
+        self._hist = hist
+
+    def select(self, timeout=None):
+        t0 = time.monotonic()
+        try:
+            return self._inner.select(timeout)
+        finally:
+            self._hist.hinc("evloop_poll" if timeout == 0
+                            else "evloop_idle", time.monotonic() - t0)
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+
+class _LoopSampler:
+    """Records the loop thread's wall and CPU time per tick, and the
+    wall time of every ``select`` of its loop, into the histograms of
+    the tracer that started it.  A timer chain, not a task: nothing to
+    cancel when the loop closes.  Ends when its tracer is switched off
+    (at once: ``Tracer._on_cfg``) or collected, or the loop is closed,
+    and then hands the loop its own selector back; the next enabled
+    tracer on that loop starts a new one."""
+
+    __slots__ = ("loop", "tracer", "wall", "cpu", "selector", "alive")
 
     def __init__(self, loop, tracer: "Tracer"):
         self.loop = loop
         self.tracer = weakref.ref(tracer)
+        self.alive = True
+        self.selector = None
+        inner = getattr(loop, "_selector", None)
+        if inner is not None:
+            self.selector = loop._selector = _TimedSelector(
+                inner, tracer.hist)
         self.wall = time.monotonic()
         self.cpu = time.thread_time()
         loop.call_later(LOOP_SAMPLE_PERIOD, self._tick)
 
+    def stop(self) -> None:
+        """The loop's own selector back in its place; idempotent.  The
+        timer that is still out finds nothing to do."""
+        if not self.alive:
+            return
+        self.alive = False
+        _sampled_loops.discard(self.loop)
+        sel, self.selector = self.selector, None
+        if sel is not None and getattr(self.loop, "_selector",
+                                       None) is sel:
+            self.loop._selector = sel._inner
+        tr = self.tracer()
+        if tr is not None and self in tr._samplers:
+            tr._samplers.remove(self)
+
     def _tick(self) -> None:
         tr = self.tracer()
         if tr is None or not tr.enabled or self.loop.is_closed():
-            _sampled_loops.discard(self.loop)
+            self.stop()
+        if not self.alive:
             return
         wall, cpu = time.monotonic(), time.thread_time()
         tr.hist.hinc("loop_wall", wall - self.wall)
@@ -362,13 +470,17 @@ class _LoopSampler:
 def _ensure_sampler(tracer: "Tracer") -> None:
     """Start this thread's loop's sampler if it has none.  No-op off
     the loop (executor threads) and under the deterministic sim loop,
-    whose clock is virtual and whose schedule a timer would perturb."""
+    whose clock is virtual and whose schedule a timer would perturb
+    (and whose selector is the schedule's own wrapper)."""
     loop = asyncio._get_running_loop()
     if loop is None or loop in _sampled_loops \
             or getattr(loop, "deterministic", False):
         return
+    for old in list(tracer._samplers):
+        if old.loop.is_closed():    # its timer will never fire
+            old.stop()
     _sampled_loops.add(loop)
-    _LoopSampler(loop, tracer)
+    tracer._samplers.append(_LoopSampler(loop, tracer))
 
 
 class Tracer:
@@ -380,6 +492,8 @@ class Tracer:
     def __init__(self, ctx):
         self.ctx = ctx
         self._hist = None
+        #: the loop samplers this tracer started and that still live
+        self._samplers: List[_LoopSampler] = []
         if ctx is None:         # OFF, below: belongs to no daemon
             self.enabled = False
             return
@@ -394,6 +508,10 @@ class Tracer:
 
     def _on_cfg(self, changed: set) -> None:
         self.enabled = bool(self.ctx.config["op_tracing"])
+        if not self.enabled:
+            # off is off at once: no wrapper stays on a loop's selector
+            for sampler in list(self._samplers):
+                sampler.stop()
 
     @property
     def hist(self):

@@ -388,6 +388,20 @@ class LocalConnection:
             d.ms_handle_reset(self.addr)
 
     def send(self, msg: Message) -> None:
+        # op tracing: the local hand-over runs on the SENDER's stack,
+        # so this section nests in the sender's (loop_submit,
+        # loop_reply, loop_read, ...) and takes its time out of it; a
+        # receiver that runs inline (a client batch's intake) takes
+        # its own out of this one.  (Guarded, not a bare `with`: off, a
+        # no-op section is three calls, and a read is five sends)
+        tr = self.msgr.ctx.tracer
+        if tr.enabled:
+            with tr.section("loop_msg"):
+                self._send(msg)
+        else:
+            self._send(msg)
+
+    def _send(self, msg: Message) -> None:
         if self.closed:
             return
         if self._task is None and not self.out_q:
@@ -823,12 +837,23 @@ class Messenger:
                 msg.throttle_cost = cost
                 if span is not None:
                     span.cut("throttle_wait", self.ctx.tracer.hist)
-            gate.put(cost)   # message left the intake queue
-            try:
-                self._dispatch(msg)
-            finally:
-                left = self._local_pending.get(conn_id, 1) - 1
-                self._local_pending[conn_id] = max(0, left)
+            # op tracing: one pass of the receiving side, down to the
+            # dispatcher's own section (which takes its time out)
+            tr = self.ctx.tracer
+            if tr.enabled:
+                with tr.section("loop_msg"):
+                    self._dispatch_local(msg, gate, cost, conn_id)
+            else:
+                self._dispatch_local(msg, gate, cost, conn_id)
+
+    def _dispatch_local(self, msg: Message, gate: AsyncThrottle,
+                        cost: int, conn_id: int) -> None:
+        gate.put(cost)   # message left the intake queue
+        try:
+            self._dispatch(msg)
+        finally:
+            left = self._local_pending.get(conn_id, 1) - 1
+            self._local_pending[conn_id] = max(0, left)
 
     # --- receive path ---
     async def _handle_incoming(self, reader: asyncio.StreamReader,

@@ -730,7 +730,13 @@ class ReplicatedBackend(PGBackend):
                 if result == 0:
                     result = op.rval
             else:
-                rv = execute_read_op(self.osd.store, pg.cid, soid, op)
+                tr = self.osd.ctx.tracer
+                if tr.enabled:
+                    with tr.section("loop_read"):
+                        rv = execute_read_op(self.osd.store, pg.cid,
+                                             soid, op)
+                else:
+                    rv = execute_read_op(self.osd.store, pg.cid, soid, op)
                 if rv < 0 and result == 0:
                     result = rv
         return result
@@ -1829,41 +1835,18 @@ class ECBackend(PGBackend):
         shard_vers: Dict[int, bytes] = {}
         my = self.my_shard
         candidates: List[int] = []
-        for i, osd_id in enumerate(pg.acting):
-            if osd_id == CRUSH_ITEM_NONE or i in exclude:
-                continue
-            if i == my:
-                from ceph_tpu.osd.pglog import LB_MAX
-                try:
-                    my_attrs = self.osd.store.getattrs(pg.cid, soid)
-                    if pg.info.last_backfill != LB_MAX \
-                            and oid > pg.info.last_backfill \
-                            and VERSION_XATTR not in my_attrs:
-                        # OUR OWN copy is mid-backfill, this name is
-                        # past the durable cursor AND versionless: an
-                        # untrusted half-copy — the same read gate
-                        # _handle_ec_sub_read applies for peers
-                        # (PG.h:1911).  A versioned row still joins
-                        # the gather; the cohort check judges it.
-                        continue
-                    streams[i] = np.frombuffer(
-                        self.osd.store.read(pg.cid, soid), np.uint8)
-                    attrs = my_attrs
-                    shard_attrs[i] = attrs
-                    shard_vers[i] = attrs.get(VERSION_XATTR, b"")
-                except (NoSuchObject, NoSuchCollection):
-                    pass
-            else:
-                candidates.append(i)
-        need = self.k - len(streams)
+        tr = self.osd.ctx.tracer
+        need, pending = self.k, []
 
         async def ask_shard(i: int):
-            osd_id = pg.acting[i]
-            tid = self.osd.next_tid()
-            fut = asyncio.get_running_loop().create_future()
-            self._inflight[tid] = ({osd_id}, fut)
-            self.osd.send_osd(osd_id, MOSDECSubOpRead(
-                pg.pgid.with_shard(i), tid, [(oid, 0, -1)], snap=snap))
+            with tr.section("loop_read"):
+                osd_id = pg.acting[i]
+                tid = self.osd.next_tid()
+                fut = asyncio.get_running_loop().create_future()
+                self._inflight[tid] = ({osd_id}, fut)
+                self.osd.send_osd(osd_id, MOSDECSubOpRead(
+                    pg.pgid.with_shard(i), tid, [(oid, 0, -1)],
+                    snap=snap))
             try:
                 reply: MOSDECSubOpReadReply = \
                     await asyncio.wait_for(fut, 15.0)
@@ -1872,33 +1855,82 @@ class ECBackend(PGBackend):
                 raise
             return i, reply
 
-        # fan out to exactly `need` candidates CONCURRENTLY — a
-        # degraded k-shard read is one RTT, not k sequential ones —
-        # topping up from the remaining candidates (preference order
-        # preserved) as refusals and timeouts come back
-        pending = list(candidates)
-        while need > 0 and pending:
+        def fan_out():
+            """The next wave of sub-reads: a task per shard asked, made
+            here, run on the loop's next pass; None when there is
+            nothing more to ask."""
+            nonlocal pending
+            if need <= 0 or not pending:
+                return None
             wave, pending = pending[:need], pending[need:]
-            replies = await asyncio.gather(
-                *[ask_shard(i) for i in wave], return_exceptions=True)
+            return asyncio.gather(*[ask_shard(i) for i in wave],
+                                  return_exceptions=True)
+
+        with tr.section("loop_read"):
+            for i, osd_id in enumerate(pg.acting):
+                if osd_id == CRUSH_ITEM_NONE or i in exclude:
+                    continue
+                if i == my:
+                    from ceph_tpu.osd.pglog import LB_MAX
+                    try:
+                        my_attrs = self.osd.store.getattrs(pg.cid, soid)
+                        if pg.info.last_backfill != LB_MAX \
+                                and oid > pg.info.last_backfill \
+                                and VERSION_XATTR not in my_attrs:
+                            # OUR OWN copy is mid-backfill, this name
+                            # is past the durable cursor AND
+                            # versionless: an untrusted half-copy — the
+                            # same read gate _handle_ec_sub_read
+                            # applies for peers (PG.h:1911).  A
+                            # versioned row still joins the gather; the
+                            # cohort check judges it.
+                            continue
+                        streams[i] = np.frombuffer(
+                            self.osd.store.read(pg.cid, soid), np.uint8)
+                        attrs = my_attrs
+                        shard_attrs[i] = attrs
+                        shard_vers[i] = attrs.get(VERSION_XATTR, b"")
+                    except (NoSuchObject, NoSuchCollection):
+                        pass
+                else:
+                    candidates.append(i)
+            # fan out to exactly `need` candidates CONCURRENTLY — a
+            # degraded k-shard read is one RTT, not k sequential ones
+            # — topping up from the remaining candidates (preference
+            # order preserved) as refusals and timeouts come back
+            need = self.k - len(streams)
+            pending = list(candidates)
+            # op tracing: the first sub-read's send -> k streams in
+            # hand (the read's twin of replica_rtt), once per gather
+            # that asks
+            t_ask = tr.stamp() if tr.enabled and need > 0 and pending \
+                else 0.0
+            asked = fan_out()
+        while asked is not None:
+            replies = await asked
             interval_err = None
-            for r in replies:
-                if isinstance(r, PGIntervalChanged):
-                    # don't degrade the gather to EIO — abort the whole
-                    # op so the caller retries under the new acting set
-                    interval_err = r
-                    continue
-                if isinstance(r, BaseException):
-                    continue
-                i, reply = r
-                if reply.result == 0 and reply.data:
-                    streams[i] = np.frombuffer(reply.data[0], np.uint8)
-                    if reply.attrs:
-                        attrs = reply.attrs
-                        shard_attrs[i] = reply.attrs
-                        shard_vers[i] = reply.attrs.get(
-                            VERSION_XATTR, b"")
-                    need -= 1
+            with tr.section("loop_read"):
+                for r in replies:
+                    if isinstance(r, PGIntervalChanged):
+                        # don't degrade the gather to EIO — abort the
+                        # whole op so the caller retries under the new
+                        # acting set
+                        interval_err = r
+                        continue
+                    if isinstance(r, BaseException):
+                        continue
+                    i, reply = r
+                    if reply.result == 0 and reply.data:
+                        streams[i] = np.frombuffer(reply.data[0],
+                                                   np.uint8)
+                        if reply.attrs:
+                            attrs = reply.attrs
+                            shard_attrs[i] = reply.attrs
+                            shard_vers[i] = reply.attrs.get(
+                                VERSION_XATTR, b"")
+                        need -= 1
+                if interval_err is None:
+                    asked = fan_out()
             if interval_err is not None:
                 raise interval_err
         if len(streams) < self.k:
@@ -1907,6 +1939,8 @@ class ECBackend(PGBackend):
         vers = {shard_vers.get(i, b"") for i in streams}
         if (want_version is not None and len(lens) == 1
                 and vers == {want_version}):
+            if t_ask:
+                tr.interval("read_gather", t_ask)
             return streams, attrs        # exact generation, consistent
         if len(lens) > 1 or len(vers) > 1 or (
                 want_version is not None
@@ -1964,6 +1998,8 @@ class ECBackend(PGBackend):
             if len(best) < self.k:
                 return None
             streams = best
+        if t_ask:
+            tr.interval("read_gather", t_ask)
         # attrs must describe the RETURNED cohort, not whichever shard
         # replied last: a stale generation's SIZE_XATTR would silently
         # truncate fresh decoded bytes downstream
@@ -2214,7 +2250,12 @@ class ECBackend(PGBackend):
         if isinstance(m, MOSDECSubOpWrite):
             self._apply_ec_sub_write(m)
         elif isinstance(m, MOSDECSubOpRead):
-            self._handle_ec_sub_read(m)
+            tr = self.osd.ctx.tracer
+            if tr.enabled:
+                with tr.section("loop_sub_read"):
+                    self._handle_ec_sub_read(m)
+            else:
+                self._handle_ec_sub_read(m)
 
     def sub_write_fast(self, m) -> bool:
         if isinstance(m, MOSDECSubOpWrite):

@@ -1568,6 +1568,14 @@ class PG:
             await tiering.maybe_promote(self, m)
 
     def queue_op(self, m) -> None:
+        tr = self.osd.ctx.tracer
+        if tr.enabled:
+            with tr.section("loop_admit"):
+                self._op_queue.put_nowait(m, self._queue_class(m))
+        else:
+            self._op_queue.put_nowait(m, self._queue_class(m))
+
+    def _queue_class(self, m) -> str:
         from ceph_tpu.osd.messages import (MPGPush, MPGScrub,
                                            MPGScrubScan)
         if callable(m):
@@ -1593,7 +1601,7 @@ class PG:
             # client's priority (a deprioritized sub-op would stall the
             # primary awaiting its ack)
             klass = "client"
-        self._op_queue.put_nowait(m, klass)
+        return klass
 
     def _is_barrier_op(self, m: MOSDOp) -> bool:
         """Whole-PG dependency class: ops that read or mutate PG-scope
@@ -1676,21 +1684,22 @@ class PG:
                         # admission (per-object order == queue order);
                         # machine-checked by devtools rule AF01
                         # awaitfree:begin window-admission
-                        m._windowed = True
-                        slot = m._slot = seq.admit(
-                            m.oid, *self._admission_class(m))
-                        self._track_window_task(
-                            m, asyncio.get_running_loop().create_task(
-                                self._run_windowed(m, slot)))
-                        early = getattr(self.backend,
-                                        "start_early_encode", None)
-                        if early is not None and slot.must_wait() \
-                                and not self._resend_in_window(m):
-                            # an EC pool's full write: its encode is a
-                            # pure function of its payload and need
-                            # not stand in the chain with the op
-                            # (None: no such op)
-                            self._track_window_task(m, early(m))
+                        with self.osd.ctx.tracer.section("loop_admit"):
+                            m._windowed = True
+                            slot = m._slot = seq.admit(
+                                m.oid, *self._admission_class(m))
+                            self._track_window_task(
+                                m, asyncio.get_running_loop().create_task(
+                                    self._run_windowed(m, slot)))
+                            early = getattr(self.backend,
+                                            "start_early_encode", None)
+                            if early is not None and slot.must_wait() \
+                                    and not self._resend_in_window(m):
+                                # an EC pool's full write: its encode
+                                # is a pure function of its payload and
+                                # need not stand in the chain with the
+                                # op (None: no such op)
+                                self._track_window_task(m, early(m))
                         # awaitfree:end window-admission
                 elif isinstance(m, MPGScrub):
                     # scrub drains the window: no client op can
@@ -1793,8 +1802,10 @@ class PG:
         if slot is None or not slot.reply_waits:
             return
         if await slot.wait_reply() and m._span is not None:
-            # the same wait for its own object's chain, at its far end
-            m._span.cut("dep_wait", self.osd.ctx.tracer.hist)
+            # the wait for its own object's chain at its far end, under
+            # a name of its own: dep_wait stays "an admitted op's wait
+            # before it runs"
+            m._span.cut("reply_wait", self.osd.ctx.tracer.hist)
 
     def _finish_client_op(self, m: MOSDOp) -> None:
         """Release one client op's OSD-wide accounting — OpTracker

@@ -310,6 +310,7 @@ class Shard:
         evt = self._evt
         osd = self.plane.osd
         log = osd.logger
+        tracer = osd.ctx.tracer
         while not self._stopping:
             if ring:
                 # gil-atomic:begin ring,_wake_armed single consumer:
@@ -323,7 +324,14 @@ class Shard:
                 fn, args = ring.popleft()
                 # gil-atomic:end
                 try:
-                    fn(*args)
+                    # op tracing: one ring item; what it runs inside
+                    # (loop_dispatch, loop_store_apply, ...) takes its
+                    # own time out of this
+                    if tracer.enabled:
+                        with tracer.section("loop_pump"):
+                            fn(*args)
+                    else:
+                        fn(*args)
                 except asyncio.CancelledError:
                     raise
                 except Exception:

@@ -475,15 +475,28 @@ def test_admin_socket_tracer_commands():
     asyncio.run(run())
 
 
-def test_loop_sections_never_nest_and_seam_sections_run_off_the_loop(
+#: the loop sections that were there before sections could nest (PR 34)
+OLD_LOOP_SECTIONS = ("loop_client", "loop_dispatch", "loop_prepare",
+                     "loop_ec_host", "loop_store_apply",
+                     "loop_store_commit", "loop_submit", "loop_reply")
+#: the hops every op takes and the read path (PR 34)
+HOP_SECTIONS = ("loop_msg", "loop_pump", "loop_admit", "loop_client_reply",
+                "loop_read", "loop_sub_read")
+
+
+def test_loop_sections_nest_in_order_and_seam_sections_run_off_the_loop(
         monkeypatch):
     """The rule the readers lean on, on a live EC mini-cluster through
-    the device seam: `loop_*` sections run on the loop thread and never
-    inside one another (their sum is loop time with a name, counted
-    once); the `seam_*` sections run on the ec-device thread, a full
-    write's continuation (`seam_finish`: its shards' bytes and digests)
-    among them and never on the loop; and the loop sampler's two
-    stages are there beside them."""
+    the device seam: `loop_*` sections run on the loop thread; they may
+    lie inside one another (a send inside `loop_submit` is the
+    messenger's `loop_msg`, a ring item's `loop_pump` holds what it
+    dispatches) and each records its SELF time, so their sum is loop
+    time with a name, counted once; the eight that were there before
+    the hops had names never lie inside one another (so no reading of
+    theirs was ever counted twice); the `seam_*` sections run on the
+    ec-device thread, a full write's continuation (`seam_finish`: its
+    shards' bytes and digests) among them and never on the loop; and
+    the loop sampler's stages are there beside them."""
     import threading
     from ceph_tpu.qa.cluster import Cluster, make_ctx
 
@@ -494,18 +507,26 @@ def test_loop_sections_never_nest_and_seam_sections_run_off_the_loop(
     real_enter = tracer_mod._Section.__enter__
     real_exit = tracer_mod._Section.__exit__
 
+    nested = set()
+
     def enter(self):
         on_loop = threading.get_ident() == loop_thread
         seen.setdefault(self.name, set()).add(on_loop)
         if self.name.startswith("loop_"):
-            if open_loop:
-                faults.append(f"{self.name} inside {open_loop[-1]}")
+            for outer in open_loop:
+                nested.add((outer, self.name))
+                if outer in OLD_LOOP_SECTIONS \
+                        and self.name in OLD_LOOP_SECTIONS:
+                    faults.append(f"{self.name} inside {outer}")
             open_loop.append(self.name)
         return real_enter(self)
 
     def exit_(self, *exc):
         if self.name.startswith("loop_"):
-            open_loop.pop()
+            if open_loop.pop() != self.name:
+                faults.append(f"{self.name} closed out of order")
+        elif threading.get_ident() == loop_thread and open_loop:
+            faults.append(f"{self.name} inside {open_loop[-1]}")
         return real_exit(self, *exc)
 
     monkeypatch.setattr(tracer_mod._Section, "__enter__", enter)
@@ -548,11 +569,17 @@ def test_loop_sections_never_nest_and_seam_sections_run_off_the_loop(
     assert sum(d["finish_thread"] for d in seam) == 12
     assert sum(d["finish_inline"] for d in seam) == 0
     assert not faults, faults[:5]
-    assert not open_loop
-    for name in ("loop_client", "loop_dispatch", "loop_prepare",
-                 "loop_ec_host", "loop_store_apply", "loop_store_commit",
-                 "loop_submit", "loop_reply"):
+    assert not open_loop and not tracer_mod._open_section
+    for name in OLD_LOOP_SECTIONS + HOP_SECTIONS:
         assert seen[name] == {True}, (name, seen[name])
+    # the local hand-over runs on its sender's stack, a ring item holds
+    # its dispatch, a dispatched op its admission
+    for pair in (("loop_submit", "loop_msg"), ("loop_reply", "loop_msg"),
+                 ("loop_read", "loop_msg"), ("loop_sub_read", "loop_msg"),
+                 ("loop_msg", "loop_client_reply"),
+                 ("loop_dispatch", "loop_admit"),
+                 ("loop_pump", "loop_store_apply")):
+        assert pair in nested, (pair, sorted(nested))
     # (no seam_split: that is the result copy of a request WITHOUT a
     # continuation, and every request here is a full write's)
     for name in ("seam_fold", "seam_h2d", "seam_launch", "seam_d2h",
@@ -560,8 +587,13 @@ def test_loop_sections_never_nest_and_seam_sections_run_off_the_loop(
         assert seen[name] == {False}, (name, seen[name])
     assert "seam_split" not in seen
     for name in list(seen) + ["seam_apply", "seam_pending",
-                              "seam_resume", "loop_wall", "loop_cpu"]:
+                              "seam_resume", "loop_wall", "loop_cpu",
+                              "evloop_idle", "evloop_poll",
+                              "read_gather"]:
         assert merged[name].count > 0, name
+    # a read of a k=2 m=1 object asks one remote shard: one gather each
+    assert merged["read_gather"].count == 12
+    assert merged["loop_sub_read"].count == 12
     # 12 EC writes: one split and one shard-txn build each (the
     # shards' tobytes and digests are the continuation's), and one
     # assembly per read; every write of both pools applies at its
