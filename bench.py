@@ -488,7 +488,8 @@ def stage_ec_e2e():
         shard_c = {}
         for osd in cl.osds.values():
             for k in ("handoff_ops", "handoff_wakeups",
-                      "direct_local_ops", "subop_inline"):
+                      "direct_local_ops", "subop_inline",
+                      "subread_inline", "subread_queued"):
                 shard_c[k] = shard_c.get(k, 0) \
                     + int(osd.shards.counters().get(k, 0))
         obj_batches = admin.objecter.batches_sent

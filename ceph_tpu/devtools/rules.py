@@ -846,7 +846,8 @@ _S11_MUT_METHODS = {
     "handle_watch", "maybe_trim_snaps", "generate_past_intervals",
     "load_meta", "create_onstore", "save_meta", "save_meta_log",
     "complete_to",
-    "append_log", "note_reqid", "try_fast_sub_write"}
+    "append_log", "note_reqid", "try_fast_sub_write",
+    "try_fast_sub_read"}
 #: calls whose result is a PG object
 _S11_PG_SOURCES = {"_pg_for", "_load_stray_pg"}
 

@@ -79,13 +79,71 @@ class _ReplTrace:
         self.hist.hinc("repl_commit", time.monotonic() - self.t_q)
 
 
+class _SubReadWave:
+    """One wave of an EC gather's sub-reads: ONE future and ONE
+    deadline for all of them, and no task.  The sends of a wave all
+    happen at one instant, so a deadline per wave falls on the instant
+    a deadline per shard would.  `handle_reply` stores each reply by
+    shard as it comes and resolves the future from the dispatch of the
+    LAST one the wave waits for; at the deadline the unanswered tids
+    leave `_inflight` and the wave resolves with what it has (the
+    gather skips them and tops up, as after a refusal)."""
+
+    __slots__ = ("fut", "replies", "waiting", "_inflight", "_timer")
+
+    def __init__(self, inflight: dict):
+        self.fut = asyncio.get_running_loop().create_future()
+        self.replies: Dict[int, MOSDECSubOpReadReply] = {}
+        self.waiting: Dict[int, int] = {}     # tid -> shard, unanswered
+        self._inflight = inflight
+        self._timer: Optional[asyncio.TimerHandle] = None
+
+    def expect(self, tid: int, shard: int) -> None:
+        self.waiting[tid] = shard
+        self._inflight[tid] = (self, self.fut)
+
+    def arm(self, timeout: float) -> None:
+        if self.waiting:
+            self._timer = self.fut.get_loop().call_later(
+                timeout, self.close)
+        else:
+            self.close()
+
+    def answered(self, tid: int, reply) -> None:
+        shard = self.waiting.pop(tid, None)
+        if shard is not None:
+            self.replies[shard] = reply
+            if not self.waiting:
+                self.close()
+
+    def close(self) -> None:
+        """All answered, the deadline, or the gather gone: what is
+        unanswered leaves `_inflight`, the timer goes, and a future
+        still open resolves with the replies in hand."""
+        for tid in self.waiting:
+            self._inflight.pop(tid, None)
+        self.waiting.clear()
+        if self._timer is not None:
+            self._timer.cancel()
+            self._timer = None
+        if not self.fut.done():
+            self.fut.set_result(self.replies)
+
+    async def wait(self) -> Dict[int, MOSDECSubOpReadReply]:
+        try:
+            return await self.fut
+        finally:
+            self.close()
+
+
 class PGBackend:
     def __init__(self, pg):
         self.pg = pg
         self.osd = pg.osd
         self.log_ = pg.log_
-        # in-flight rep ops: tid -> (pending peer set, future)
-        self._inflight: Dict[int, Tuple[set, asyncio.Future]] = {}
+        # in-flight rep ops: tid -> (pending peer set, future); an EC
+        # gather's sub-reads: tid -> (their _SubReadWave, its future)
+        self._inflight: Dict[int, Tuple[object, asyncio.Future]] = {}
 
     def on_interval_change(self) -> None:
         """Fail every in-flight ack/read/push future: replies from the
@@ -343,6 +401,11 @@ class PGBackend:
         hand it to the PG worker as usual."""
         return False
 
+    def sub_read_fast(self, m) -> bool:
+        """sub_write_fast's twin for an EC shard's READ sub-op (a
+        replicated pool has none)."""
+        return False
+
     def handle_reply(self, m) -> None:
         """Ack-type messages resolve futures the PG worker is awaiting —
         they MUST bypass the op queue (the worker is blocked on them)."""
@@ -350,7 +413,11 @@ class PGBackend:
             self._ack_rx(m.tid, m.from_osd)
         elif isinstance(m, MOSDECSubOpReadReply):
             ent = self._inflight.pop(m.tid, None)
-            if ent is not None and not ent[1].done():
+            if ent is None:
+                return
+            if isinstance(ent[0], _SubReadWave):
+                ent[0].answered(m.tid, m)
+            elif not ent[1].done():
                 ent[1].set_result(m)
 
     async def recover_object(self, peer: int, oid: str,
@@ -1838,33 +1905,44 @@ class ECBackend(PGBackend):
         tr = self.osd.ctx.tracer
         need, pending = self.k, []
 
-        async def ask_shard(i: int):
-            with tr.section("loop_read"):
-                osd_id = pg.acting[i]
+        def ask(wave_shards: List[int]) -> _SubReadWave:
+            """Build and SEND one wave's sub-reads in place (a send to
+            a local peer appends to a ring and runs nothing of the
+            receiver): no task and no timer per shard, one future and
+            one deadline for the wave."""
+            wave = _SubReadWave(self._inflight)
+            for i in wave_shards:
                 tid = self.osd.next_tid()
-                fut = asyncio.get_running_loop().create_future()
-                self._inflight[tid] = ({osd_id}, fut)
-                self.osd.send_osd(osd_id, MOSDECSubOpRead(
+                wave.expect(tid, i)
+                self.osd.send_osd(pg.acting[i], MOSDECSubOpRead(
                     pg.pgid.with_shard(i), tid, [(oid, 0, -1)],
                     snap=snap))
-            try:
-                reply: MOSDECSubOpReadReply = \
-                    await asyncio.wait_for(fut, 15.0)
-            except (asyncio.TimeoutError, PGIntervalChanged):
-                self._inflight.pop(tid, None)
-                raise
-            return i, reply
+            wave.arm(15.0)
+            return wave
 
-        def fan_out():
-            """The next wave of sub-reads: a task per shard asked, made
-            here, run on the loop's next pass; None when there is
-            nothing more to ask."""
+        def take(replies: Dict[int, MOSDECSubOpReadReply]) -> int:
+            """Keep the streams a wave brought; how many it brought."""
+            nonlocal attrs
+            n = 0
+            for i, reply in replies.items():
+                if reply.result == 0 and reply.data:
+                    streams[i] = np.frombuffer(reply.data[0], np.uint8)
+                    if reply.attrs:
+                        attrs = reply.attrs
+                        shard_attrs[i] = reply.attrs
+                        shard_vers[i] = reply.attrs.get(
+                            VERSION_XATTR, b"")
+                    n += 1
+            return n
+
+        def fan_out() -> Optional[_SubReadWave]:
+            """The next wave of sub-reads, sent here; None when there
+            is nothing more to ask."""
             nonlocal pending
             if need <= 0 or not pending:
                 return None
             wave, pending = pending[:need], pending[need:]
-            return asyncio.gather(*[ask_shard(i) for i in wave],
-                                  return_exceptions=True)
+            return ask(wave)
 
         with tr.section("loop_read"):
             for i, osd_id in enumerate(pg.acting):
@@ -1907,32 +1985,13 @@ class ECBackend(PGBackend):
                 else 0.0
             asked = fan_out()
         while asked is not None:
-            replies = await asked
-            interval_err = None
+            # PGIntervalChanged (on_interval_change fails the wave's
+            # future) is not degraded to EIO: it aborts the whole op
+            # so the caller retries under the new acting set
+            replies = await asked.wait()
             with tr.section("loop_read"):
-                for r in replies:
-                    if isinstance(r, PGIntervalChanged):
-                        # don't degrade the gather to EIO — abort the
-                        # whole op so the caller retries under the new
-                        # acting set
-                        interval_err = r
-                        continue
-                    if isinstance(r, BaseException):
-                        continue
-                    i, reply = r
-                    if reply.result == 0 and reply.data:
-                        streams[i] = np.frombuffer(reply.data[0],
-                                                   np.uint8)
-                        if reply.attrs:
-                            attrs = reply.attrs
-                            shard_attrs[i] = reply.attrs
-                            shard_vers[i] = reply.attrs.get(
-                                VERSION_XATTR, b"")
-                        need -= 1
-                if interval_err is None:
-                    asked = fan_out()
-            if interval_err is not None:
-                raise interval_err
+                need -= take(replies)
+                asked = fan_out()
         if len(streams) < self.k:
             return None
         lens = {len(s) for s in streams.values()}
@@ -1954,24 +2013,9 @@ class ECBackend(PGBackend):
             # agree on VERSION_XATTR.  Pull every remaining candidate
             # and decode from the best consistent cohort.
             rest = [i for i in candidates if i not in streams]
-            replies = await asyncio.gather(
-                *[ask_shard(i) for i in rest], return_exceptions=True)
-            interval_err = None
-            for r in replies:
-                if isinstance(r, PGIntervalChanged):
-                    interval_err = r
-                    continue
-                if isinstance(r, BaseException):
-                    continue
-                i, reply = r
-                if reply.result == 0 and reply.data:
-                    streams[i] = np.frombuffer(reply.data[0], np.uint8)
-                    if reply.attrs:
-                        shard_attrs[i] = reply.attrs
-                        shard_vers[i] = reply.attrs.get(VERSION_XATTR,
-                                                        b"")
-            if interval_err is not None:
-                raise interval_err
+            with tr.section("loop_read"):
+                asked = ask(rest)
+            take(await asked.wait())
             cohorts: Dict[tuple, Dict[int, np.ndarray]] = {}
             for i, s in streams.items():
                 cohorts.setdefault(
@@ -2250,18 +2294,26 @@ class ECBackend(PGBackend):
         if isinstance(m, MOSDECSubOpWrite):
             self._apply_ec_sub_write(m)
         elif isinstance(m, MOSDECSubOpRead):
-            tr = self.osd.ctx.tracer
-            if tr.enabled:
-                with tr.section("loop_sub_read"):
-                    self._handle_ec_sub_read(m)
-            else:
-                self._handle_ec_sub_read(m)
+            self.sub_read_fast(m)
 
     def sub_write_fast(self, m) -> bool:
         if isinstance(m, MOSDECSubOpWrite):
             self._apply_ec_sub_write(m)
             return True
         return False
+
+    def sub_read_fast(self, m) -> bool:
+        """Serve a shard's sub-read: SYNCHRONOUS like the sub-write's
+        apply (store read, getattrs, the reply's send), so the sharded
+        plane runs it off the ring (PG.try_fast_sub_read) and the PG
+        worker calls the same."""
+        tr = self.osd.ctx.tracer
+        if tr.enabled:
+            with tr.section("loop_sub_read"):
+                self._handle_ec_sub_read(m)
+        else:
+            self._handle_ec_sub_read(m)
+        return True
 
     def _apply_ec_sub_write(self, m) -> None:
         """Shard write sub-op apply: SYNCHRONOUS by contract (no

@@ -964,14 +964,23 @@ class OSD(Dispatcher):
                 return
             # sharded plane: write sub-ops apply INLINE off the ring
             # when nothing is queued ahead — the queue+wakeup hop is
-            # the per-sub-op cost the tracer's replica_rtt carries.
+            # the per-sub-op cost the tracer's replica_rtt carries —
+            # and an EC sub-read is SERVED from the ring under the
+            # same rule (read_gather carries that hop).
             # shards=1 keeps the classic queue path bit-for-bit.
-            if self.shards.enabled \
-                    and isinstance(m, (MOSDRepOp, MOSDECSubOpWrite)) \
-                    and pg.try_fast_sub_write(m):
-                if self.shards.perf is not None:
-                    self.shards.perf.inc("subop_inline")
-                return
+            if self.shards.enabled:
+                perf = self.shards.perf
+                if isinstance(m, MOSDECSubOpRead):
+                    inline = pg.try_fast_sub_read(m)
+                    if perf is not None:
+                        perf.inc("subread_inline" if inline
+                                 else "subread_queued")
+                    if inline:
+                        return
+                elif pg.try_fast_sub_write(m):
+                    if perf is not None:
+                        perf.inc("subop_inline")
+                    return
             pg.queue_op(m)
             return
         if isinstance(m, (MOSDRepOpReply, MOSDECSubOpWriteReply,

@@ -1745,6 +1745,18 @@ class PG:
             return False
         return self.backend.sub_write_fast(m)
 
+    def try_fast_sub_read(self, m) -> bool:
+        """The read's twin of try_fast_sub_write, under the SAME rule
+        and no other: an EC sub-read is served straight from the
+        classify seam while nothing could be ordered ahead of it
+        (per-connection FIFO holds on the ring, an inline sub-write
+        has already applied, a queued one makes the queue non-empty),
+        so it sees exactly the store state the worker would have shown
+        it one pass later."""
+        if self._worker_busy or not self._op_queue.empty():
+            return False
+        return self.backend.sub_read_fast(m)
+
     async def _run_windowed(self, m: MOSDOp, slot) -> None:
         """One admitted client op: wait out its object-dependency
         chain, execute, release the slot (always — a failed op must

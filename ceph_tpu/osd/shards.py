@@ -391,7 +391,8 @@ class ShardedDataPlane:
         if self.enabled:
             self.perf = osd.ctx.perf.create("osd_shard_handoff")
             for key in ("handoff_ops", "handoff_wakeups",
-                        "direct_local_ops", "subop_inline"):
+                        "direct_local_ops", "subop_inline",
+                        "subread_inline", "subread_queued"):
                 self.perf.add_u64(key)
         self._host_loop: Optional[asyncio.AbstractEventLoop] = None
 

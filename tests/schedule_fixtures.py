@@ -188,11 +188,14 @@ def copies_not_holding(cl, pool_id: int, name: str, payload: bytes):
     return seen, bad
 
 
-def run_two_writes_and_a_read(seed: int, pool_type: str = "erasure"):
+def run_two_writes_and_a_read(seed: int, pool_type: str = "erasure",
+                              num_shards: int = 1):
     """(ScheduleReport, writes_pipelined) of one schedule of: write A,
     write B, read of ONE object, submitted in that order by one client
     without waiting for an answer, on a sim cluster (EC k=2 m=1 or
-    replicated, one PG).  Findings: a reply out of the
+    replicated, one PG; `num_shards` > 1 runs the sharded plane, whose
+    pumps serve sub-writes and sub-reads off their rings).  Findings:
+    a reply out of the
     per-object order (B acked before A; the read answered before
     either, or with other bytes than B's), a final state other than B
     on the served path or on any stored copy, and everything
@@ -200,7 +203,7 @@ def run_two_writes_and_a_read(seed: int, pool_type: str = "erasure"):
     balanced, pglog dense and in order, no leaked accounting)."""
     from ceph_tpu.devtools import schedule as sched
     from ceph_tpu.msg import payload as payload_mod
-    from ceph_tpu.qa.cluster import Cluster, make_sim_ctx
+    from ceph_tpu.qa.cluster import Cluster
 
     report = sched.ScheduleReport(seed=seed)
     findings = report.findings
@@ -215,7 +218,7 @@ def run_two_writes_and_a_read(seed: int, pool_type: str = "erasure"):
 
     async def body():
         encode_base = payload_mod.counters()["msg_encode_calls"]
-        cl = Cluster(ctx_factory=make_sim_ctx)
+        cl = Cluster(ctx_factory=sched._sim_ctx_factory(num_shards))
         admin = await cl.start(3)
         kw = dict(pool_type="erasure", k=2, m=1) \
             if pool_type == "erasure" else {}

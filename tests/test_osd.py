@@ -414,3 +414,219 @@ def test_cluster_flag_noout_holds_down_osd_in():
         assert "nosuchflag" in str(ei.value)
         await cl.stop()
     asyncio.run(run())
+
+
+# ----------------------- an EC gather's sub-read waves (ISSUE 35)
+#
+# The asking side of an EC read on the deterministic loop (virtual
+# time): a wave of sub-reads is sent in place and waits on ONE future
+# under ONE deadline, whatever happens to the replies.
+
+_GATHER_POOLS = {
+    # id: (osds, k, m, OSDs of the PG marked down first)
+    "k2m1_healthy": (3, 2, 1, 0),
+    "k4m2_one_down": (6, 4, 2, 1),
+}
+
+
+def _backend_calls(loop):
+    """Count `loop.create_task` and `loop.call_at` calls made from
+    osd/backend.py (the nearest frame outside asyncio), and keep the
+    timer handles.  Returns (counts, handles, undo)."""
+    import sys
+    counts = {"create_task": 0, "call_at": 0}
+    handles = []
+    real_task, real_at = loop.create_task, loop.call_at
+
+    def from_backend() -> bool:
+        f = sys._getframe(2)
+        while f is not None and "asyncio" in f.f_code.co_filename:
+            f = f.f_back
+        return f is not None and \
+            f.f_code.co_filename.endswith("osd/backend.py")
+
+    def create_task(*a, **kw):
+        if from_backend():
+            counts["create_task"] += 1
+        return real_task(*a, **kw)
+
+    def call_at(*a, **kw):
+        h = real_at(*a, **kw)
+        if from_backend():
+            counts["call_at"] += 1
+            handles.append(h)
+        return h
+
+    loop.create_task, loop.call_at = create_task, call_at
+
+    def undo():
+        del loop.create_task, loop.call_at
+    return counts, handles, undo
+
+
+def _run_gather_case(pool: str, case: str):
+    import errno
+
+    from ceph_tpu.devtools import schedule as sched
+    from ceph_tpu.osd.backend import (VERSION_XATTR, PGIntervalChanged,
+                                      _SubReadWave)
+    from ceph_tpu.osd.messages import MOSDECSubOpReadReply
+    from ceph_tpu.qa.cluster import make_sim_ctx
+    from ceph_tpu.store.objectstore import Transaction
+
+    n_osds, k, m, n_down = _GATHER_POOLS[pool]
+    old, new = b"o" * 6000, b"n" * 9000
+
+    async def main():
+        loop = asyncio.get_running_loop()
+        cl = Cluster(ctx_factory=make_sim_ctx)
+        admin = await cl.start(n_osds)
+        await admin.pool_create("g", pg_num=1, pool_type="erasure",
+                                k=k, m=m)
+        io = admin.open_ioctx("g")
+        await io.write_full("obj", old)
+        v_old = _pool_pg(cl, io, primary=True)[1].info.last_update
+        await io.write_full("obj", new)
+
+        def shard_pgs():
+            return sorted(((p.pgid.shard, o, p)
+                           for o in cl.osds.values()
+                           for p in o.pgs.values()
+                           if p.pool_id == io.pool_id
+                           and not p.is_primary()),
+                          key=lambda t: t[0])
+
+        for _ in range(n_down):
+            # a shard in the middle of the preference order goes down:
+            # its position reads NONE and the gather skips it
+            _s, dosd, _p = shard_pgs()[1]
+            await cl.kill_osd(dosd.whoami)
+            await cl.mark_down_and_wait(admin, dosd.whoami)
+        for _ in range(600):
+            posd, ppg = _pool_pg(cl, io, primary=True)
+            if ppg.state == "active" and not ppg.missing \
+                    and len(shard_pgs()) == k + m - 1 - n_down:
+                break
+            await asyncio.sleep(0.1)
+        assert await io.read("obj") == new
+        be = ppg.backend
+        want = be._auth_version("obj")
+        assert want == ppg.info.last_update.to_bytes()
+        shards = shard_pgs()
+        # the first wave asks the k-1 first candidates: the first of
+        # them is the one the case tampers with, the last candidate
+        # is the top-up
+        assert len(shards) >= k
+        t_shard, t_osd, t_pg = shards[0]
+        spare = shards[-1][0]
+        counts, handles, undo = _backend_calls(loop)
+        t0 = loop.time()
+        try:
+            if case == "healthy":
+                streams, attrs = await be._gather_shards(
+                    "obj", want_version=want)
+                assert sorted(streams) == \
+                    [ppg.pgid.shard] + [s for s, _o, _p in shards[:k - 1]]
+                assert attrs[VERSION_XATTR] == want
+                # one wave: no task, one timer, cancelled at the last
+                # reply's dispatch
+                assert counts == {"create_task": 0, "call_at": 1}
+                assert handles[0].cancelled()
+                assert loop.time() - t0 < 1.0
+            elif case == "silent_shard":
+                t_pg.backend._handle_ec_sub_read = lambda m: None
+                streams, _ = await be._gather_shards(
+                    "obj", want_version=want)
+                # dropped at the wave's deadline, the wave topped up
+                assert loop.time() - t0 >= 15.0
+                assert t_shard not in streams and spare in streams
+                assert len(streams) == k
+                assert counts == {"create_task": 0, "call_at": 2}
+            elif case == "eagain":
+                def refuse(m):
+                    t_osd.send_osd(int(m.src_name.id),
+                                   MOSDECSubOpReadReply(
+                                       t_pg.pgid, m.tid, t_shard,
+                                       -errno.EAGAIN, [b""], {}))
+                t_pg.backend._handle_ec_sub_read = refuse
+                streams, _ = await be._gather_shards(
+                    "obj", want_version=want)
+                assert loop.time() - t0 < 1.0
+                assert t_shard not in streams and spare in streams
+                assert len(streams) == k
+                assert counts == {"create_task": 0, "call_at": 2}
+            elif case == "interval_change":
+                t_pg.backend._handle_ec_sub_read = lambda m: None
+                g = asyncio.ensure_future(be._gather_shards(
+                    "obj", want_version=want))
+                for _ in range(50):
+                    await asyncio.sleep(0)
+                    if be._inflight:
+                        break
+                waves = {e[0] for e in be._inflight.values()}
+                assert len(waves) == 1 and all(
+                    isinstance(w, _SubReadWave) for w in waves)
+                await asyncio.sleep(0.5)     # the other replies land
+                assert len(be._inflight) == 1 and not g.done()
+                be.on_interval_change()
+                with pytest.raises(PGIntervalChanged):
+                    await g
+                assert handles and all(h.cancelled() for h in handles)
+                assert counts["create_task"] == 0
+            elif case == "mixed_generations":
+                # the first shard asked holds the OLD generation whole
+                # (bytes, length and version): the cohort check sees
+                # two, the second wave asks the rest, the newest
+                # consistent cohort serves
+                soid = t_pg.object_id("obj")
+                stale = bytes(be.codec.encode(
+                    set(range(be.n)), old)[t_shard])
+                attrs = dict(t_osd.store.getattrs(t_pg.cid, soid))
+                attrs[VERSION_XATTR] = v_old.to_bytes()
+                txn = Transaction()
+                txn.remove(t_pg.cid, soid)
+                txn.write(t_pg.cid, soid, 0, stale)
+                txn.setattrs(t_pg.cid, soid, attrs)
+                t_osd.store.apply_transaction(txn)
+                for want_version in (want, None):
+                    del handles[:]
+                    counts.update(create_task=0, call_at=0)
+                    streams, gattrs = await be._gather_shards(
+                        "obj", want_version=want_version)
+                    assert t_shard not in streams and spare in streams
+                    assert len(streams) == k
+                    assert gattrs[VERSION_XATTR] == want
+                    assert counts == {"create_task": 0, "call_at": 2}
+            else:
+                raise AssertionError(case)
+            assert be._inflight == {}
+            assert all(h.cancelled() or h.when() <= loop.time()
+                       for h in handles)
+        finally:
+            undo()
+            t_pg.backend.__dict__.pop("_handle_ec_sub_read", None)
+        if case != "mixed_generations":
+            assert await io.read("obj") == new
+        await cl.stop()
+
+    sched.run_deterministic(main, seed=35)
+
+
+def _pool_pg(cl, io, primary: bool):
+    return next((o, p) for o in cl.osds.values()
+                for p in o.pgs.values()
+                if p.pool_id == io.pool_id
+                and p.is_primary() == primary)
+
+
+@pytest.mark.parametrize("pool", sorted(_GATHER_POOLS))
+@pytest.mark.parametrize("case", ["healthy", "silent_shard", "eagain",
+                                  "interval_change",
+                                  "mixed_generations"])
+def test_ec_gather_wave_one_future_one_deadline(pool, case):
+    """An EC read's gather makes NO task and ONE timer per wave; a
+    shard that never answers is dropped at the wave's deadline and the
+    wave tops up; an -EAGAIN tops up at once; an interval change
+    mid-wave aborts the gather; the mixed-generation second wave picks
+    the newest consistent cohort; `_inflight` ends empty in all."""
+    _run_gather_case(pool, case)

@@ -278,15 +278,16 @@ def test_sharded_plane_perf_guards():
         await cl.write_burst(io, blobs, iodepth=16)
         win = cl.window_counters()
         enc = payload_mod.counters()
+        routers = [osd.messenger.shard_router
+                   for osd in cl.osds.values()]
+        for k, v in blobs.items():
+            assert await io.read(k) == v
+        # after the reads: the sub-reads' counters are among them
         sc = {}
         for osd in cl.osds.values():
             for k, v in osd.shards.counters().items():
                 if isinstance(v, (int, float)):
                     sc[k] = sc.get(k, 0) + v
-        routers = [osd.messenger.shard_router
-                   for osd in cl.osds.values()]
-        for k, v in blobs.items():
-            assert await io.read(k) == v
         await cl.stop()
         return win, enc, sc, routers
 
@@ -296,6 +297,7 @@ def test_sharded_plane_perf_guards():
     assert sc["handoff_ops"] > 0, sc
     assert sc["handoff_wakeups"] < sc["handoff_ops"], sc
     assert sc["subop_inline"] > 0, sc
+    assert sc["subread_inline"] > 0, sc
     assert all(r is not None for r in routers)
 
     # shards=1 compat pin: plane fully off, zero-encode still holds

@@ -190,23 +190,27 @@ def test_explorer_catches_boolean_backfill_marker():
 # ------------------------------ writes to one object pipeline (ISSUE 33)
 
 
-@pytest.mark.parametrize("pool_type", ["erasure", "replicated"])
-def test_two_writes_and_a_read_of_one_object_keep_their_order(pool_type):
+@pytest.mark.parametrize("pool_type,num_shards", [
+    ("erasure", 1), ("replicated", 1), ("erasure", 2)])
+def test_two_writes_and_a_read_of_one_object_keep_their_order(
+        pool_type, num_shards):
     """Two writes and a read of ONE object in the window at once, the
     second write pipelined behind the first's submit section: in every
     explored schedule the replies keep the per-object order, the read
     returns the write submitted before it, every copy ends as the last
-    write, the pglog is dense and the window's slots balance."""
+    write, the pglog is dense and the window's slots balance.  On two
+    shards the pumps apply the sub-writes and serve the read's
+    sub-read off their rings (ISSUE 35)."""
     from schedule_fixtures import run_two_writes_and_a_read
     pipelined = 0
     for seed in range(24):
-        rep, n = run_two_writes_and_a_read(seed, pool_type)
+        rep, n = run_two_writes_and_a_read(seed, pool_type, num_shards)
         assert rep.ok, rep.render()
         pipelined += n
     # the schedules explored the mechanism, not a serial chain
     assert pipelined > 0
-    r1, _ = run_two_writes_and_a_read(5, pool_type)
-    r2, _ = run_two_writes_and_a_read(5, pool_type)
+    r1, _ = run_two_writes_and_a_read(5, pool_type, num_shards)
+    r2, _ = run_two_writes_and_a_read(5, pool_type, num_shards)
     assert r1.trace_hash == r2.trace_hash and r1.steps == r2.steps
 
 

@@ -147,6 +147,8 @@ def test_sharded_inline_cluster_rw_and_home_shard_pinning():
         assert sc["handoff_wakeups"] < sc["handoff_ops"], sc
         # replica write sub-ops applied inline off the ring
         assert sc["subop_inline"] > 0, sc
+        # and the reads' sub-reads were served off the ring
+        assert sc["subread_inline"] > 0, sc
         # home-shard pinning: every PG's worker task lives on the loop
         # of shard_index(pgid) — the SHARD11 property, checked live
         for osd in cl.osds.values():
@@ -158,6 +160,143 @@ def test_sharded_inline_cluster_rw_and_home_shard_pinning():
         await cl.stop()
 
     asyncio.run(run())
+
+
+# ------------------------------------- sub-reads off the ring (ISSUE 35)
+
+def _pool_pgs(cl, io):
+    return [(o, p) for o in cl.osds.values() for p in o.pgs.values()
+            if p.pool_id == io.pool_id]
+
+
+async def _until(cond, tries=400):
+    for _ in range(tries):
+        if cond():
+            return
+        await asyncio.sleep(0.005)
+    assert cond()
+
+
+async def _sub_read_cluster_case(shards):
+    """A burst of EC writes and reads: (shard counters, sub-reads that
+    took a PG queue)."""
+    from ceph_tpu.osd.messages import MOSDECSubOpRead
+    from ceph_tpu.osd.pg import PG
+    queued = []
+    real = PG.queue_op
+
+    def queue_op(self, m):
+        if isinstance(m, MOSDECSubOpRead):
+            queued.append(m)
+        real(self, m)
+
+    PG.queue_op = queue_op
+    try:
+        cl = Cluster(ctx_factory=_ctx_factory(shards))
+        admin = await cl.start(4)
+        await _rw_burst(cl, admin)
+        sc = _sum_shard_counters(cl)
+        await cl.stop()
+    finally:
+        PG.queue_op = real
+    return sc, len(queued)
+
+
+async def _sub_read_behind_a_sub_write(case):
+    """One shard's sub-WRITE of an overwrite and a sub-READ of the same
+    object, held at the shard OSD's dispatch and then dispatched in one
+    step as `case` says.  Whatever path the read takes it answers
+    AFTER the write applied: the new shard bytes and version."""
+    from ceph_tpu.osd.backend import VERSION_XATTR
+    from ceph_tpu.osd.messages import (EVersion, MOSDECSubOpRead,
+                                       MOSDECSubOpWrite)
+    cl = Cluster(ctx_factory=_ctx_factory(4))
+    admin = await cl.start(3)
+    await admin.pool_create("sr", pg_num=1, pool_type="erasure",
+                            k=2, m=1)
+    io = admin.open_ioctx("sr")
+    old, new = b"a" * 5000, b"b" * 7000
+    await io.write_full("o", old)
+    posd, ppg = next((o, p) for o, p in _pool_pgs(cl, io)
+                     if p.is_primary())
+    sosd, spg = next((o, p) for o, p in _pool_pgs(cl, io)
+                     if not p.is_primary())
+    held, dispatch = [], sosd._dispatch_pg_msg
+
+    def hold(m):
+        if isinstance(m, (MOSDECSubOpWrite, MOSDECSubOpRead)) \
+                and m.pgid == spg.pgid:
+            held.append(m)
+        else:
+            dispatch(m)
+
+    sosd._dispatch_pg_msg = hold
+    write = asyncio.ensure_future(io.write_full("o", new))
+    await _until(lambda: len(held) == 1)
+    tid = posd.next_tid()
+    answer = asyncio.get_running_loop().create_future()
+    ppg.backend._inflight[tid] = ({sosd.whoami}, answer)
+    posd.send_osd(sosd.whoami,
+                  MOSDECSubOpRead(spg.pgid, tid, [("o", 0, -1)]))
+    await _until(lambda: len(held) == 2)
+    del sosd._dispatch_pg_msg
+    sub_write, sub_read = held
+    assert isinstance(sub_write, MOSDECSubOpWrite)
+    before = dict(sosd.shards.counters())
+    gate = asyncio.Event()
+    if case == "idle":
+        # nothing ahead: the write applies inline, the read is served
+        # off the ring behind it
+        dispatch(sub_write)
+    elif case == "queue_nonempty":
+        spg.queue_op(sub_write)     # as a refused fast path leaves it
+        assert not spg._op_queue.empty()
+    elif case == "worker_busy":
+        spg.queue_op(gate.wait)     # a work item the worker sits in
+        await _until(lambda: spg._worker_busy
+                     and spg._op_queue.empty())
+        dispatch(sub_write)
+    dispatch(sub_read)
+    after = dict(sosd.shards.counters())
+    gate.set()
+    reply = await asyncio.wait_for(answer, 10.0)
+    await asyncio.wait_for(write, 10.0)
+    assert reply.result == 0
+    assert bytes(reply.data[0]) == bytes(spg.backend.codec.encode(
+        set(range(spg.backend.n)), new)[spg.pgid.shard])
+    assert EVersion.from_bytes(reply.attrs[VERSION_XATTR]) == \
+        ppg.info.last_update == spg.info.last_update
+    assert await io.read("o") == new
+    await cl.stop()
+    return {k: after[k] - before[k] for k in
+            ("subread_inline", "subread_queued", "subop_inline")}
+
+
+@pytest.mark.parametrize("case", ["sharded_cluster", "one_shard", "idle",
+                                  "queue_nonempty", "worker_busy"])
+def test_sub_read_is_served_from_the_ring_or_takes_the_queue(case):
+    """The sharded plane serves an EC sub-read straight off the ring
+    under the sub-write's own rule (queue empty, worker idle);
+    osd_op_num_shards=1 keeps the classic queue path; a sub-read that
+    finds the queue non-empty or the worker busy is QUEUED, behind the
+    sub-write ahead of it."""
+    if case == "sharded_cluster":
+        sc, queued = asyncio.run(_sub_read_cluster_case(4))
+        assert sc["subread_inline"] > 0, sc
+        assert sc["subread_queued"] == queued, (sc, queued)
+        # 24 reads of k=2 m=2 objects: one remote shard asked each
+        assert sc["subread_inline"] + queued >= 24, (sc, queued)
+    elif case == "one_shard":
+        sc, queued = asyncio.run(_sub_read_cluster_case(1))
+        assert sc.get("subread_inline", 0) == 0, sc
+        assert sc.get("subread_queued", 0) == 0, sc
+        assert queued >= 24
+    else:
+        d = asyncio.run(_sub_read_behind_a_sub_write(case))
+        want = {"idle": (1, 0, 1), "queue_nonempty": (0, 1, 0),
+                "worker_busy": (0, 1, 0)}[case]
+        assert (d["subread_inline"], d["subread_queued"],
+                d["subop_inline"]) == want, d
 
 
 # ---------------------------------------------------------- e2e threaded
