@@ -1071,8 +1071,9 @@ class ECBackend(PGBackend):
             return out
         with tr.section("loop_ec_host"):
             mat = mat_for(present, missing)
-            src = np.stack([np.asarray(streams[i], np.uint8)
-                            for i in present])
+            # the survivors go as rows, views of the replies: the fold
+            # copies them on the ec-device thread, nothing stacks them
+            src = [np.asarray(streams[i], np.uint8) for i in present]
         # device-candidate:ec-decode@landed the live degraded-read/rebuild
         # decode call site: awaits the cross-PG collector
         # (LANE_BUCKETS-bucketed, executor dispatch) like encodes do
@@ -2083,8 +2084,11 @@ class ECBackend(PGBackend):
             # collector, so concurrent recovery-window reads fold
             # their decodes into single launches like writes do
             decoded = await self._decode_shards(range(self.k), streams)
+            # the read's one copy: the join reads the rows where they
+            # lie (reply bytes, decoded rows).  Items that are not
+            # exact bytes keep the join on the GIL: no hand-over
             with self.osd.ctx.tracer.section("loop_ec_host"):
-                data = b"".join(np.asarray(decoded[i]).tobytes()
+                data = b"".join(np.ascontiguousarray(decoded[i])
                                 for i in range(self.k))
         except (ErasureCodeError, ValueError):
             # ValueError: mixed-generation chunk lengths — undecodable
@@ -2210,11 +2214,12 @@ class ECBackend(PGBackend):
         # the digest xattr is PER SHARD: the rebuilt chunk gets its own,
         # never a copy of ours (scrub would flag it forever)
         from ceph_tpu.osd.scrub import CRC_XATTR
+        blob = rebuilt.tobytes()
         attrs = dict(attrs)
-        attrs[CRC_XATTR] = str(crc32c(rebuilt.tobytes())).encode()
+        attrs[CRC_XATTR] = str(crc32c(blob)).encode()
         msg = MPGPush(
             pg.pgid.with_shard(target), oid, pg.info.last_update,
-            rebuilt.tobytes(), attrs, {}, b"", self.osd.whoami)
+            blob, attrs, {}, b"", self.osd.whoami)
         msg.backfill_progress = progress
         ssb, clones = await self._rebuild_clones(oid, target, exclude)
         if ssb is not None:

@@ -35,6 +35,10 @@ Design:
     group, so that work is off the event loop too.  The rows still
     come back through `apply()`, carrying what the continuation made
     of them.
+  * A request's input is a [k, L] array (an encode's split data) or
+    k equal-length 1-D rows (a decode's survivors, views of the shard
+    replies): rows are copied once, into the folded batch on the
+    ec-device thread; only the host kernel stacks them.
   * Whether the queue launches on the device at all is decided ONCE,
     by `resolve_backend()`, before the OSD takes ops: "on" without an
     accelerator fails the start, and every later device->host reroute
@@ -46,7 +50,7 @@ from __future__ import annotations
 import asyncio
 import concurrent.futures
 import contextvars
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
@@ -63,13 +67,14 @@ def _bucket(n: int) -> int:
 
 
 class _Req:
-    __slots__ = ("key", "mat", "chunks", "finish", "fut", "t_apply",
-                 "t_done")
+    __slots__ = ("key", "mat", "chunks", "lanes", "finish", "fut",
+                 "t_apply", "t_done")
 
-    def __init__(self, key, mat, chunks, finish, fut, t_apply):
+    def __init__(self, key, mat, chunks, lanes, finish, fut, t_apply):
         self.key = key
         self.mat = mat
-        self.chunks = chunks        # [k, L] uint8
+        self.chunks = chunks        # [k, L] uint8, or k rows of L
+        self.lanes = lanes          # L
         self.finish = finish        # continuation or None
         self.fut = fut
         # op tracing (tracer stamps; 0.0 while tracing is off):
@@ -126,7 +131,9 @@ class ECBatchQueue:
                     "host_requests", "host_bytes", "device_fallbacks",
                     # continuations run on the ec-device thread / on
                     # the caller's thread (host kernel, fallback)
-                    "finish_thread", "finish_inline"):
+                    "finish_thread", "finish_inline",
+                    # requests handed in as rows, not as one array
+                    "row_requests"):
             self.perf.add_u64(key)
         self.perf.add_avg("batch_fill")    # requests per device launch
         # concurrent encodes parked in the collector at each arrival:
@@ -192,8 +199,14 @@ class ECBatchQueue:
 
     # ---------------------------------------------------------------- api
     async def apply(self, mat: np.ndarray,
-                    chunks: np.ndarray) -> np.ndarray:
+                    chunks: Union[np.ndarray, Sequence[np.ndarray]]
+                    ) -> np.ndarray:
         """out[r, L] = mat @ chunks over GF(2^8), batched across callers.
+
+        `chunks` is a [k, L] array, or a sequence of k equal-length 1-D
+        uint8 rows, which are read where they lie: the device path
+        copies each into its slice of the folded batch, and only the
+        host kernel stacks them.
 
         Single awaitable entry for PG backends; takes the native host
         kernel when the device isn't worth it (small lone request) or
@@ -201,8 +214,13 @@ class ECBatchQueue:
         accelerator)."""
         tr = self.ctx.tracer
         t_apply = tr.stamp()
-        chunks = np.ascontiguousarray(chunks, np.uint8)
-        nbytes = chunks.shape[0] * chunks.shape[1]
+        if isinstance(chunks, np.ndarray):
+            chunks = np.ascontiguousarray(chunks, np.uint8)
+            lanes = chunks.shape[1]
+        else:
+            self.perf.inc("row_requests")
+            lanes = len(chunks[0])
+        nbytes = len(chunks) * lanes
         if (not self.resolve_backend()
                 or (nbytes < self.min_device_bytes
                     and not self._pending)):
@@ -215,7 +233,7 @@ class ECBatchQueue:
         await self._pending_throttle.get(nbytes)
         fut = loop.create_future()
         req = _Req((mat.shape, mat.tobytes()),
-                   np.ascontiguousarray(mat, np.uint8), chunks,
+                   np.ascontiguousarray(mat, np.uint8), chunks, lanes,
                    _FINISH.get(), fut, t_apply)
         self._pending.append(req)
         self._pending_bytes += nbytes
@@ -238,6 +256,8 @@ class ECBatchQueue:
         self.perf.inc("host_bytes", nbytes)
         from ceph_tpu.common import devstats
         devstats.note_bytes("ec_apply", nbytes, device=False)
+        if not isinstance(chunks, np.ndarray):
+            chunks = np.stack(chunks)
         from ceph_tpu import native
         if native.available():
             return native.gf_matrix_apply(mat, chunks)
@@ -331,7 +351,7 @@ class ECBatchQueue:
                     for r in reqs:
                         if not r.fut.done():
                             try:
-                                nb = r.chunks.shape[0] * r.chunks.shape[1]
+                                nb = len(r.chunks) * r.lanes
                                 r.fut.set_result(
                                     self._host_apply(r.mat, r.chunks, nb))
                             except Exception as e2:
@@ -361,15 +381,24 @@ class ECBatchQueue:
             for r in reqs:
                 tr.interval("seam_pending", r.t_apply)
         mat = reqs[0].mat
-        lens = [r.chunks.shape[1] for r in reqs]
+        lens = [r.lanes for r in reqs]
         total = sum(lens)
-        k = reqs[0].chunks.shape[0]
+        k = len(reqs[0].chunks)
         with tr.section("seam_fold"):
             folded = np.zeros((k, total), np.uint8)
+            flat = memoryview(folded.reshape(-1))
             off = 0
             for r in reqs:
-                folded[:, off:off + r.chunks.shape[1]] = r.chunks
-                off += r.chunks.shape[1]
+                end = off + r.lanes
+                if isinstance(r.chunks, np.ndarray):
+                    folded[:, off:end] = r.chunks
+                else:
+                    # a memoryview copy keeps the GIL: a numpy copy per
+                    # row would give it up, and wait to win it back
+                    # from the busy loop, once per row
+                    for i, row in enumerate(r.chunks):
+                        flat[i * total + off:i * total + end] = row
+                off = end
         ap = matrix_apply(mat)
         cap = LANE_BUCKETS[-1]
         # device-candidate:ec-dispatch@landed the live executor-side launch:

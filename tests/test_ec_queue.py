@@ -238,6 +238,70 @@ def test_device_failure_falls_back_to_host(monkeypatch):
     asyncio.run(run())
 
 
+# ------------------------------------------------- requests as rows
+
+def _rows_of(arr):
+    """k read-only 1-D rows, each a view of its own bytes: a decode's
+    survivors as the shard replies bring them."""
+    return [np.frombuffer(row.tobytes(), np.uint8) for row in arr]
+
+
+@pytest.mark.parametrize("grouping", ["lone", "mixed_group"])
+@pytest.mark.parametrize("mode", ["force", "off"])
+def test_rows_request_equals_the_stacked_array(mode, grouping):
+    """apply(mat, rows) is apply(mat, np.stack(rows)) byte for byte, on
+    the device path and on the host kernel, alone or in one group with
+    an array request under the same matrix; only the rows request is
+    counted as one."""
+    async def run():
+        q = make_queue(mode=mode, min_device_bytes=256)
+        mat = gen_mat()
+        rng = np.random.default_rng(31)
+        src = rng.integers(0, 256, (4, 5000), dtype=np.uint8)
+        rows = _rows_of(src)
+        if grouping == "lone":
+            got = await q.apply(mat, rows)
+        else:
+            other = rng.integers(0, 256, (4, 3000), dtype=np.uint8)
+            got, got_other = await asyncio.gather(q.apply(mat, rows),
+                                                  q.apply(mat, other))
+            assert np.array_equal(got_other, gf256.host_apply(mat, other))
+        assert np.array_equal(got, await q.apply(mat, np.stack(rows)))
+        assert np.array_equal(got, gf256.host_apply(mat, src))
+        d = q.perf.dump()
+        assert d["row_requests"] == 1
+        assert d["device_fallbacks"] == 0
+        if mode == "force":
+            assert d["host_bytes"] == 0
+            assert d["device_launches"] == 2
+            assert d["device_requests"] == (2 if grouping == "lone" else 3)
+        else:
+            assert d["device_requests"] == 0
+        await q.stop()
+    asyncio.run(run())
+
+
+def test_rows_request_after_a_device_failure_reroutes_whole(monkeypatch):
+    """The device-failure fallback stacks a rows request for the host
+    kernel: the same bytes come back, and the reroute is counted."""
+    async def run():
+        q = make_queue(min_device_bytes=256)
+
+        def boom(reqs):
+            raise RuntimeError("device gone")
+        monkeypatch.setattr(q, "_run_group", boom)
+        mat = gen_mat()
+        src = np.random.default_rng(32).integers(0, 256, (4, 1 << 15),
+                                                 dtype=np.uint8)
+        out = await q.apply(mat, _rows_of(src))
+        assert np.array_equal(out, gf256.host_apply(mat, src))
+        d = q.perf.dump()
+        assert d["device_fallbacks"] == 1 and d["row_requests"] == 1
+        assert d["host_bytes"] == 4 * (1 << 15)
+        await q.stop()
+    asyncio.run(run())
+
+
 # ------------------------------------------------------ continuations
 
 def _digest_on(seen):
@@ -449,6 +513,18 @@ def _seam_sums(q):
 @pytest.mark.parametrize("last", ["seam_split", "seam_finish"])
 @pytest.mark.parametrize("grouping", ["own_groups", "one_group"])
 def test_seam_stages_tile_the_apply_await(grouping, last):
+    _check_seam_stages_tile(grouping, last, as_rows=False)
+
+
+@pytest.mark.parametrize("last", ["seam_split", "seam_finish"])
+@pytest.mark.parametrize("grouping", ["own_groups", "one_group"])
+def test_seam_stages_tile_the_apply_await_of_rows(grouping, last):
+    """The same account for requests handed in as rows (a decode's
+    survivors): the fold copies them, and the stages still tile."""
+    _check_seam_stages_tile(grouping, last, as_rows=True)
+
+
+def _check_seam_stages_tile(grouping, last, as_rows):
     """With op_tracing on, a device request's trip is tiled by
     seam_pending (enqueue -> the executor takes its group), the five
     sections on the ec-device thread, and seam_resume (the executor's
@@ -479,11 +555,12 @@ def test_seam_stages_tile_the_apply_await(grouping, last):
             mats = [gen_mat()] * n
         ins = [rng.integers(0, 256, (4, 1 << 18), dtype=np.uint8)
                for _ in range(n)]
+        given = [_rows_of(c) for c in ins] if as_rows else ins
 
         async def burst():
             outs = await asyncio.gather(
                 *[q.apply_then(m, c, finish) if last == "seam_finish"
-                  else q.apply(m, c) for m, c in zip(mats, ins)])
+                  else q.apply(m, c) for m, c in zip(mats, given)])
             for m, c, o in zip(mats, ins, outs):
                 assert np.array_equal(o, gf256.host_apply(m, c))
 
@@ -506,6 +583,7 @@ def test_seam_stages_tile_the_apply_await(grouping, last):
             + weight * sum(d[name][1] for name in sections)
         total = d["seam_apply"][1]
         assert abs(tiled - total) <= 0.10 * total, (tiled, total, d)
+        assert q.perf.dump()["row_requests"] == (2 * n if as_rows else 0)
         await q.stop()
     asyncio.run(run())
 
