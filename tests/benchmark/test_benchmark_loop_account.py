@@ -1,24 +1,20 @@
 """The per-layer metrics that read the loop's time account and the two
-stages PR 34 named (common/tracer.py: the loop sampler's `evloop_idle` /
-`evloop_poll` beside `loop_wall` / `loop_cpu`, the interval
-`read_gather`, the chain stage `reply_wait`; benchmark/loop_account.py
-and the `osd.loop_idle_share`, `osd.loop_offcore_share`,
-`osd.loop_poll_ms`, `ec.read_gather_ms`, `osd.reply_wait_ms` readers
-under benchmark/metrics/): each reader's arithmetic on a hand-made
-observation, what it does on a program without the stage (a parent
-commit: None, never 0, never an exception), the entries a `benchmark`
-PR has to declare for them, and traced toy cells on the CPU in which
-every one of them finds something to read, the hops and the read path
-record under their names, and the three shares of the loop's wall sum
-to 100.
+stages the tracer names beside it (common/tracer.py: the loop sampler's
+`evloop_idle` / `evloop_poll` beside `loop_wall` / `loop_cpu`, the
+interval `read_gather`, the chain stage `reply_wait`;
+benchmark/loop_account.py and the `osd.loop_idle_share`,
+`osd.loop_offcore_share`, `osd.loop_poll_ms`, `ec.read_gather_ms`,
+`osd.reply_wait_ms` readers under benchmark/metrics/): each reader's
+arithmetic on a hand-made observation, what it does on a program without
+the stage (a parent commit: None, never 0, never an exception), the
+nine entries BENCHMARK.json declares for them, and traced toy cells on
+the CPU in which every one of them finds something to read, the hops
+and the read path record under their names, and the three shares of
+the loop's wall sum to 100.
 
-The entries are NOT in BENCHMARK.json yet: the mirror tests of this
-directory pin each bound cell's per-layer list to its toy manifest and
-BENCHMARK.json's last two entries, and PR 34, no `benchmark` PR, may
-edit no file the benchmark has.  They wait, as data, in
-benchmark/pending/per_layer_pr34.json.  The toy manifest here is built
-in a tmp dir from the tests' own one plus those entries; no file of the
-benchmark is edited.  Nothing here is a number about speed."""
+The toy manifest here is built in a tmp dir from the tests' own one
+plus those entries; no file of the benchmark is edited.  Nothing here
+is a number about speed."""
 
 import json
 import pathlib
@@ -31,9 +27,6 @@ from test_benchmark_rehearsal import TOY, run_toy
 
 REPO = pathlib.Path(__file__).resolve().parents[2]
 REAL = manifest.Manifest()
-PENDING = {m["name"]: m for m in json.loads(
-    (REPO / "benchmark" / "pending" / "per_layer_pr34.json").read_text())[
-        "per_layer"]}
 LOOP_BASES = ("osd.loop_idle_share", "osd.loop_offcore_share",
               "osd.loop_poll_ms")
 NEW = [f"{b}.{sfx}" for b in LOOP_BASES + ("ec.read_gather_ms",)
@@ -41,6 +34,17 @@ NEW = [f"{b}.{sfx}" for b in LOOP_BASES + ("ec.read_gather_ms",)
 READ_CELLS = {"goodput": ["rb_seq_degraded_4m_qd16"],
               "op_rate": ["cos_mix_64k_w8", "cos_mix_64k_w8_open",
                           "ycsb_a_1k_zipf"]}
+#: the cells each of the nine named while it waited, undeclared, as data
+#: under benchmark/pending/; a cell appended since follows them
+WAITED_CELLS = {
+    **{f"{b}.{sfx}": cells for b in LOOP_BASES for sfx, cells in (
+        ("goodput", ["rb_write_4m_qd16", "rb_seq_degraded_4m_qd16",
+                     "rb_write_4m_qd16_blockstore"]),
+        ("op_rate", ["cos_mix_64k_w8", "cos_write_64k_w64",
+                     "cos_mix_64k_w8_open", "ycsb_a_1k_zipf"]))},
+    **{f"ec.read_gather_ms.{sfx}": cells
+       for sfx, cells in READ_CELLS.items()},
+    "osd.reply_wait_ms.op_rate": ["ycsb_a_1k_zipf"]}
 #: the toy cells that stand for them
 TOY_CELLS = {"osd.loop": {"goodput": ["toy_write", "toy_seq_degraded"],
                           "op_rate": ["toy_mix"]},
@@ -121,52 +125,69 @@ def test_reply_wait_is_a_plain_zero_when_no_op_waited():
         pytest.approx(20.0)
 
 
-@pytest.mark.parametrize("name", NEW)
-def test_pending_entry_names_the_cells_the_metric_is_for(name):
-    spec = PENDING[name]
+def check_entry(man, name):
+    """The entry `man` declares for `name` names every cell of its
+    suffix (a read metric: every one whose mix reads) and no other."""
+    spec = man.per_layer[name]
     assert set(spec) == {"name", "unit", "better", "source", "layer",
                          "moves", "workloads"}
     assert manifest.NAME_RE.match(name) and manifest.UNIT_RE.match(
         spec["unit"])
-    assert REAL.find(f"metrics/{name}.py").is_file()
+    assert man.find(f"metrics/{name}.py").is_file()
     base, sfx = name.rsplit(".", 1)
     assert spec["moves"] == sfx and spec["source"] == "program_span"
     assert spec["better"] == "lower"
     assert spec["unit"] == ("%" if base.endswith("_share") else "ms")
     assert spec["layer"] == ("EC backend" if base.startswith("ec.")
                              else "OSD / PG")
-    of_suffix = [w["name"] for w in REAL.doc["workloads"]
+    of_suffix = [w["name"] for w in man.doc["workloads"]
                  if sfx in {m["name"] for m in
-                            REAL.metrics_of(w["name"], "end_to_end")}]
+                            man.metrics_of(w["name"], "end_to_end")}]
     if base in LOOP_BASES:
         assert spec["workloads"] == of_suffix
     elif base == "ec.read_gather_ms":
         # the cells that read (cos_write_64k_w64 does not)
-        assert spec["workloads"] == READ_CELLS[sfx]
+        assert spec["workloads"] == [
+            w for w in of_suffix
+            if man.traffic(man.workloads[w]["traffic"])["read_ratio"] > 0]
     else:
         assert spec["workloads"] == ["ycsb_a_1k_zipf"]
 
 
-def test_pending_entries_are_the_nine_and_none_is_declared_yet():
-    """What stands between them and BENCHMARK.json is under the
-    benchmark's own paths: a `benchmark` PR's to change."""
-    assert sorted(PENDING) == sorted(NEW)
-    assert not set(PENDING) & set(REAL.per_layer)
-    assert [m["name"] for m in REAL.doc["per_layer"][-2:]] == [
-        "osd.dep_wait_ms.op_rate", "osd.admit_wait_ms.op_rate"]
-    layers = {m["layer"] for m in REAL.doc["per_layer"]}
-    assert {m["layer"] for m in PENDING.values()} <= layers
-    moved = {m["name"] for m in REAL.doc["end_to_end"]}
-    for spec in PENDING.values():
-        assert spec["moves"] in moved
-        assert set(spec["workloads"]) <= set(REAL.workloads)
+def check_the_nine_are_declared(man):
+    """Each of the nine once, as it waited: its layer, what it moves,
+    and the cells it named first in its list."""
+    names = [m["name"] for m in man.doc["per_layer"]]
+    layers = {m["layer"] for m in man.doc["per_layer"]
+              if m["name"] not in NEW}
+    moved = {m["name"] for m in man.doc["end_to_end"]}
+    for name in NEW:
+        assert names.count(name) == 1, name
+        spec = man.per_layer[name]
+        base, sfx = name.rsplit(".", 1)
+        assert spec["moves"] == sfx and spec["moves"] in moved
+        assert spec["layer"] == ("EC backend" if base.startswith("ec.")
+                                 else "OSD / PG")
+        assert spec["layer"] in layers
+        cells = WAITED_CELLS[name]
+        assert spec["workloads"][:len(cells)] == cells, name
+        assert set(spec["workloads"]) <= set(man.workloads)
+
+
+@pytest.mark.parametrize("name", NEW)
+def test_pending_entry_names_the_cells_the_metric_is_for(name):
+    check_entry(REAL, name)
+
+
+def test_the_nine_are_declared_once_each_as_they_waited():
+    check_the_nine_are_declared(REAL)
 
 
 @pytest.fixture(scope="module")
 def toy_with_account(tmp_path_factory):
     doc = json.loads(TOY.read_text())
     for name in NEW:
-        spec = dict(PENDING[name])
+        spec = dict(REAL.per_layer[name])
         base = base_of(name)
         cells = TOY_CELLS["osd.loop" if base in LOOP_BASES else base]
         spec["workloads"] = cells[spec["moves"]]

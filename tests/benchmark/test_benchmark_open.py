@@ -107,8 +107,9 @@ def test_the_mix_and_the_configuration_hold_what_the_cell_is_defined_by():
     assert len(cfg["source"]) <= 200 and cfg["assumed"]
 
 
-def test_toy_manifest_mirrors_the_cells_entries():
-    real, toy = manifest.Manifest(), manifest.Manifest(path=TOY)
+def check_mirror(real):
+    """The toy cell reports what `real`'s cell reports."""
+    toy = manifest.Manifest(path=TOY)
     for section in ("end_to_end", "per_layer"):
         assert [m["name"] for m in toy.metrics_of(CELL, section)] == [
             m["name"] for m in real.metrics_of(REAL, section)]
@@ -120,6 +121,10 @@ def test_toy_manifest_mirrors_the_cells_entries():
     # run's tails are on its `diag` line
     assert [m["name"] for m in real.metrics_of(REAL, "end_to_end")] == [
         "op_rate", "setup_s"]
+
+
+def test_toy_manifest_mirrors_the_cells_entries():
+    check_mirror(manifest.Manifest())
 
 
 # ------------------------------------------------- schedule, names, model

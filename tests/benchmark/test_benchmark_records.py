@@ -24,7 +24,10 @@ REPO = pathlib.Path(__file__).resolve().parents[2]
 TOY = REPO / "tests" / "benchmark" / "toy" / "manifest_records.json"
 CELL = "toy_records"
 REAL, REAL_CONTROL = "ycsb_a_1k_zipf", "cos_mix_64k_w8"
-NEW_METRICS = {"osd.dep_wait_ms.op_rate", "osd.admit_wait_ms.op_rate"}
+#: the two stages of the cell that lie inside `osd.queue_ms`, and the
+#: wait to reply in order, which was cut out of the first of them
+QUEUE_METRICS = {"osd.dep_wait_ms.op_rate", "osd.admit_wait_ms.op_rate"}
+NEW_METRICS = QUEUE_METRICS | {"osd.reply_wait_ms.op_rate"}
 LIMIT_0 = ("write_order_violations", "read_order_violations",
            "read_length_mismatch", "readback_mismatch", "shard_mismatch",
            "host_bytes", "device_fallbacks", "device_bytes_short",
@@ -135,8 +138,11 @@ def test_the_configuration_states_its_guarantees_cuts_and_settings():
     assert cdf[99] == pytest.approx(0.459, abs=1e-3)
 
 
-def test_toy_manifest_mirrors_the_cells_entries():
-    real, toy = manifest.Manifest(), manifest.Manifest(path=TOY)
+def check_mirror(real):
+    """The toy cell reports what `real`'s cell reports, and the cell, its
+    configuration and its own metrics are declared, found by name
+    wherever in their lists they sit."""
+    toy = manifest.Manifest(path=TOY)
     for section in ("end_to_end", "per_layer"):
         assert [m["name"] for m in toy.metrics_of(CELL, section)] == [
             m["name"] for m in real.metrics_of(REAL, section)]
@@ -150,10 +156,13 @@ def test_toy_manifest_mirrors_the_cells_entries():
             "name": name, "unit": "ms", "better": "lower",
             "source": "program_span", "layer": "OSD / PG",
             "moves": "op_rate", "workloads": [REAL]}
-    assert real.doc["configs"][-1]["name"] == "ycsb_a_1k_ec_k2m1"
-    assert real.doc["workloads"][-1]["name"] == REAL
-    assert [m["name"] for m in real.doc["per_layer"][-2:]] == [
-        "osd.dep_wait_ms.op_rate", "osd.admit_wait_ms.op_rate"]
+    assert "ycsb_a_1k_ec_k2m1" in real.configs
+    cell = real.workloads[REAL]
+    assert (cell["config"], cell["traffic"]) == ("ycsb_a_1k_ec_k2m1", REAL)
+
+
+def test_toy_manifest_mirrors_the_cells_entries():
+    check_mirror(manifest.Manifest())
 
 
 def test_kind_and_reference_import_nothing_of_the_program_to_compare():
@@ -361,9 +370,11 @@ def test_traced_rehearsal_reports_the_two_new_per_layer_metrics(
     assert got == declared - silent and NEW_METRICS <= got
     for name in NEW_METRICS:
         assert result["metrics"][name]["unit"] == "ms"
+        assert result["metrics"][name]["value"] >= 0
+    for name in QUEUE_METRICS:
         assert result["metrics"][name]["value"] > 0
     # both lie inside osd.queue_ms
-    assert sum(result["metrics"][n]["value"] for n in NEW_METRICS) <= \
+    assert sum(result["metrics"][n]["value"] for n in QUEUE_METRICS) <= \
         result["metrics"]["osd.queue_ms.op_rate"]["value"] + 1e-9
     assert result["metrics"]["seam.device_byte_fraction.op_rate"][
         "value"] == 100.0
@@ -372,12 +383,18 @@ def test_traced_rehearsal_reports_the_two_new_per_layer_metrics(
 def test_new_readers_read_their_stage_alone_or_nothing(toy):
     obs = SimpleNamespace(ops=100, stages={
         "dep_wait": (40, 0.5), "admit_wait": (100, 0.2),
-        "queue_wait_pump": (100, 1.0)})
+        "queue_wait_pump": (100, 1.0), "reply_wait": (10, 0.3)})
     assert toy.reader("osd.dep_wait_ms.op_rate")(obs) == pytest.approx(5.0)
     assert toy.reader("osd.admit_wait_ms.op_rate")(obs) == \
         pytest.approx(2.0)
-    for name in NEW_METRICS:
+    assert toy.reader("osd.reply_wait_ms.op_rate")(obs) == \
+        pytest.approx(3.0)
+    for name in QUEUE_METRICS:
         assert toy.reader(name)(SimpleNamespace(ops=100, stages={})) is None
+    # no op waited to reply: a plain 0, not nothing
+    assert toy.reader("osd.reply_wait_ms.op_rate")(
+        SimpleNamespace(ops=100, stages={})) == 0.0
+    for name in NEW_METRICS:
         assert toy.reader(name)(SimpleNamespace(ops=0, stages={})) is None
 
 

@@ -58,10 +58,10 @@ def run_toy(man, tmp_path, *, trace=False, seed=2_600_000_011):
     return result, json.loads(lines[-2][len("diag "):]), err.getvalue()
 
 
-def test_toy_manifest_mirrors_the_cells_entries():
-    """The toy cell reports what the real cell reports, under the real
+def check_mirror(real):
+    """The toy cell reports what `real`'s cell reports, under the real
     readers: the same end-to-end metrics and the same per-layer set."""
-    real, toy = manifest.Manifest(), manifest.Manifest(path=TOY)
+    toy = manifest.Manifest(path=TOY)
     cell = "rb_write_4m_qd16_blockstore"
     for section in ("end_to_end", "per_layer"):
         assert [m["name"] for m in toy.metrics_of(CELL, section)] == [
@@ -90,6 +90,10 @@ def test_toy_manifest_mirrors_the_cells_entries():
     assert kind.store_dir("/abs/dir") == "/abs/dir"
     assert cfg["options"]["objectstore"] == cfg["objectstore"] \
         == "blockstore"
+
+
+def test_toy_manifest_mirrors_the_cells_entries():
+    check_mirror(manifest.Manifest())
 
 
 def test_rehearsal_is_correct_and_prints_the_new_numbers(tmp_path):

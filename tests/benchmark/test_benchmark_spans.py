@@ -95,20 +95,26 @@ def test_named_share_leaves_the_samplers_stages_out_and_needs_both():
     assert spans.loop_cpu_share(no_sampler) is None
 
 
-@pytest.mark.parametrize("name", NEW)
-def test_new_metric_is_declared_for_the_cells_of_its_suffix(name):
-    spec = REAL.per_layer[name]
+def check_entry(man, name):
+    """The entry `man` declares for `name` names every cell of its
+    suffix and no other."""
+    spec = man.per_layer[name]
     base, sfx = name.rsplit(".", 1)
     assert spec["moves"] == sfx and spec["source"] == "program_span"
     assert spec["workloads"] == [
-        w["name"] for w in REAL.doc["workloads"]
+        w["name"] for w in man.doc["workloads"]
         if sfx in {m["name"] for m in
-                   REAL.metrics_of(w["name"], "end_to_end")}]
+                   man.metrics_of(w["name"], "end_to_end")}]
     assert spec["layer"] == {"seam": "Device seam", "ec": "EC backend",
                              "osd": "OSD / PG"}[base.split(".")[0]]
     assert spec["unit"] == ("%" if base.endswith("_share") else "ms")
     assert spec["better"] == ("higher" if base == "osd.loop_named_share"
                               else "lower")
+
+
+@pytest.mark.parametrize("name", NEW)
+def test_new_metric_is_declared_for_the_cells_of_its_suffix(name):
+    check_entry(REAL, name)
 
 
 @pytest.fixture(scope="module")
