@@ -139,10 +139,11 @@ QUEUE_WAIT_CAUSES = (
 SEAM_STAGES = (
     "seam_apply",       # interval: the whole apply() await
     "seam_pending",     # interval: apply() entry -> executor takes the group
-    "seam_fold",        # section: zero + concatenate into the folded batch
-    "seam_h2d",         # section: jax.device_put of the folded batch
-    "seam_launch",      # section: slice / pad / device_call / concatenate
-    "seam_d2h",         # section: np.asarray of the device result
+    "seam_fold",        # section: the group into bucket-wide windows
+    "seam_h2d",         # section: jax.device_put of one window
+    "seam_launch",      # section: device_call on one staged window
+    "seam_window",      # interval: one window's seam_h2d + seam_launch
+    "seam_d2h",         # section: np.asarray of every window's result
     "seam_split",       # section: result copies (plain apply() requests)
     "seam_finish",      # section: the requests' continuations (apply_then)
     "seam_resume",      # interval: executor done -> awaiter runs again

@@ -14,10 +14,14 @@ Design:
     generator matrix CONCATENATE along the lane axis regardless of their
     individual lengths: one [k, ΣL] device launch encodes stripes from
     many PGs (and many objects) at once.
-  * The folded batch pads up to a fixed lane-bucket so the jit cache
-    stays bounded; the device call (fused pallas kernel on TPU, XLA
-    elsewhere — ec/kernel.py) runs in a single-thread executor so the
-    event loop never blocks on the device.
+  * The folded batch is cut on the HOST into launch windows, each a
+    [k, b] buffer with b in LANE_BUCKETS (the tail padded there),
+    and each window is staged, launched and fetched whole: no device
+    op ever takes the group's own width, so the compiled programs are
+    one per (matrix shape, lane bucket) whatever mix of sizes arrives.
+    The device call (fused pallas kernel on TPU, XLA elsewhere —
+    ec/kernel.py) runs in a single-thread executor so the event loop
+    never blocks on the device.
   * Small lone requests take the native host kernel (GFNI/AVX-512)
     instead: a window plus a device dispatch costs more latency than
     encoding 64 KiB on the CPU.  Everything is counted in perf
@@ -54,9 +58,16 @@ from typing import Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
-#: folded-lane padding buckets: at most this many compiled shapes per
-#: generator matrix (largest bucket repeats for oversize batches)
+#: launch-window widths: at most this many compiled shapes per
+#: generator matrix shape (the largest repeats for oversize groups)
 LANE_BUCKETS = (1 << 14, 1 << 16, 1 << 18, 1 << 20, 1 << 22)
+
+#: host bytes of launch windows one queue keeps from group to group
+#: (`ECBatchQueue._window_buffers`): the eight [2, 2^22] windows of a
+#: 64 MiB k=2 write, or the four [4, 2^22] of sixteen 4 MiB k=4 ones,
+#: beside one window of each smaller bucket; a wider group's other
+#: windows are made for it alone
+WINDOW_POOL_BYTES = 72 << 20
 
 
 def _bucket(n: int) -> int:
@@ -64,6 +75,20 @@ def _bucket(n: int) -> int:
         if n <= b:
             return b
     return LANE_BUCKETS[-1]
+
+
+def _windows(total: int) -> List[int]:
+    """The widths, in lanes, of the launch windows of a group of
+    `total` lanes, in fold order: the largest bucket while the rest
+    overfills it, then the bucket that holds the rest.  Every window
+    costs the ec-device thread a transfer each way and a launch, each a
+    hand-over of the GIL beside the busy loop, so a tail is padded up
+    to its bucket rather than split into more windows."""
+    if total <= 0:
+        return []
+    top = LANE_BUCKETS[-1]
+    full, rest = divmod(total - 1, top)
+    return [top] * full + [_bucket(rest + 1)]
 
 
 class _Req:
@@ -126,8 +151,9 @@ class ECBatchQueue:
         self.perf = ctx.perf.create("ec_batch_queue")
         for key in ("device_launches", "device_requests", "device_bytes",
                     # the bucketed lanes of every device_call: what
-                    # was launched, against device_bytes / k asked for
-                    "device_lanes_launched",
+                    # was launched, against device_bytes / k asked for,
+                    # and the difference, the lanes padded on the host
+                    "device_lanes_launched", "device_pad_lanes",
                     "host_requests", "host_bytes", "device_fallbacks",
                     # continuations run on the ec-device thread / on
                     # the caller's thread (host kernel, fallback)
@@ -143,6 +169,10 @@ class ECBatchQueue:
         # each PG held one op in flight)
         self.perf.add_avg("pending_depth")
         self._device_ok: Optional[bool] = None
+        # the launch windows' host buffers, per (rows, lanes), kept from
+        # group to group (`_window_buffers`), and their bytes
+        self._window_bufs: Dict[tuple, List[np.ndarray]] = {}
+        self._window_bytes = 0
 
     # ------------------------------------------------------------- policy
     def resolve_backend(self) -> bool:
@@ -269,9 +299,10 @@ class ECBatchQueue:
         """`finish(chunks, rows)` of one apply(): what the caller would
         do first with the rows, made where the rows are made.  For a
         device group that is the ec-device thread (section
-        `seam_finish`): `rows` is then a view of the group's whole
-        fetched batch, so `finish` copies what it keeps, writes to
-        neither argument, and what it raises fails this request alone.
+        `seam_finish`): `rows` is then a view of a fetched window's
+        result (its pieces joined, where the request ran over windows),
+        so `finish` copies what it keeps, writes to neither argument,
+        and what it raises fails this request alone.
 
         The rows come back through apply() all the same, so whatever
         stands in front of it sees them, and only the very rows the
@@ -357,22 +388,45 @@ class ECBatchQueue:
                             except Exception as e2:
                                 r.fut.set_exception(e2)
 
+    def _window_buffers(self, k: int, widths: List[int]) -> list:
+        """Host buffers for a group's launch windows, [k, b] each, kept
+        and reused by later groups: a fresh buffer of a large bucket
+        costs its page faults on every group, beside a busy loop and
+        threads that write to disk.  Reuse is safe because only this
+        executor thread fills them and a group's fetch waits for its
+        kernels, so for their input transfers, before the next group is
+        folded.  The queue keeps at most `WINDOW_POOL_BYTES` of them,
+        the first made; a window past that is made for its group alone
+        and dropped with it.  `_fold` zeroes the lanes a reused buffer
+        holds past the group's last."""
+        out, taken = [], {}
+        for b in widths:
+            pool = self._window_bufs.setdefault((k, b), [])
+            i = taken[k, b] = taken.get((k, b), -1) + 1
+            if i < len(pool):
+                out.append(pool[i])
+                continue
+            buf = np.zeros((k, b), np.uint8)
+            if self._window_bytes + buf.nbytes <= WINDOW_POOL_BYTES:
+                pool.append(buf)
+                self._window_bytes += buf.nbytes
+            out.append(buf)
+        return out
+
     def _run_group(self, reqs: List[_Req]) -> list:
         """Executor thread: device launches for all requests sharing a
-        generator matrix, folded along the lane axis.  Batches beyond
-        the largest lane bucket split into bucket-sized windows, so
-        compiled shapes stay bounded at any batch size.
+        generator matrix, folded along the lane axis.
 
-        The whole group stays ON the device between windows: the
-        folded batch is staged once (declared ``device_put``), each
-        bucket window runs ``device_call`` on a device slice, and the
-        results come home in ONE declared fetch — the old shape paid
-        a full ``np.asarray`` round-trip per bucket window
-        (``MatrixApply.__call__``'s unconditional materialize, the
-        SYNC15 live-tree finding), serializing d2h transfers between
-        launches the device could have overlapped."""
+        The fold goes straight into the launch windows (`_windows`):
+        host buffers of [k, b] lanes, b a lane bucket, kept from group
+        to group (`_window_buffers`).  Each window is staged (declared
+        ``device_put``) and launched as it stands, and the results come
+        home in one declared fetch region, a window at a time, and are
+        split into the requests' rows on the host.  No device op here takes the
+        group's own width or an offset into it, so every program that
+        runs is one of at most ``len(LANE_BUCKETS)`` per matrix shape,
+        compiled once, whatever widths the groups have."""
         import jax
-        import jax.numpy as jnp
         from ceph_tpu.ec.kernel import matrix_apply
         tr = self.ctx.tracer
         if tr.enabled:
@@ -381,51 +435,36 @@ class ECBatchQueue:
             for r in reqs:
                 tr.interval("seam_pending", r.t_apply)
         mat = reqs[0].mat
-        lens = [r.lanes for r in reqs]
-        total = sum(lens)
+        total = sum(r.lanes for r in reqs)
         k = len(reqs[0].chunks)
+        widths = _windows(total)
         with tr.section("seam_fold"):
-            folded = np.zeros((k, total), np.uint8)
-            flat = memoryview(folded.reshape(-1))
-            off = 0
-            for r in reqs:
-                end = off + r.lanes
-                if isinstance(r.chunks, np.ndarray):
-                    folded[:, off:end] = r.chunks
-                else:
-                    # a memoryview copy keeps the GIL: a numpy copy per
-                    # row would give it up, and wait to win it back
-                    # from the busy loop, once per row
-                    for i, row in enumerate(r.chunks):
-                        flat[i * total + off:i * total + end] = row
-                off = end
+            bufs = self._window_buffers(k, widths)
+            spans = _fold(reqs, bufs)
         ap = matrix_apply(mat)
-        cap = LANE_BUCKETS[-1]
+        outs = []
         # device-candidate:ec-dispatch@landed the live executor-side launch:
-        # LANE_BUCKETS-bucketed windows over the folded group, staged
-        # once, fetched once (the shape every candidate above adopts)
-        # XFER17 staging transfer: one h2d for the whole folded group
-        with tr.section("seam_h2d"):
-            dev = jax.device_put(folded)
-        with tr.section("seam_launch"):
-            parts = []
-            for w0 in range(0, total, cap):
-                seg = dev[:, w0:w0 + cap]
-                pad = _bucket(seg.shape[1]) - seg.shape[1]
-                if pad:
-                    seg = jnp.pad(seg, ((0, 0), (0, pad)))
-                parts.append(
-                    ap.device_call(seg)[:, :min(cap, total - w0)])
-                self.perf.inc("device_launches")
-                self.perf.inc("device_lanes_launched", seg.shape[1])
-            out_dev = parts[0] if len(parts) == 1 \
-                else jnp.concatenate(parts, axis=1)
-        # device-sync:begin group result fetch: one d2h for the whole
-        # folded batch, on the ec-device executor thread — the event
-        # loop only awaits run_in_executor
+        # the group folded on the host into LANE_BUCKETS-wide windows,
+        # each staged and launched whole and fetched whole (the shape
+        # every candidate above adopts)
+        for buf in bufs:
+            t0 = tr.stamp()
+            # XFER17 staging transfer: one h2d per window
+            with tr.section("seam_h2d"):
+                dev = jax.device_put(buf)
+            with tr.section("seam_launch"):
+                outs.append(ap.device_call(dev))
+            tr.interval("seam_window", t0)
+        # device-sync:begin group result fetch: one d2h per window, on
+        # the ec-device executor thread — the event loop only awaits
+        # run_in_executor
         with tr.section("seam_d2h"):
-            out = np.asarray(out_dev)
+            fetched = [np.asarray(o) for o in outs]
         # device-sync:end
+        launched = sum(widths)
+        self.perf.inc("device_launches", len(widths))
+        self.perf.inc("device_lanes_launched", launched)
+        self.perf.inc("device_pad_lanes", launched - total)
         self.perf.inc("device_requests", len(reqs))
         self.perf.inc("device_bytes", k * total)
         # LIVE device_byte_fraction substrate (metrics plane): booked
@@ -435,11 +474,13 @@ class ECBatchQueue:
         from ceph_tpu.common import devstats
         devstats.note_bytes("ec_apply", k * total, device=True)
         self.perf.tinc("batch_fill", len(reqs))
+        # a request's rows: a view of its window's result, or, where it
+        # ran over windows, its pieces joined
         rows = []
-        off = 0
-        for ln in lens:
-            rows.append(out[:, off:off + ln])
-            off += ln
+        for parts in spans:
+            pieces = [fetched[w][:, a:b] for w, a, b in parts]
+            rows.append(pieces[0] if len(pieces) == 1 else np.concatenate(
+                pieces or [np.zeros((mat.shape[0], 0), np.uint8)], axis=1))
         res: list = [None] * len(reqs)
         todo = [i for i, r in enumerate(reqs) if r.finish is not None]
         if len(todo) < len(reqs):
@@ -468,3 +509,36 @@ class ECBatchQueue:
         for r in reqs:
             r.t_done = t_done
         return res
+
+
+def _fold(reqs: List[_Req], bufs: List[np.ndarray]) -> list:
+    """Copy the requests' lanes, in order, into the host windows `bufs`,
+    and zero the padded tail of the last.  Returns, per request, the
+    pieces it landed in: [(window, first lane, end lane)]."""
+    widths = [buf.shape[1] for buf in bufs]
+    flats = [memoryview(buf.reshape(-1)) for buf in bufs]
+    spans = []
+    w = at = 0
+    for r in reqs:
+        parts = []
+        done = 0
+        while done < r.lanes:
+            n = min(r.lanes - done, widths[w] - at)
+            if isinstance(r.chunks, np.ndarray):
+                bufs[w][:, at:at + n] = r.chunks[:, done:done + n]
+            else:
+                # a memoryview copy keeps the GIL: a numpy copy per
+                # row would give it up, and wait to win it back from
+                # the busy loop, once per row
+                b, flat = widths[w], flats[w]
+                for i, row in enumerate(r.chunks):
+                    flat[i * b + at:i * b + at + n] = row[done:done + n]
+            parts.append((w, at, at + n))
+            done += n
+            at += n
+            if at == widths[w]:
+                w, at = w + 1, 0
+        spans.append(parts)
+    if at:
+        bufs[w][:, at:] = 0
+    return spans

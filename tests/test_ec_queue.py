@@ -502,6 +502,8 @@ def test_rows_replaced_in_front_of_apply_lose_what_was_made_of_them():
 # -------------------------------------------- op tracing at the seam
 
 EXEC_SECTIONS = ("seam_fold", "seam_h2d", "seam_launch", "seam_d2h")
+#: the sections that run once per launch window, not once per group
+WINDOW_SECTIONS = ("seam_h2d", "seam_launch")
 
 
 def _seam_sums(q):
@@ -529,12 +531,15 @@ def _check_seam_stages_tile(grouping, last, as_rows):
     seam_pending (enqueue -> the executor takes its group), the five
     sections on the ec-device thread, and seam_resume (the executor's
     last instant -> the awaiter runs again): together within 10% of
-    seam_apply.  Sections are per GROUP: when n requests share one
-    launch each of them waits through the same sections, so against the
+    seam_apply.  Sections are per GROUP, but for seam_h2d and
+    seam_launch, which run once per launch window of it (the interval
+    seam_window spans the two): when n requests share one group each
+    of them waits through the same sections, so against the
     per-request intervals they weigh n.  The fifth section is
     seam_split (the result copies) for requests without a continuation
-    and seam_finish (the continuations, which take their rows as views)
-    for requests with one: a group records the one it has work for."""
+    and seam_finish (the continuations, which take their rows as
+    views) for requests with one: a group records the one it has work
+    for."""
     n = 6
     sections = EXEC_SECTIONS + (last,)
     other = "seam_finish" if last == "seam_split" else "seam_split"
@@ -567,16 +572,23 @@ def _check_seam_stages_tile(grouping, last, as_rows):
         await burst()                      # compiles, imports
         before = _seam_sums(q)
         launches = q.perf.dump()["device_launches"]
+        fills = q.perf.dump()["batch_fill"]["avgcount"]
         await burst()
         after = _seam_sums(q)
         d = {k: (after[k][0] - before[k][0], after[k][1] - before[k][1])
              for k in after}
-        groups = q.perf.dump()["device_launches"] - launches
+        groups = q.perf.dump()["batch_fill"]["avgcount"] - fills
+        windows = q.perf.dump()["device_launches"] - launches
         assert groups == (n if grouping == "own_groups" else 1)
+        # the one group of 6 x 2^18 lanes is one window of 2^22
+        assert windows == groups
         for name in ("seam_apply", "seam_pending", "seam_resume"):
             assert d[name][0] == n, (name, d[name])
         for name in sections:
-            assert d[name][0] == groups, (name, d[name])
+            want = windows if name in WINDOW_SECTIONS else groups
+            assert d[name][0] == want, (name, d[name])
+        assert d["seam_window"][0] == windows, d["seam_window"]
+        assert d["seam_window"][1] >= d["seam_h2d"][1] + d["seam_launch"][1]
         assert other not in d, d
         weight = n // groups
         tiled = d["seam_pending"][1] + d["seam_resume"][1] \

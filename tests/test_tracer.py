@@ -589,8 +589,8 @@ def test_loop_sections_nest_in_order_and_seam_sections_run_off_the_loop(
         assert seen[name] == {False}, (name, seen[name])
     assert "seam_split" not in seen
     for name in list(seen) + ["seam_apply", "seam_pending",
-                              "seam_resume", "loop_wall", "loop_cpu",
-                              "evloop_idle", "evloop_poll",
+                              "seam_resume", "seam_window", "loop_wall",
+                              "loop_cpu", "evloop_idle", "evloop_poll",
                               "read_gather"]:
         assert merged[name].count > 0, name
     # a read of a k=2 m=1 object asks one remote shard: one gather each
